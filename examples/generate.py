@@ -52,15 +52,17 @@ def main():
     p.add_argument("--top-k", type=int, default=50)
     p.add_argument("--batch", type=int, default=2)
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--cpu", action="store_true", help="force CPU backend")
+    p.add_argument("--cpu", action="store_true",
+                   help="run on the CPU backend (without it the TPU is "
+                        "required)")
     p.add_argument("--no-cache", action="store_true",
                    help="decode with the full forward per token instead "
                         "of the KV cache (cross-check / debugging; "
                         "greedy outputs match the cached path)")
     args = p.parse_args()
 
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
+    from tiny_deepspeed_tpu.utils.startup import select_platform
+    select_platform(cpu=args.cpu, cpu_flag="--cpu")
 
     from tiny_deepspeed_tpu import SGD, SingleDevice
     from tiny_deepspeed_tpu.models import build_model

@@ -71,6 +71,9 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from tiny_deepspeed_tpu.utils.startup import select_platform
+    select_platform()  # a device trace needs the chip
+
     from bench import _bench_config
     from tiny_deepspeed_tpu import AdamW, SingleDevice, make_mesh
     from tiny_deepspeed_tpu.models import ALL_PRESETS, build_model
@@ -84,7 +87,10 @@ def main():
     if args.offload:
         ek = dict(offload_opt_state=True,
                   offload_prefetch=args.offload_prefetch)
-    engine = SingleDevice(model, opt, mesh=make_mesh(), **ek)
+    # ONE chip even on a four-chip host: SingleDevice over every visible
+    # device would replicate the step, not profile the single-chip config
+    engine = SingleDevice(model, opt,
+                          mesh=make_mesh(devices=[jax.devices()[0]]), **ek)
     state = engine.init(jax.random.PRNGKey(0))
     b, t = bc["batch"], 1024
     idx = jax.random.randint(jax.random.PRNGKey(1), (b, t), 0,
@@ -107,8 +113,7 @@ def main():
     if tpu is None:
         raise SystemExit(
             f"no TPU plane in {xplane} (planes: "
-            f"{[pl.name for pl in p.planes]}) — this script needs the "
-            "real chip; the CPU backend records no per-op device line")
+            f"{[pl.name for pl in p.planes]})")
     ops = next(ln for ln in tpu.lines if ln.name == "XLA Ops")
     tot = defaultdict(float)
     for e in ops.events:

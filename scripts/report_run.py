@@ -205,17 +205,23 @@ def render_report(metas: List[dict], steps: List[dict],
             out.append(f"- wire traffic: "
                        f"{_fmt_bytes(cost['wire_bytes'])}")
         ai = cost.get("arithmetic_intensity", 0.0)
-        ridge = cost.get("ridge_intensity", 0.0)
-        out.append(f"- arithmetic intensity: {ai:.1f} FLOPs/byte "
-                   f"(device ridge {ridge:.1f})")
-        bound = cost.get("bound", "?")
-        out.append(
-            f"- bound verdict: **{bound}-bound** "
-            f"(t_compute {cost.get('t_compute_s', 0.0) * 1e3:.2f} ms, "
-            f"t_hbm {cost.get('t_hbm_s', 0.0) * 1e3:.2f} ms, "
-            f"t_wire {cost.get('t_wire_s', 0.0) * 1e3:.2f} ms lower "
-            f"bounds)"
-        )
+        if cost.get("bound"):
+            out.append(f"- arithmetic intensity: {ai:.1f} FLOPs/byte "
+                       f"(device ridge "
+                       f"{cost.get('ridge_intensity', 0.0):.1f})")
+            out.append(
+                f"- bound verdict: **{cost['bound']}-bound** "
+                f"(t_compute {cost.get('t_compute_s', 0.0) * 1e3:.2f} ms, "
+                f"t_hbm {cost.get('t_hbm_s', 0.0) * 1e3:.2f} ms, "
+                f"t_wire {cost.get('t_wire_s', 0.0) * 1e3:.2f} ms lower "
+                f"bounds)"
+            )
+        else:
+            # counts only: the run's device has no entry in the peak
+            # tables (utils/hlo_cost.py), so no roofline was drawn
+            out.append(f"- arithmetic intensity: {ai:.1f} FLOPs/byte")
+            out.append("- bound verdict: not measured (no peak known "
+                       "for this device)")
         centers = cost.get("top_cost_centers") or []
         if centers:
             out.append("\ntop cost centers:\n")

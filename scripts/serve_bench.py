@@ -39,6 +39,7 @@ terminal-status counts (ok/shed/expired/failed) and p99 TTFT with and
 without faults."""
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -46,10 +47,17 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
-def main(argv=None) -> int:
+def serve(argv=None, cfg_overrides=None) -> dict:
+    """Run the load driver; returns the JSON summary it prints plus
+    `outputs` (each request's tokens, in submission order) and, with
+    --serial, `serial_outputs` (the same trace through generate()).
+    `cfg_overrides` (model config fields) is for programmatic callers
+    that need the preset at another precision (chip_smoke.py)."""
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--model", default="tiny")
-    p.add_argument("--cpu", action="store_true", help="force CPU backend")
+    p.add_argument("--cpu", action="store_true",
+                   help="run on the CPU backend (without it the TPU is "
+                        "required)")
     p.add_argument("--requests", type=int, default=16)
     p.add_argument("--rate", type=float, default=None, metavar="RPS",
                    help="Poisson arrival rate (default: closed loop — "
@@ -169,19 +177,21 @@ def main(argv=None) -> int:
     elif jsonl_path.lower() == "none":
         jsonl_path = None
 
-    if args.cpu:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
+
+    from tiny_deepspeed_tpu.utils.startup import select_platform
+    select_platform(cpu=args.cpu, cpu_flag="--cpu")
 
     from tiny_deepspeed_tpu.models import ALL_PRESETS, build_model
     from tiny_deepspeed_tpu.serving import ServeConfig, ServingEngine
     from tiny_deepspeed_tpu.serving.driver import poisson_trace, run_trace
     from tiny_deepspeed_tpu.telemetry import Telemetry
 
-    model = build_model(args.model)
-    cfg = model.config
-    params = model.init(jax.random.PRNGKey(args.seed))
+    cfg = dataclasses.replace(ALL_PRESETS[args.model],
+                              **(cfg_overrides or {}))
+    model = build_model(cfg)
+    # one compiled program, not one dispatch per initializer op
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     prompt_lens = [int(x) for x in args.prompt_lens.split(",")]
 
     tenants = None
@@ -430,7 +440,9 @@ def main(argv=None) -> int:
     eng = build_target(tel, logger)
     res = run_trace(eng, trace, realtime=realtime,
                     slo=slo_tracker, live=live_agg)
-    res.pop("outputs")
+    # returned, not printed (ids are global across engines: submission
+    # order is the stable key)
+    returned = {"outputs": list(res.pop("outputs").values())}
     res.pop("requests")
     if slo_tracker is not None and logger is not None:
         # final budget snapshot as an `slo` record: the engine only
@@ -563,6 +575,7 @@ def main(argv=None) -> int:
         from tiny_deepspeed_tpu.serving.driver import run_serial
         ser = run_serial(model, params, trace,
                          temperature=args.temperature, top_k=args.top_k)
+        returned["serial_outputs"] = ser["outputs"]
         summary["serial_tokens_per_s"] = ser["tokens_per_s"]
         summary["vs_serial"] = round(
             res["tokens_per_s"] / max(ser["tokens_per_s"], 1e-9), 3)
@@ -655,6 +668,11 @@ def main(argv=None) -> int:
             f"scripts/serve_report.py {jsonl_path}; timeline: python "
             f"scripts/trace_view.py {jsonl_path})", file=sys.stderr,
         )
+    return {**summary, **returned}
+
+
+def main(argv=None) -> int:
+    serve(argv)
     return 0
 
 

@@ -33,7 +33,6 @@ mode, journal_path = sys.argv[1], sys.argv[2]
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("TINY_DS_NO_COMPILE_CACHE", "1")
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

@@ -409,12 +409,13 @@ class TestTuneE2E:
         t3 = RuntimeAutoTuner()
         assert t3.load(p) == 1
 
-    def test_spec_k_roundtrip_plan_to_serveconfig_to_fingerprint(
+    def test_spec_k_roundtrip_plan_to_serveconfig_to_kernel_stamp(
             self, tmp_path, monkeypatch):
         """The satellite fix: a tuned spec_k round-trips plan ->
         resolve_spec_k -> ServeConfig, and the consumed plan's hash
-        lands in BENCH_TUNE_PLAN so `_config_fingerprint` separates
-        runs under different plans."""
+        lands in BENCH_TUNE_PLAN so the record's kernel stamp separates
+        runs under different plans.  The plan is read only from a cache
+        passed explicitly (BENCH_TUNE_CACHE)."""
         import bench
         from tiny_deepspeed_tpu.autotuner import (
             RuntimeAutoTuner, plan_key,
@@ -430,19 +431,23 @@ class TestTuneE2E:
         t.store_plan(plan_key("tiny", mesh, backend), {"spec_k": 6}, {})
         t.save(cache)
 
-        fp_before = bench._config_fingerprint()
+        assert bench._kernel_stamp()["tune_plan"] == ""
         k, source = bench.resolve_spec_k("tiny")
         assert (k, source) == (6, "plan")
         assert os.environ["BENCH_TUNE_PLAN"]  # hash exported
-        assert bench._config_fingerprint() != fp_before
+        assert (bench._kernel_stamp()["tune_plan"]
+                == os.environ["BENCH_TUNE_PLAN"])
         cfg = ServeConfig(spec_draft="ngram", spec_k=k)
         assert cfg.spec_k == 6
         # explicit env outranks the plan
         monkeypatch.setenv("BENCH_SPEC_K", "3")
         assert bench.resolve_spec_k("tiny") == (3, "env")
-        # no plan, no env -> the hand-set default
+        # no cache passed explicitly, no env -> the hand-set default
+        # (a generated artifacts/autotune_cache.json is never consulted)
         monkeypatch.delenv("BENCH_SPEC_K")
-        monkeypatch.setenv("BENCH_TUNE_CACHE", str(tmp_path / "none.json"))
+        monkeypatch.delenv("BENCH_TUNE_CACHE")
+        monkeypatch.setattr(
+            bench, "_tune_cache_path", lambda: cache)
         assert bench.resolve_spec_k("tiny") == (4, "default")
 
 

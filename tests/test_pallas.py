@@ -86,10 +86,48 @@ class TestPallasLayerNorm:
         # huge feature dim shrinks the block to fit VMEM
         rb = LNP._pick_row_block(4096, 8192)
         assert rb is not None and rb * 8192 * 16 <= 8 * 1024 * 1024
+        # Mosaic tiles (8, 128): a block that is not the whole array must
+        # have a multiple-of-8 row count.  300 rows (a generate() prompt)
+        # has no such divisor <= 256 -> XLA; the old picker chose 150 and
+        # the TPU lowering refused the program on the chip
+        assert LNP._pick_row_block(300, 768) is None
+        assert LNP._pick_row_block(100, 768) == 100  # whole array: legal
+        assert LNP._pick_row_block(12 * 1024, 768) == 256
+        for rows in (264, 520, 1000, 3000, 12288):
+            rb = LNP._pick_row_block(rows, 768)
+            assert rb is None or (rows % rb == 0 and rb % 8 == 0), rows
 
     def test_pallas_supported_gate(self):
         assert LNP.pallas_supported(jnp.zeros((64, 128)))
         assert not LNP.pallas_supported(jnp.zeros((7, 128)))
+
+
+class TestFlashGate:
+    def test_flash_gate_follows_sequence_length(self, monkeypatch):
+        """The attention gate picks the Pallas kernel only on the 128
+        grid (Mosaic refuses the backward passes, and FA2's bf16 forward,
+        off it — ops/attention.flash_kernel_ok), and notes what it chose
+        (ops/dispatch.kernels_noted, what chip_smoke.py prints)."""
+        from tiny_deepspeed_tpu.ops import attention, flash_fa2
+        from tiny_deepspeed_tpu.ops.dispatch import (
+            kernel_target_forced, kernels_noted,
+        )
+        monkeypatch.setattr(flash_fa2, "_INTERPRET", True)
+        q = jnp.ones((1, 2, 128, 64), jnp.float32)
+        # gates choose (and note) at TRACE time: eval_shape is enough; the
+        # kernels' numerics are tests/test_flash_fa2.py's
+        with kernel_target_forced("tpu"):
+            kernels_noted(clear=True)
+            jax.eval_shape(attention.flash_attention, q, q, q)
+            assert kernels_noted(clear=True)["attention"] == [
+                "pallas:fa2_q512_k512"]
+            q2 = q[:, :, :100]
+            jax.eval_shape(attention.flash_attention, q2, q2, q2)
+            assert kernels_noted(clear=True)["attention"] == [
+                "xla:dot_product_attention"]
+        assert [attention.flash_kernel_ok(t)
+                for t in (8, 100, 128, 300, 1024)] == [
+            False, False, True, False, True]
 
 
 class TestPallasAdamW:

@@ -7,7 +7,6 @@ import json
 
 import jax
 import jax.numpy as jnp
-import jaxlib.version
 import pytest
 
 from tiny_deepspeed_tpu import AdamW, DDP, GPTConfig, GPT2Model, Zero2, Zero3
@@ -95,20 +94,6 @@ class TestCommReport:
             "zero3_tail_release_bytes"] == 0.0
 
 
-# Known environment-dependent failure on this jax 0.4.37 / jaxlib 0.4.36
-# XLA-CPU build: the SPMD partitioner hits "Involuntary full
-# rematerialization" on the attention backward dot's resharding
-# ({devices=[8,1,..]} -> {devices=[1,2,4,..]}) and emits extra all-gathers
-# (~3.58 MB measured vs the 0.83 MB ring model), so the formula-vs-ledger
-# agreement these tests pin cannot hold HERE.  strict=False: partitioners
-# without the fallback (TPU, newer jaxlibs) pass and report xpass.
-_SPMD_REMAT_XFAIL = pytest.mark.xfail(
-    jaxlib.version.__version__ == "0.4.36",
-    reason="env-dependent: this XLA-CPU partitioner's involuntary full "
-           "rematerialization inflates the measured all-gather wire past "
-           "the ring-model prediction", strict=False)
-
-
 class TestCommReportVsCompiledHLO:
     """comm_report's ring formulas validated against the collective ledger
     parsed out of the COMPILED step (utils/hlo_comm.py) — the round-2
@@ -142,7 +127,6 @@ class TestCommReportVsCompiledHLO:
                    - rep["grad_allreduce_bytes"]) <= 128
         assert "all-gather" not in led["payload_bytes"]
 
-    @_SPMD_REMAT_XFAIL
     def test_zero1_gather_and_allreduce_match(self):
         from tiny_deepspeed_tpu import Zero1
         rep, led = self._ledger(Zero1)
@@ -151,7 +135,6 @@ class TestCommReportVsCompiledHLO:
         assert abs(led["wire_bytes"]["all-reduce"]
                    - rep["grad_allreduce_bytes"]) <= 128
 
-    @_SPMD_REMAT_XFAIL
     def test_zero2_grads_between_rs_and_ar(self):
         rep, led = self._ledger(Zero2)
         # param re-gather exactly as predicted
@@ -256,7 +239,6 @@ class TestCommReportVsCompiledHLO:
         ]
         assert _trip_count(agreeing) == (8, True)
 
-    @_SPMD_REMAT_XFAIL
     def test_zero3_layer_gathers_match(self):
         rep, led = self._ledger(Zero3)
         # per-layer gathers: 2x block params (fwd + remat bwd) + 1x
@@ -266,13 +248,6 @@ class TestCommReportVsCompiledHLO:
                    - rep["zero3_layer_gather_bytes"]) \
             <= 0.1 * rep["zero3_layer_gather_bytes"]
 
-    @pytest.mark.xfail(
-        jaxlib.version.__version__ == "0.4.36",
-        reason="env-dependent: this jaxlib 0.4.36 XLA-CPU backend cannot "
-               "compile the pipeline step at all (UNIMPLEMENTED: "
-               "PartitionId instruction is not supported for SPMD "
-               "partitioning)",
-        strict=False)
     def test_pipeline_ppermute_counts(self):
         """Cross-check the ledger's loop multiplication on a different
         collective/loop structure: the GPipe tick scan runs M+S-1 ticks

@@ -638,23 +638,3 @@ def test_live_slo_schema_v15_names():
         "counters": {}, "histograms": {},
     })
     assert not errs, errs
-
-
-def test_perf_diff_check_committed_trajectory():
-    """CI wiring for the perf regression sentinel: `perf_diff --check`
-    must run green against the committed BENCH_*.json trajectory.  A
-    nonzero exit here means either a real cross-round regression was
-    committed or the sentinel itself broke — both block the PR."""
-    import glob
-    import sys
-
-    rounds = sorted(glob.glob(os.path.join(REPO, "BENCH_*.json")))
-    assert rounds, "no committed BENCH_*.json rounds to gate on"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "perf_diff.py"),
-         "--check", *rounds],
-        capture_output=True, text=True, cwd=REPO, timeout=60)
-    assert proc.returncode == 0, (
-        f"perf_diff --check flagged the committed trajectory:\n"
-        f"{proc.stdout}\n{proc.stderr}"
-    )

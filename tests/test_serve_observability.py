@@ -421,7 +421,7 @@ class TestWireLinkSplit:
         (groups {0,4},{1,5},...) spans slices -> ALL its wire bills to
         DCN.  The split is read off the compiled HLO's replica_groups,
         so the numbers equal the ledger's per-op wire exactly."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from tiny_deepspeed_tpu.parallel.mesh import make_mesh

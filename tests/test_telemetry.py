@@ -427,9 +427,7 @@ class TestExampleEndToEnd:
         from a REAL examples/ddp run's JSONL, including measured
         (HLO-ledger) collective bytes alongside the comm_report model."""
         jsonl = str(tmp_path / "ddp_run.jsonl")
-        env = dict(
-            os.environ, JAX_PLATFORMS="cpu", TINY_DS_NO_COMPILE_CACHE="1",
-        )
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env.pop("XLA_FLAGS", None)  # the entry point sets its own device count
         r = subprocess.run(
             [sys.executable, os.path.join(REPO, "examples", "ddp",
@@ -470,10 +468,13 @@ class TestExampleEndToEnd:
         doc = json.load(open(trace_json))
         assert doc["traceEvents"]
         ledger_loops = meta["comm_measured"]["wire_bytes_in_loops"]
+        # collective spans only: with the FLOP ledger counting again the
+        # trace also carries loop-resident COMPUTE spans (flops, no wire)
         loop_spans = [
             e for e in doc["traceEvents"]
             if e.get("ph") == "X"
             and e.get("args", {}).get("loop_resident")
+            and "wire_bytes" in e["args"]
         ]
         assert loop_spans
         for e in loop_spans:

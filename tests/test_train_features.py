@@ -13,7 +13,6 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import jaxlib.version
 import numpy as np
 import pytest
 
@@ -237,12 +236,6 @@ class TestLossScaling:
                 np.asarray(x), np.asarray(y), rtol=2e-4, atol=2e-5
             )
 
-    @pytest.mark.xfail(
-        jaxlib.version.__version__ == "0.4.36",
-        reason="environment-dependent: this jaxlib 0.4.36 XLA-CPU build's "
-               "emulated fp16 leaves the 4-step tiny-model loss marginally "
-               "above its start (4.8603 vs 4.8554); converges on backends "
-               "with native fp16", strict=False)
     def test_fp16_compute_with_dynamic_scaling_trains(self):
         """The actual AMP capability: float16 compute + dynamic scaling
         converges on the tiny model (fp16 grads without scaling underflow

@@ -30,29 +30,6 @@ Public API shape mirrors the reference's flat surface
     )
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under experimental only, with the
-    # replication check spelled `check_rep` instead of `check_vma`; the
-    # parallel modules (pipeline / ring attention / Ulysses / MoE) call
-    # the stable `jax.shard_map(..., check_vma=...)` spelling — adapt it
-    # once at package import so every entry path works on both generations
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_compat(f, *args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        names = kwargs.pop("axis_names", None)
-        if names is not None:
-            # new partial-manual spelling (manual over `axis_names`) ->
-            # old complement spelling (auto over everything else)
-            mesh = kwargs.get("mesh", args[0] if args else None)
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(names)
-        return _shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
 from .parallel.partition import partition_tensors, materialize_owned
 from .parallel.engine import SingleDevice, DDP, Zero1, Zero2, Zero3
 from .parallel.mesh import make_mesh, init_distributed

@@ -5,8 +5,10 @@
 
 Python binding (ctypes — no pybind11 in this image) over the native C++
 pipeline in native/dataloader.cpp; compiled on first use with g++ and cached
-next to the source.  Falls back to a NumPy implementation with identical
-semantics when no compiler is available.
+next to the source under a name keyed by the source's content (a copied
+tree keeps no meaningful mtimes, so a stale binary must not be picked up by
+date).  Falls back to a NumPy implementation with identical semantics when
+no compiler is available; `TokenLoader.backend` says which one runs.
 
 Two modes, both deterministic per seed:
   * corpus mode: `TokenLoader("tokens.bin", ...)` — random crops of a
@@ -19,6 +21,7 @@ Two modes, both deterministic per seed:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -28,7 +31,14 @@ import numpy as np
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
 _SRC = os.path.abspath(os.path.join(_NATIVE_DIR, "dataloader.cpp"))
-_SO = os.path.abspath(os.path.join(_NATIVE_DIR, "libtds_dataloader.so"))
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.abspath(os.path.join(
+        _NATIVE_DIR, f"libtds_dataloader-{digest}.so"))
+
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -45,14 +55,18 @@ def _load_native():
         if _lib is not None or _build_error is not None:
             return _lib
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+            so = _so_path()
+            if not os.path.exists(so):
+                # build beside the target and rename: a concurrent process
+                # (test workers) never loads a half-written library
+                tmp = f"{so}.{os.getpid()}.tmp"
                 subprocess.run(
                     ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", _SRC, "-o", _SO],
+                     "-pthread", _SRC, "-o", tmp],
                     check=True, capture_output=True, text=True,
                 )
-            lib = ctypes.CDLL(_SO)
+                os.replace(tmp, so)
+            lib = ctypes.CDLL(so)
             lib.tds_loader_create.restype = ctypes.c_void_p
             lib.tds_loader_create.argtypes = [
                 ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
@@ -75,6 +89,12 @@ def _load_native():
 
 def native_available() -> bool:
     return _load_native() is not None
+
+
+def native_build_error() -> Optional[str]:
+    """Why the native loader is unavailable (None when it loaded)."""
+    _load_native()
+    return _build_error
 
 
 class TokenLoader:

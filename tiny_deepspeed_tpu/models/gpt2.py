@@ -129,8 +129,7 @@ class GPTConfig:
     # online logsumexp + in-kernel gold gather, FA2-style recompute
     # backward; round 5).  "pallas" is TPU-gated and falls back to the
     # chunked path elsewhere; adoption as default awaits the chip A/B
-    # (tpu_batch.sh step 13, VERDICT r4 #8: measure standalone first,
-    # adopt only on an end-to-end win).
+    # (measure standalone first, adopt only on an end-to-end win).
     fused_xent_impl: str = "chunked"
     # resting dtype of the decode KV cache (generate(use_cache=True) and
     # the serving tier's contiguous prefill).  None keeps compute_dtype;
@@ -591,9 +590,12 @@ class GPT2Model:
         (materialized `paged_panel` + `_decode_attention` /
         `_span_attention`).  q (S, Hq, K1, Dh); span_kv = (sk, sv) span
         K/V switches to the span-verify mask."""
+        from ..ops.dispatch import note_kernel
         from ..ops.paged_attn_pallas import paged_attention, use_paged_kernel
         if use_paged_kernel():
+            note_kernel("paged_attention", "pallas:paged_attention")
             return paged_attention(q, view, page, l, span_kv=span_kv)
+        note_kernel("paged_attention", "xla:paged_panel")
         from ..serving.pool import paged_panel
         ck, cv = paged_panel(view, l, page, self.config.compute_dtype)
         if span_kv is None:
@@ -959,6 +961,10 @@ class GPT2Model:
                 seq_sharded=pctx is not None and pctx.seq_parallel,
                 tokens=x.shape[0] * x.shape[1],
             )
+            from ..ops.dispatch import note_kernel
+            note_kernel("loss_head",
+                        "pallas:fused_xent" if impl == "pallas"
+                        else f"xla:{impl}")
             if impl == "pallas":
                 # single-device only for now: the custom call would
                 # force GSPMD to gather the vocab-sharded w under tp

@@ -29,12 +29,13 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .dispatch import kernel_target
+from .dispatch import impl_label, kernel_target, note_kernel
 
 
 def standard_attention(q, k, v):
     """Causal softmax(QK^T/sqrt(d))V with an explicit mask (reference :29-42)."""
     *_, t, dh = q.shape
+    note_kernel("attention", "xla:standard_attention")
     scale = 1.0 / math.sqrt(dh)
     logits = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
@@ -48,41 +49,50 @@ def standard_attention(q, k, v):
 def _sdpa_or_standard(q, k, v):
     """XLA-fused causal SDPA, falling back to the explicit-mask path."""
     try:
-        return jax.nn.dot_product_attention(
+        out = jax.nn.dot_product_attention(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), is_causal=True
         ).swapaxes(1, 2)
     except Exception:
         return standard_attention(q, k, v)
+    note_kernel("attention", "xla:dot_product_attention")
+    return out
 
 
 def _tuned_pallas_flash(q, k, v):
     """Pallas flash kernel, block sizes chosen by the runtime autotuner when
     one is installed (request recorded at trace time, winner baked on
     retune — the real multi-candidate site the reference's tuner never had,
-    reference ops/linear.py:12 'Add more functions here').  Falls back to
-    the XLA SDPA path if the bundled kernel module is unavailable."""
-    try:
-        from .attention_pallas import FLASH_VARIANTS
-    except ImportError:
-        return _sdpa_or_standard(q, k, v)
+    reference ops/linear.py:12 'Add more functions here')."""
+    from .attention_pallas import FLASH_VARIANTS
     from ..autotuner import get_default_tuner
 
     tuner = get_default_tuner()
-    if tuner is not None:
-        return tuner.choose(FLASH_VARIANTS, (q, k, v))(q, k, v)
     # no tuner: candidates[0] is the measured default — round 4: the
     # hand-written FA2 kernel (ops/flash_fa2.py, fused-lse residuals, no
     # [B,H,T,block] stat broadcasts; every bench row +6-23% vs the bundled
     # kernel), T-guarded to fall back to the bundled kernel past FA2_MAX_T.
     # ONE list defines the dispatch for both the tuned and untuned paths.
-    return FLASH_VARIANTS[0](q, k, v)
+    impl = (tuner.choose(FLASH_VARIANTS, (q, k, v)) if tuner is not None
+            else FLASH_VARIANTS[0])
+    note_kernel("attention", impl_label(impl))
+    return impl(q, k, v)
+
+
+def flash_kernel_ok(t: int) -> bool:
+    """Sequence lengths Mosaic accepts the flash kernels at.  Compiled for
+    v5e at T = 8..1024 (PR 21): the backward passes of BOTH kernels (FA2
+    and the bundled one) lower only at T % 128 == 0, and FA2's bf16 forward
+    only at T % 8 == 0 — a generate() prompt of 100 or 300 tokens failed to
+    compile on the chip.  One predicate for every dispatch site: lengths off
+    the 128 grid take the XLA path."""
+    return t % 128 == 0
 
 
 def flash_attention(q, k, v):
     """Blockwise causal attention; Pallas kernel on TPU, fused XLA elsewhere."""
     # Static (trace-time) backend choice: tracers carry no device, and the
     # kernel choice must be baked into the jitted program anyway.
-    if kernel_target() == "tpu":
+    if kernel_target() == "tpu" and flash_kernel_ok(q.shape[2]):
         return _tuned_pallas_flash(q, k, v)
     return _sdpa_or_standard(q, k, v)
 
@@ -99,9 +109,10 @@ def gqa_flash_attention(q, k, v):
     autotuned: the GQA site has one kernel candidate."""
     group = q.shape[1] // k.shape[1]
     t, d = q.shape[2], q.shape[3]
-    if kernel_target() == "tpu":
+    if kernel_target() == "tpu" and flash_kernel_ok(t):
         from .flash_fa2 import fa2_flash_attention, fa2_gqa_supported
         if fa2_gqa_supported(t, d, group):
+            note_kernel("attention", impl_label(fa2_flash_attention))
             return fa2_flash_attention(q, k, v)
     k = jnp.repeat(k, group, axis=1)
     v = jnp.repeat(v, group, axis=1)
@@ -132,7 +143,7 @@ def sharded_attention(q, k, v, impl: str, pctx=None):
     # The flash paths below keep them grouped all the way into the FA2
     # kernel; every other path expands here — under GSPMD head sharding
     # the repeat is free, which is exactly what it replaced in llama.py.
-    # TINY_DS_GQA=repeat is the chip A/B knob (tpu_batch.sh): it forces
+    # TINY_DS_GQA=repeat is the chip A/B knob: it forces
     # the round-4 repeat-then-MHA-kernel path so the GQA-native win is
     # measured against the exact program it replaced.
     rep = q.shape[1] // k.shape[1]
@@ -241,7 +252,8 @@ def sharded_attention(q, k, v, impl: str, pctx=None):
             )
         return local_fn(q, k, v)
 
-    if impl == "flash_attention" and kernel_target() == "tpu":
+    if (impl == "flash_attention" and kernel_target() == "tpu"
+            and flash_kernel_ok(q.shape[2])):
         # GQA rides through: per-shard head counts keep the same group
         # ratio (tp must divide kv_heads — models/llama.py tp_rules), so
         # the local gqa path sees a consistent (H/tp, KVH/tp) pair

@@ -21,7 +21,7 @@ the old behavior exactly: the process backend decides.
 from __future__ import annotations
 
 from contextlib import contextmanager
-from typing import Optional
+from typing import Dict, List, Optional
 
 import jax
 
@@ -51,6 +51,37 @@ def kernel_target_forced(platform: Optional[str]):
         yield
     finally:
         force_kernel_target(prev)
+
+
+# --- what the gates chose ---------------------------------------------------
+# Each dispatch gate (attention, layernorm, paged attention, loss head)
+# notes the implementation it bakes into the program being traced, so a
+# run can print what it actually ran instead of what a knob asked for
+# (chip_smoke.py asserts "pallas" at every gate on the chip, and prints the
+# XLA layernorm under the GSPMD region so it is not read as a fallback).
+# Trace-time only: a cached executable re-run notes nothing.
+
+_NOTED: Dict[str, set] = {}
+
+
+def note_kernel(gate: str, impl: str) -> None:
+    _NOTED.setdefault(gate, set()).add(impl)
+
+
+def impl_label(fn) -> str:
+    """"pallas:<name>" for a function from a *_pallas / flash_fa2 kernel
+    module, "xla:<name>" otherwise."""
+    mod = getattr(fn, "__module__", "") or ""
+    kind = "pallas" if ("pallas" in mod or "flash_fa2" in mod) else "xla"
+    return f"{kind}:{getattr(fn, '__name__', fn)}"
+
+
+def kernels_noted(clear: bool = False) -> Dict[str, List[str]]:
+    """{gate: sorted implementations traced since the last clear}."""
+    out = {g: sorted(v) for g, v in _NOTED.items()}
+    if clear:
+        _NOTED.clear()
+    return out
 
 
 # --- GSPMD auto-partitioned region -----------------------------------------

@@ -601,7 +601,7 @@ def fa2_flash_attention_bthd(q, k, v, block_q: int = 512,
     ever materializes (see the section comment above for why per-head
     blocks cannot lower).  Semantics parity with `fa2_flash_attention`
     is pinned in tests/test_flash_fa2.py; chip timing pending
-    (scripts/fa2_bthd_ab.py, tpu_batch.sh step 10).  Falls back to
+    (scripts/fa2_bthd_ab.py).  Falls back to
     transpose + the standard kernels when the panel exceeds the VMEM
     budget."""
     out, _ = _fa2_bthd_fwd(q, k, v, block_q, block_k)

@@ -30,7 +30,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .dispatch import in_gspmd_auto_region, kernel_target
+from .dispatch import (
+    impl_label, in_gspmd_auto_region, kernel_target, note_kernel,
+)
 
 
 def _pallas_ok(x) -> bool:
@@ -65,6 +67,7 @@ def layernorm_fwd(x, w, b, eps=1e-5, tuner=None):
         tuner = get_default_tuner()
     cands = _fwd_candidates(x)
     impl = tuner.choose(cands, (x, w, b), eps=eps) if tuner else cands[0]
+    note_kernel("layernorm", impl_label(impl))
     return impl(x, w, b, eps)
 
 
@@ -96,6 +99,7 @@ def layernorm_dx(gy, x, w, mean, rstd, tuner=None):
         from .layernorm_pallas import ln_dx_pallas
         cands.insert(0, ln_dx_pallas)
     impl = tuner.choose(cands, (gy, x, w, mean, rstd)) if tuner else cands[0]
+    note_kernel("layernorm", impl_label(impl))
     return impl(gy, x, w, mean, rstd)
 
 
@@ -121,6 +125,7 @@ def layernorm_dwdb(gy, x, mean, rstd, tuner=None):
         from .layernorm_pallas import ln_dwdb_pallas
         cands.insert(0, ln_dwdb_pallas)
     impl = tuner.choose(cands, (gy, x, mean, rstd)) if tuner else cands[0]
+    note_kernel("layernorm", impl_label(impl))
     return impl(gy, x, mean, rstd)
 
 

@@ -40,13 +40,19 @@ INTERPRET = bool(os.environ.get("TDS_PALLAS_INTERPRET"))
 
 def _pick_row_block(n_rows: int, n_cols: int):
     """Largest row-block <= ROW_BLOCK that DIVIDES n_rows (so no padding
-    rows exist — padding would corrupt the dwdb accumulation) and fits
-    comfortably in VMEM.  Returns None when no suitable block exists; the
-    dispatch site falls back to the XLA implementation."""
+    rows exist — padding would corrupt the dwdb accumulation), fits
+    comfortably in VMEM, and that Mosaic can tile: a block's second-minor
+    dim must be a multiple of 8 or the whole array's (300 rows used to
+    pick 150-row blocks, which the TPU lowering rejects — found by the
+    first chip run of generate() at a 300-token prompt).  Returns None
+    when no suitable block exists; the dispatch site falls back to the
+    XLA implementation."""
     cap = ROW_BLOCK
     while cap > 8 and cap * n_cols * 4 * 4 > 8 * 1024 * 1024:
         cap //= 2
-    for rb in range(min(cap, n_rows), 7, -1):
+    if 8 <= n_rows <= cap:
+        return n_rows  # one block spanning the array
+    for rb in range(cap, 7, -8):
         if n_rows % rb == 0:
             return rb
     return None

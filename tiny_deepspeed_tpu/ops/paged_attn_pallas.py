@@ -299,20 +299,15 @@ def paged_attention(q, view, page, l, *, span_kv=None):
             pltpu.VMEM((kvh, g * k1), jnp.float32),
         ],
     )
-    kwargs = {}
-    try:
-        # slots are independent (scratch resets at j == 0), so the s
-        # dimension may split across Mosaic cores; j must stay ordered
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        )
-    except Exception:  # older jaxlib spelling; default semantics are safe
-        pass
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, kvh, g * k1, dh), q.dtype),
         interpret=INTERPRET,
-        **kwargs,
+        # slots are independent (scratch resets at j == 0), so the s
+        # dimension may split across Mosaic cores; j must stay ordered
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
     )(tables, pos, larr, *args)
     return out.reshape(s, kvh, g, k1, dh).reshape(s, hq, k1, dh)

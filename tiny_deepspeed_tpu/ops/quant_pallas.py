@@ -12,11 +12,11 @@ panel: 8 scale-blocks (8 x block f32 = 8 KB at block=256) per grid step,
 emitting the 1-byte codes and the (rows, 1) scales directly.
 
 Stochastic rounding takes the uniform dither as an OPERAND (drawn with
-jax.random by the caller) rather than the on-core PRNG: jaxlib 0.4.37
-has no interpret-mode lowering for `pltpu.prng_seed`, and the parity
-tests (tests/test_grad_comm.py) run the kernel in interpret mode on the
-CPU mesh like every other kernel here.  The extra operand is one f32
-read of the gradient's size — the win this kernel chases is the fused
+jax.random by the caller) rather than the on-core PRNG, so the parity
+tests (tests/test_grad_comm.py), which run the kernel in interpret mode
+on the CPU mesh like every other kernel here, round with the same
+dither the XLA formulation does.  The extra operand is one f32 read of
+the gradient's size — the win this kernel chases is the fused
 reduce+quantize pass, not the dither bytes.
 
 Dispatched from `comm.quantize_blockwise` behind the standard trace-time

@@ -1088,7 +1088,7 @@ class ZeroEngine:
         inbound copy-start sits at ~86% of the schedule for w=2/4/6
         alike), so the extra window buys HBM pressure, not overlap.  The
         knob remains for the chip A/B at sizes with headroom
-        (tpu_batch.sh step 9b runs 774M w=2 vs w=4); within the update
+        (774M, w=2 vs w=4); within the update
         phase the w=2 chain already lets inbound(i) overlap both
         update(i-1) and outbound(i-1) (86/110 copy pairs overlap >=1
         fusion in the compiled schedule).

@@ -37,33 +37,20 @@ def init_distributed(**kwargs) -> None:
     ddp/train.py:19 — torchrun env:// rendezvous becomes
     jax.distributed.initialize auto-configuration on Cloud TPU).
 
-    Single-process runs (no multi-host env, no kwargs, not on a pod) skip
-    initialization — jax.distributed.initialize would otherwise block
-    waiting for a coordinator.
+    Single-host runs skip initialization — jax.distributed.initialize
+    would otherwise block waiting for a coordinator.  "Single host" is
+    decided by COUNT, not spelling: the Cloud TPU runtime sets
+    TPU_WORKER_HOSTNAMES on one-host machines too (a four-chip host
+    lists its one own address), so only a list of two or more workers,
+    an explicit JAX_COORDINATOR_ADDRESS, or explicit kwargs mean
+    multi-host.
     """
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        if is_init():
-            return
-    else:
-        # jax builds without the predicate (e.g. 0.4.37): the global state
-        # object's client is the same signal
-        state = getattr(jax._src.distributed, "global_state", None)
-        if state is not None and getattr(state, "client", None) is not None:
-            return
-    multi_host_env = any(
-        os.environ.get(v)
-        for v in (
-            "JAX_COORDINATOR_ADDRESS",     # explicit coordinator
-            "COORDINATOR_ADDRESS",
-            "TPU_WORKER_HOSTNAMES",        # Cloud TPU pod runtime
-            "MEGASCALE_COORDINATOR_ADDRESS",
-        )
-    ) or kwargs
-    single = os.environ.get("TPU_WORKER_HOSTNAMES", "localhost") in (
-        "localhost", "127.0.0.1", ""
-    ) and not (kwargs or os.environ.get("JAX_COORDINATOR_ADDRESS"))
-    if multi_host_env and not single:
+    if jax.distributed.is_initialized():
+        return
+    workers = [h for h in os.environ.get(
+        "TPU_WORKER_HOSTNAMES", "").split(",") if h.strip()]
+    if (kwargs or os.environ.get("JAX_COORDINATOR_ADDRESS")
+            or len(workers) > 1):
         jax.distributed.initialize(**kwargs)
 
 

@@ -609,14 +609,10 @@ class Telemetry:
             cost_ledger, cost_summary, peak_flops_per_chip,
         )
         cled = cost_ledger(compiled_text)
-        dev_kind = None
-        try:
-            mesh = getattr(engine, "mesh", None)
-            dev = (mesh.devices.flatten()[0] if mesh is not None
-                   else jax.devices()[0])
-            dev_kind = getattr(dev, "device_kind", None)
-        except Exception:
-            pass
+        mesh = getattr(engine, "mesh", None)
+        dev = (mesh.devices.flatten()[0] if mesh is not None
+               else jax.devices()[0])
+        dev_kind = getattr(dev, "device_kind", None)
         cost = cost_summary(
             cled, device_kind=dev_kind,
             wire_bytes=float(measured.get("total_wire_bytes", 0.0)),
@@ -625,14 +621,14 @@ class Telemetry:
         self.gauge("hlo_flops", cost["total_flops"])
         self.gauge("hlo_hbm_bytes", cost["hbm_bytes"])
         self.gauge("arithmetic_intensity", cost["arithmetic_intensity"])
-        if self.timer.times:
+        # a utilization needs the device's peak: on a device the table
+        # does not know (the CPU mesh) the gauge is not emitted
+        peak = peak_flops_per_chip(dev_kind)
+        if peak is not None and self.timer.times:
             step_s = float(np.median(np.asarray(self.timer.times)))
             if step_s > 0:
-                self.gauge(
-                    "step_mfu_hlo",
-                    cost["total_flops"] / step_s
-                    / peak_flops_per_chip(dev_kind),
-                )
+                self.gauge("step_mfu_hlo",
+                           cost["total_flops"] / step_s / peak)
         # per-layer attribution for trace_view's compute spans
         self._cost_loops = [
             dict(l) for l in cled["loops"] if l.get("flops", 0.0) > 0

@@ -17,8 +17,13 @@ collective payloads.
 FLOPs
   dot:  2 * prod(result dims) * prod(lhs contracting dim sizes) — the
         contracting-dim product is read off `lhs_contracting_dims={...}`
-        against the inline lhs operand shape, so batched attention dots
-        (lhs_batch_dims) come out right without special-casing.
+        against the lhs operand's shape, so batched attention dots
+        (lhs_batch_dims) come out right without special-casing.  XLA
+        prints operands by NAME (`dot(%a.1, %b.1)`; older builds inlined
+        `f32[4,5] %a`), so each operand resolves to the shape of its
+        defining instruction in the same computation.  An operand that
+        resolves to nothing raises: a ledger that silently counted 0
+        FLOPs read as a 0 MFU on every run.
   convolution:  2 * prod(result dims) * (rhs elems / out_channels) with
         out_channels inferred as the largest dim shared by rhs and result
         — an approximation (no conv in this repo today); such lines are
@@ -63,7 +68,6 @@ from typing import Dict, List, Optional, Tuple
 from .hlo_comm import (
     _BRANCH_RE,
     _CALL_RE,
-    _DTYPE_BYTES,
     _FUSION_CALL_RE,
     _SHAPE_RE,
     _TRUE_FALSE_RE,
@@ -75,68 +79,71 @@ from .hlo_comm import (
 )
 
 # ---------------------------------------------------------------------------
-# Per-device roofline tables (public spec-sheet numbers).
+# Per-device roofline tables (public spec-sheet numbers), keyed by a
+# substring of `jax.devices()[0].device_kind` ("TPU v5 lite" on a v5e).
 #
-# Peak dense bf16 FLOP/s per chip — the same table bench.py has carried
-# since round 1 (bench._peak_flops_per_chip now delegates here so the two
-# can never drift).  HBM and interchip (ICI) bandwidths are per chip:
+# Peak dense bf16 FLOP/s per chip (bench._peak_flops_per_chip delegates
+# here so the MFU denominator and the roofline verdict cannot drift).
+# HBM and interchip (ICI) bandwidths are per chip:
 #   HBM    v4 1228 GB/s · v5e 819 GB/s · v5p 2765 GB/s · v6e 1640 GB/s
 #   ICI    v4 300 GB/s  · v5e 200 GB/s · v5p 600 GB/s  · v6e 448 GB/s
-# Unknown devices (the CPU mesh) fall back to v5e-class numbers, matching
-# bench's long-standing default peak.
+# A device that is not in the table (the CPU mesh) has NO peak: the
+# lookups return None, and everything priced against a peak (mfu_hlo,
+# step_mfu_hlo, the roofline bound) is not emitted for it.
 # ---------------------------------------------------------------------------
 
 _PEAK_FLOPS_TABLE: Tuple[Tuple[str, float], ...] = (
     ("v5 lite", 197e12), ("v5e", 197e12), ("v5p", 459e12),
     ("v6", 918e12), ("v4", 275e12),
 )
-DEFAULT_PEAK_FLOPS = 197e12
 
 _HBM_BW_TABLE: Tuple[Tuple[str, float], ...] = (
     ("v5 lite", 819e9), ("v5e", 819e9), ("v5p", 2765e9),
     ("v6", 1640e9), ("v4", 1228e9),
 )
-DEFAULT_HBM_BW = 819e9
 
 _WIRE_BW_TABLE: Tuple[Tuple[str, float], ...] = (
     ("v5 lite", 200e9), ("v5e", 200e9), ("v5p", 600e9),
     ("v6", 448e9), ("v4", 300e9),
 )
-DEFAULT_WIRE_BW = 200e9
 
 
-def _lookup(table: Tuple[Tuple[str, float], ...], default: float,
-            device_kind: Optional[str]) -> float:
+def _lookup(table: Tuple[Tuple[str, float], ...],
+            device_kind: Optional[str]) -> Optional[float]:
     kind = (device_kind or "").lower()
     for key, val in table:
         if key in kind:
             return val
-    return default
+    return None
 
 
-def peak_flops_per_chip(device_kind: Optional[str]) -> float:
-    """Peak dense bf16 FLOP/s for a device-kind string (substring match)."""
-    return _lookup(_PEAK_FLOPS_TABLE, DEFAULT_PEAK_FLOPS, device_kind)
+def peak_flops_per_chip(device_kind: Optional[str]) -> Optional[float]:
+    """Peak dense bf16 FLOP/s for a device-kind string (substring match);
+    None for a device the table does not know."""
+    return _lookup(_PEAK_FLOPS_TABLE, device_kind)
 
 
-def hbm_bw_per_chip(device_kind: Optional[str]) -> float:
-    """HBM bandwidth (bytes/s) for a device-kind string."""
-    return _lookup(_HBM_BW_TABLE, DEFAULT_HBM_BW, device_kind)
+def hbm_bw_per_chip(device_kind: Optional[str]) -> Optional[float]:
+    """HBM bandwidth (bytes/s) for a device-kind string, or None."""
+    return _lookup(_HBM_BW_TABLE, device_kind)
 
 
-def wire_bw_per_chip(device_kind: Optional[str]) -> float:
-    """Interchip (ICI) bandwidth (bytes/s) for a device-kind string."""
-    return _lookup(_WIRE_BW_TABLE, DEFAULT_WIRE_BW, device_kind)
+def wire_bw_per_chip(device_kind: Optional[str]) -> Optional[float]:
+    """Interchip (ICI) bandwidth (bytes/s) for a device-kind string, or
+    None."""
+    return _lookup(_WIRE_BW_TABLE, device_kind)
 
 
 # ---------------------------------------------------------------------------
 # Line parsing
 # ---------------------------------------------------------------------------
 
-# opcode after "= <result shape> " — tuple-typed results "(s32[], ...)" are
+# "%name = <result type> opcode(" — tuple-typed results "(s32[], ...)" are
 # a parenthesized group, plain results a non-space token
-_OP_RE = re.compile(r"=\s*(?:\([^=]*?\)|\S+)\s+([\w\-]+)\(")
+_DEF_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*(\([^=]*?\)|\S+)\s+([\w\-]+)\(")
 _LHS_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 # ops that move no HBM data of their own
 _HBM_SKIP_OPS = frozenset({
@@ -148,78 +155,99 @@ _HBM_CONTAINER_OPS = frozenset({"while", "conditional", "call"})
 
 
 def _strip_metadata(line: str) -> str:
-    """Drop `metadata={...}` — op_name strings may contain shape-like text
-    that would be mis-summed as payload."""
+    """Drop `metadata={...}` and what follows it — op_name strings may
+    contain shape-like text that would be mis-summed as payload."""
     i = line.find(", metadata=")
     return line[:i] if i >= 0 else line
 
 
-def _shapes_of(line: str) -> List[int]:
-    """Byte size of every typed shape on an (already metadata-stripped)
-    instruction line, in textual order: result first, then operands."""
-    out: List[int] = []
-    for dt, dims in _SHAPE_RE.findall(line):
-        if dt not in _DTYPE_BYTES:
+def _operand_types(line: str, op: str, defs: Dict[str, str]) -> List[str]:
+    """Type text of each operand of `op(...)` on an instruction line, in
+    order.  An operand printed with an inline type ("f32[4,5] %a") keeps
+    it; one printed by name ("%a.1") takes the type of its defining
+    instruction in the same computation (`defs`).  Raises ValueError on
+    an operand with neither."""
+    i = line.index(f" {op}(") + len(op) + 2
+    toks: List[str] = []
+    depth, start = 0, i
+    for j in range(i, len(line)):
+        ch = line[j]
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            if depth == 0:
+                toks.append(line[start:j])
+                break
+            depth -= 1
+        elif ch == "," and depth == 0:
+            toks.append(line[start:j])
+            start = j + 1
+    else:
+        raise ValueError(f"unterminated operand list: {line.strip()[:160]}")
+    out: List[str] = []
+    for tok in toks:
+        tok = tok.strip()
+        if not tok:
             continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        out.append(n * _DTYPE_BYTES[dt])
+        if "[" in tok:
+            out.append(tok.rsplit("%", 1)[0] if "%" in tok else tok)
+            continue
+        name = tok.lstrip("%")
+        if name not in defs:
+            raise ValueError(
+                f"operand {tok!r} of {op} has no inline shape and no "
+                f"defining instruction in its computation: "
+                f"{line.strip()[:160]}")
+        out.append(defs[name])
     return out
 
 
-def _dims_of(shape_txt: str) -> List[int]:
-    return [int(d) for d in shape_txt.split(",") if d]
+def _dims(type_txt: str, line: str) -> Tuple[str, List[int]]:
+    """(dtype, dims) of an array type text."""
+    m = _SHAPE_RE.search(type_txt)
+    if m is None:
+        raise ValueError(f"no array shape in {type_txt!r}: "
+                         f"{line.strip()[:160]}")
+    return m.group(1), [int(d) for d in m.group(2).split(",") if d]
 
 
-def _dot_flops(line: str) -> Tuple[float, str]:
+def _dot_flops(line: str, res_type: str,
+               defs: Dict[str, str]) -> Tuple[float, str]:
     """(FLOPs, signature) of one `dot` instruction line.
 
     FLOPs = 2 * prod(result dims) * prod(lhs contracting dim sizes).
     Batch dims are already part of the result, so no special handling.
     """
-    head, args = line.split(" dot(", 1)
-    if "=" not in head:
-        return 0.0, ""
-    res_m = _SHAPE_RE.search(head.split("=", 1)[1])
-    lhs_m = _SHAPE_RE.search(args)
-    if res_m is None or lhs_m is None:
-        return 0.0, ""
-    res_dims = _dims_of(res_m.group(2))
-    lhs_dims = _dims_of(lhs_m.group(2))
+    ops = _operand_types(line, "dot", defs)
+    res_dt, res_dims = _dims(res_type, line)
+    lhs_dt, lhs_dims = _dims(ops[0], line)
     cm = _LHS_CONTRACT_RE.search(line)
     contract = [int(d) for d in cm.group(1).split(",") if d] if cm else []
     k = 1
     for c in contract:
-        if c < len(lhs_dims):
-            k *= lhs_dims[c]
+        k *= lhs_dims[c]
     n = 1
     for d in res_dims:
         n *= d
-    # signature: result <- lhs, for cost-center aggregation
-    shapes = _SHAPE_RE.findall(args)
-    rhs_txt = ("%s[%s]" % shapes[1]) if len(shapes) > 1 else "?"
-    sig = "dot %s[%s] <- %s[%s] x %s" % (
-        res_m.group(1), res_m.group(2), lhs_m.group(1), lhs_m.group(2),
-        rhs_txt,
-    )
+    # signature: result <- lhs x rhs, for cost-center aggregation
+    rhs_dt, rhs_dims = _dims(ops[1], line)
+
+    def fmt(dt, dims):
+        return "%s[%s]" % (dt, ",".join(map(str, dims)))
+
+    sig = "dot %s <- %s x %s" % (
+        fmt(res_dt, res_dims), fmt(lhs_dt, lhs_dims), fmt(rhs_dt, rhs_dims))
     return 2.0 * n * k, sig
 
 
-def _conv_flops(line: str) -> Tuple[float, str]:
+def _conv_flops(line: str, res_type: str,
+                defs: Dict[str, str]) -> Tuple[float, str]:
     """Approximate convolution FLOPs: 2 * out_elems * rhs_elems /
     out_channels, with out_channels = the largest dim shared by rhs and
     result.  Flagged via `approx_ops` — this repo emits no convolutions."""
-    head, args = line.split(" convolution(", 1)
-    if "=" not in head:
-        return 0.0, ""
-    res_m = _SHAPE_RE.search(head.split("=", 1)[1])
-    shapes = _SHAPE_RE.findall(args)
-    if res_m is None or len(shapes) < 2:
-        return 0.0, ""
-    res_dims = _dims_of(res_m.group(2))
-    rhs_dims = _dims_of(shapes[1][1])
+    ops = _operand_types(line, "convolution", defs)
+    res_dt, res_dims = _dims(res_type, line)
+    _, rhs_dims = _dims(ops[1], line)
     shared = [d for d in rhs_dims if d in res_dims]
     out_ch = max(shared) if shared else 1
     n = 1
@@ -228,29 +256,26 @@ def _conv_flops(line: str) -> Tuple[float, str]:
     k = 1
     for d in rhs_dims:
         k *= d
-    sig = "convolution %s[%s]" % (res_m.group(1), res_m.group(2))
+    sig = "convolution %s[%s]" % (res_dt, ",".join(map(str, res_dims)))
     return 2.0 * n * (k / max(out_ch, 1)), sig
 
 
-def _hbm_bytes_of_line(line: str, op: str) -> float:
+def _hbm_bytes_of_line(line: str, name: str, op: str, res_type: str,
+                       defs: Dict[str, str]) -> float:
     """HBM traffic model for one instruction: operands + result, with the
     dynamic-update-slice aliasing special case (see module docstring)."""
-    seg = _strip_metadata(line)
-    shapes = _shapes_of(seg)
-    if not shapes:
-        return 0.0
-    if op == "dynamic-update-slice" or "dynamic-update-slice" in \
-            seg.split("=", 1)[0]:
-        # result first, then operands; destination operand aliases the
-        # result — drop both, count the update slice for read AND write
-        result, operands = shapes[0], shapes[1:]
+    result = _shape_bytes(res_type)
+    operands = [_shape_bytes(t) for t in _operand_types(line, op, defs)]
+    if op == "dynamic-update-slice" or "dynamic-update-slice" in name:
+        # the destination operand aliases the result — drop both, count
+        # the update slice for read AND write
         dest_i = next((i for i, b in enumerate(operands) if b == result),
                       None)
         if dest_i is not None:
             rest = operands[:dest_i] + operands[dest_i + 1:]
             upd = max(rest) if rest else 0
             return float(sum(rest) + upd)
-    return float(sum(shapes))
+    return float(result + sum(operands))
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +313,7 @@ def cost_ledger(compiled_text: str) -> Dict[str, object]:
                 fusion_payloads.add(m.group(1))
 
     # per-computation local stats + call edges
-    local_flops: Dict[str, List[Tuple[str, float, str, float]]] = {}
+    local_flops: Dict[str, List[Tuple[str, float, str]]] = {}
     local_hbm: Dict[str, float] = {}
     edges: Dict[str, List[Tuple[str, float, str, bool]]] = {}
     unresolved: List[str] = []
@@ -299,23 +324,26 @@ def cost_ledger(compiled_text: str) -> Dict[str, object]:
         local_hbm[name] = 0.0
         edges[name] = []
         count_hbm = name not in fusion_payloads
-        for ln in lines:
-            if "=" not in ln:
+        # long operand / tuple-type lists carry position comments
+        # ("/*index=5*/%x") whose "=" would derail the parse; metadata
+        # (always after the attributes read below) carries op_name
+        # strings with shape-like text
+        lines = [_strip_metadata(_COMMENT_RE.sub("", ln)) for ln in lines]
+        parsed = [(ln, _DEF_RE.match(ln)) for ln in lines]
+        # instruction name -> result type text, for operands printed by
+        # name only
+        defs = {m.group(1): m.group(2) for _, m in parsed if m}
+        for ln, dm in parsed:
+            if dm is None:
                 continue
-            if " dot(" in ln:
-                fl, sig = _dot_flops(ln)
-                if fl:
-                    local_flops[name].append(
-                        ("dot", fl, sig, _hbm_bytes_of_line(ln, "dot")))
-            elif " convolution(" in ln:
-                fl, sig = _conv_flops(ln)
-                if fl:
-                    local_flops[name].append(
-                        ("convolution", fl, sig,
-                         _hbm_bytes_of_line(ln, "convolution")))
-                    approx_ops.append(ln.strip()[:160])
-            om = _OP_RE.search(ln)
-            op = om.group(1) if om else None
+            iname, res_type, op = dm.groups()
+            if op == "dot":
+                fl, sig = _dot_flops(ln, res_type, defs)
+                local_flops[name].append(("dot", fl, sig))
+            elif op == "convolution":
+                fl, sig = _conv_flops(ln, res_type, defs)
+                local_flops[name].append(("convolution", fl, sig))
+                approx_ops.append(ln.strip()[:160])
             wm = _WHILE_RE.search(ln)
             if wm:
                 cond, body = wm.group(1), wm.group(2)
@@ -340,9 +368,10 @@ def cost_ledger(compiled_text: str) -> Dict[str, object]:
             for tm in _TRUE_FALSE_RE.finditer(ln):
                 if tm.group(1) in comps:
                     edges[name].append((tm.group(1), 1.0, "branch", True))
-            if count_hbm and op is not None and op not in _HBM_SKIP_OPS \
+            if count_hbm and op not in _HBM_SKIP_OPS \
                     and op not in _HBM_CONTAINER_OPS:
-                local_hbm[name] += _hbm_bytes_of_line(ln, op)
+                local_hbm[name] += _hbm_bytes_of_line(
+                    ln, iname, op, res_type, defs)
 
     # entry = computation nobody calls (prefer one whose name says so)
     called = {b for es in edges.values() for b, _, _, _ in es}
@@ -366,7 +395,7 @@ def cost_ledger(compiled_text: str) -> Dict[str, object]:
             return 0.0, 0.0
         if comp in _sub_memo:
             return _sub_memo[comp]
-        fl = sum(f for _, f, _, _ in local_flops.get(comp, []))
+        fl = sum(f for _, f, _ in local_flops.get(comp, []))
         hb = local_hbm.get(comp, 0.0)
         for tgt, trips, kind, _res in edges.get(comp, []):
             m = trips if kind in ("while", "while-cond") else 1.0
@@ -381,7 +410,7 @@ def cost_ledger(compiled_text: str) -> Dict[str, object]:
         nonlocal flops_in_loops, hbm_total, hbm_in_loops
         if comp in seen:
             return
-        for op, fl, sig, _hb in local_flops.get(comp, []):
+        for op, fl, sig in local_flops.get(comp, []):
             flops_by_op[op] = flops_by_op.get(op, 0.0) + mult * fl
             count_by_op[op] = count_by_op.get(op, 0.0) + mult
             if in_loop:
@@ -445,15 +474,20 @@ def roofline_verdict(total_flops: float, hbm_bytes: float,
     is the bound.  `arithmetic_intensity` (FLOPs/HBM byte) vs
     `ridge_intensity` (peak FLOPs / HBM BW) is the classic roofline view
     of the compute-vs-HBM race; the wire axis extends it with the ledger's
-    measured collective bytes.
+    measured collective bytes.  Raises ValueError for a device the peak
+    tables do not know (and no explicit peaks): a roofline needs a roof.
     """
     peak = peak if peak is not None else peak_flops_per_chip(device_kind)
     hbm_bw = hbm_bw if hbm_bw is not None else hbm_bw_per_chip(device_kind)
     wire_bw = wire_bw if wire_bw is not None \
         else wire_bw_per_chip(device_kind)
-    t_compute = total_flops / peak if peak > 0 else 0.0
-    t_hbm = hbm_bytes / hbm_bw if hbm_bw > 0 else 0.0
-    t_wire = wire_bytes / wire_bw if wire_bw > 0 else 0.0
+    if peak is None or hbm_bw is None or wire_bw is None:
+        raise ValueError(
+            f"no peak FLOP/s / bandwidth known for device_kind="
+            f"{device_kind!r}: a roofline verdict needs the device's peaks")
+    t_compute = total_flops / peak
+    t_hbm = hbm_bytes / hbm_bw
+    t_wire = wire_bytes / wire_bw
     times = {"compute": t_compute, "hbm": t_hbm, "wire": t_wire}
     bound = max(times, key=lambda k: times[k]) if any(times.values()) \
         else "compute"
@@ -461,7 +495,7 @@ def roofline_verdict(total_flops: float, hbm_bytes: float,
         "bound": bound,
         "arithmetic_intensity": (total_flops / hbm_bytes)
         if hbm_bytes > 0 else 0.0,
-        "ridge_intensity": peak / hbm_bw if hbm_bw > 0 else 0.0,
+        "ridge_intensity": peak / hbm_bw,
         "t_compute_s": t_compute,
         "t_hbm_s": t_hbm,
         "t_wire_s": t_wire,
@@ -475,24 +509,23 @@ def cost_summary(led: Dict[str, object],
                  device_kind: Optional[str] = None,
                  wire_bytes: float = 0.0,
                  top_n: int = 3) -> Dict[str, object]:
-    """Compact JSON-safe summary of a cost ledger + roofline verdict —
-    what rides in telemetry run_meta and bench `extra.hlo_cost`."""
-    verdict = roofline_verdict(
-        float(led["total_flops"]), float(led["hbm_bytes"]),
-        wire_bytes=wire_bytes, device_kind=device_kind)
-    total = float(led["total_flops"]) or 1.0
-    return {
-        "total_flops": float(led["total_flops"]),
+    """Compact JSON-safe summary of a cost ledger — what rides in
+    telemetry run_meta and bench `extra.hlo_cost`.  The counts (FLOPs,
+    modeled HBM bytes, wire bytes, arithmetic intensity, cost centers)
+    are always present; the roofline fields (`bound`, `ridge_intensity`,
+    `t_*_s`) only for a device the peak tables know — a CPU run emits
+    counts, never a verdict priced at some other chip's peaks."""
+    total_flops = float(led["total_flops"])
+    hbm_bytes = float(led["hbm_bytes"])
+    total = total_flops or 1.0
+    out: Dict[str, object] = {
+        "total_flops": total_flops,
         "flops_in_loops": float(led["flops_in_loops"]),
-        "hbm_bytes": float(led["hbm_bytes"]),
+        "hbm_bytes": hbm_bytes,
         "hbm_bytes_in_loops": float(led["hbm_bytes_in_loops"]),
         "wire_bytes": float(wire_bytes),
-        "arithmetic_intensity": verdict["arithmetic_intensity"],
-        "ridge_intensity": verdict["ridge_intensity"],
-        "bound": verdict["bound"],
-        "t_compute_s": verdict["t_compute_s"],
-        "t_hbm_s": verdict["t_hbm_s"],
-        "t_wire_s": verdict["t_wire_s"],
+        "arithmetic_intensity": (total_flops / hbm_bytes)
+        if hbm_bytes > 0 else 0.0,
         "top_cost_centers": [
             {"sig": c["sig"], "flops": float(c["flops"]),
              "count": float(c["count"]), "in_loop": bool(c["in_loop"]),
@@ -502,6 +535,14 @@ def cost_summary(led: Dict[str, object],
         "unresolved_loops": len(list(led["unresolved_loops"])),
         "approx_ops": len(list(led["approx_ops"])),
     }
+    if peak_flops_per_chip(device_kind) is not None:
+        verdict = roofline_verdict(
+            total_flops, hbm_bytes, wire_bytes=wire_bytes,
+            device_kind=device_kind)
+        out.update({k: verdict[k] for k in (
+            "ridge_intensity", "bound", "t_compute_s", "t_hbm_s",
+            "t_wire_s")})
+    return out
 
 
 def hlo_cost_report(engine, state, batch) -> Dict[str, object]:
@@ -511,11 +552,7 @@ def hlo_cost_report(engine, state, batch) -> Dict[str, object]:
     text = compiled.as_text()
     led = cost_ledger(text)
     wire = float(collective_ledger(text).get("total_wire_bytes", 0.0))
-    dev = None
-    try:
-        import jax
-        dev = jax.devices()[0].device_kind
-    except Exception:
-        pass
+    import jax
+    dev = jax.devices()[0].device_kind
     return {"ledger": led,
             "summary": cost_summary(led, device_kind=dev, wire_bytes=wire)}
