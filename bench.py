@@ -1417,7 +1417,6 @@ def _write_bench_telemetry(path, engine, state, batch, compiled_text,
     The JSONL renders with scripts/report_run.py; the record's
     extra.telemetry_jsonl points here."""
     from tiny_deepspeed_tpu.telemetry.schema import SCHEMA_VERSION
-    from tiny_deepspeed_tpu.telemetry.trace import collective_span_template
     from tiny_deepspeed_tpu.utils.hlo_comm import (
         collective_ledger, ledger_summary, overlap_report,
     )
@@ -1446,24 +1445,6 @@ def _write_bench_telemetry(path, engine, state, batch, compiled_text,
             **({"flops_per_token_matmul": float(flops_tok_matmul)}
                if flops_tok_matmul is not None else {}),
             **({"hlo_cost": hlo_cost} if hlo_cost else {}),
-        )
-        # step-trace span template: trace_view.py renders the sidecar's
-        # timeline without recompiling the step
-        cost_loops = None
-        if hlo_cost:
-            from tiny_deepspeed_tpu.telemetry.trace import (
-                compute_span_template,
-            )
-            from tiny_deepspeed_tpu.utils.hlo_cost import cost_ledger
-            _cl = cost_ledger(compiled_text)
-            cost_loops = compute_span_template(
-                [lo for lo in _cl["loops"] if lo.get("flops", 0.0) > 0],
-                float(_cl["total_flops"]),
-            )
-        ml.log_meta(
-            kind="trace",
-            spans=collective_span_template(measured),
-            **({"compute_spans": cost_loops} if cost_loops else {}),
         )
         for i in range(steps):
             with timer.step() as tm:

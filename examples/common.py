@@ -30,6 +30,7 @@ from tiny_deepspeed_tpu import (
     make_mesh,
 )
 from tiny_deepspeed_tpu.models import ALL_PRESETS, build_model
+from tiny_deepspeed_tpu.utils.profiling import span
 from tiny_deepspeed_tpu.utils.startup import select_platform
 
 
@@ -299,14 +300,15 @@ def parse_args(default_model="gpt2-124m", argv=None, **defaults):
              "(grad/update/param norms, non-finite counts), step-time "
              "breakdown (data wait / host->device / compute) with "
              "recompile detection, HBM watermarks, measured HLO-ledger "
-             "collective bytes + step-trace span template in the meta "
+             "collective bytes in the meta "
              "records, a flight recorder flushed on anomalies, and "
              "straggler gauges.  '--telemetry layers' additionally "
              "computes PER-LAYER health inside the block scan "
              "(grad/activation norms + non-finite counts; the first-NaN "
              "layer localized in one step — plain-scan engines, "
              "GPT-2/Llama).  Pairs with --metrics; render with "
-             "scripts/report_run.py and scripts/trace_view.py",
+             "scripts/report_run.py (a step's timeline is a profiler "
+             "trace: --profile)",
     )
     p.add_argument(
         "--telemetry-trace", default=None, metavar="DIR",
@@ -683,9 +685,10 @@ def run(engine_cls, args, single_device=False, cfg_overrides=None,
                 # pushes the aux un-synced; the compiled program is identical
                 # on every rank)
                 with telem.step(index=it) as t:
-                    idx, tgt = loader.next()
+                    idx, tgt = loader.next()      # its own tds.load span
                     t.mark("data")
-                    batch = (jnp.asarray(idx), jnp.asarray(tgt))
+                    with span("tds.h2d"):
+                        batch = (jnp.asarray(idx), jnp.asarray(tgt))
                     t.mark("h2d")
                     host_prep_s += time.perf_counter() - it_t0
                     state, loss = engine.step(state, batch)
@@ -715,7 +718,8 @@ def run(engine_cls, args, single_device=False, cfg_overrides=None,
                 # device 0 and the step's in_shardings reshard it — correct,
                 # but one extra device-to-device hop per step on a
                 # multi-chip host (PERF.md "Open questions")
-                batch = (jnp.asarray(idx), jnp.asarray(tgt))
+                with span("tds.h2d"):
+                    batch = (jnp.asarray(idx), jnp.asarray(tgt))
                 host_prep_s += time.perf_counter() - it_t0
                 state, loss = engine.step(state, batch)
                 ran += 1
@@ -724,7 +728,8 @@ def run(engine_cls, args, single_device=False, cfg_overrides=None,
                     # consumed — other ranks run ahead and overlap
                     # loader.next() with device compute (MetricsLogger.log is
                     # rank-0 gated too)
-                    loss_f = float(loss)
+                    with span("tds.sync"):
+                        loss_f = float(loss)
                     it_dt = time.perf_counter() - it_t0
                     print(f"iter {it:3d} loss {loss_f:.4f}")
                     if metrics is not None:
@@ -815,23 +820,6 @@ def run(engine_cls, args, single_device=False, cfg_overrides=None,
                 n_params=model.num_params(), batch=b,
                 seq_len=args.seq_len, tokens_per_step=b * args.seq_len,
             ))
-            spans = telem.trace_spans()
-            cspans = telem.compute_trace_spans()
-            pipe_tr = telem.pipe_trace(engine)
-            if spans or cspans or pipe_tr:
-                # step-trace span template (telemetry/trace.py): the
-                # compiled step's collectives by (op, loop residency)
-                # with exact ledger wire bytes, plus the compute spans
-                # sized by HLO-counted FLOPs (utils/hlo_cost.py) and —
-                # under a table pipeline schedule — the tick program's
-                # per-stage rows; scripts/trace_view.py joins all three
-                # with the per-step wall segments above
-                metrics.log_meta(
-                    kind="trace",
-                    **({"spans": spans} if spans else {}),
-                    **({"compute_spans": cspans} if cspans else {}),
-                    **({"pipe": pipe_tr} if pipe_tr else {}),
-                )
         if ran:
             # per-host straggler attribution over the UNCOUPLED host-side
             # prep wall (data load + staging): collectives equalize the
@@ -863,7 +851,5 @@ def run(engine_cls, args, single_device=False, cfg_overrides=None,
                   f"compiles {tm.compile_count}")
             if getattr(args, "metrics", None):
                 print("run report: python scripts/report_run.py "
-                      f"{args.metrics}")
-                print("step timeline: python scripts/trace_view.py "
                       f"{args.metrics}")
     return engine, state

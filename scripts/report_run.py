@@ -413,12 +413,6 @@ def render_report(metas: List[dict], steps: List[dict],
                     f"| {h.get('max', 0):.4g} |"
                 )
             out.append("")
-    if _meta(metas, "trace") is not None:
-        out.append(
-            "Step timeline: `python scripts/trace_view.py "
-            f"{source or 'RUN.jsonl'}` -> Chrome-trace JSON "
-            "(chrome://tracing / Perfetto).\n"
-        )
     return "\n".join(out) + "\n"
 
 

@@ -1,25 +1,20 @@
 # Copyright 2026 tiny-deepspeed-tpu authors
 # SPDX-License-Identifier: Apache-2.0
 
-"""Export a run's metrics JSONL as a Chrome-trace timeline.
+"""Export a serving run's metrics JSONL as a Chrome-trace timeline.
 
     python scripts/trace_view.py RUN.jsonl [-o TRACE.json]
 
 Load TRACE.json in chrome://tracing or https://ui.perfetto.dev.
 
-TRAINING runs show, per step: the whole-step span, the measured host
-wall segments (data wait / host->device / device compute+sync —
-StepTimer `mark()`), and the compiled step's collective spans from the
-HLO ledger (`utils/hlo_comm.py`) instantiated inside the compute window
-— widths proportional to wire bytes (schematic), annotations exact:
-wire bytes, op count, per-dtype split, loop-resident flag.
-
-SERVING runs (auto-detected from `request`/`tick` records — the
-`serve_bench.py` sidecar or any ServingEngine with a logger) show the
-scheduler ticks with their measured wall split, a queue track of
-request wait windows, and one track per decode slot with each request's
-active windows — preemptions, quarantines, and watchdog warm restarts
-visible as span boundaries and instant markers.
+SERVING runs (`request`/`tick` records — the `serve_bench.py` sidecar or
+any ServingEngine with a logger) show the scheduler ticks with their
+parts at their measured starts, a queue track of request wait windows,
+and one track per decode slot with each request's active windows —
+preemptions, quarantines, and watchdog warm restarts visible as span
+boundaries and instant markers.  Where a TRAINING step's time goes is
+read from a profiler trace (`--profile`; `python benchmarks/run.py
+--trace 1`), which carries the program's own `tds.*` spans and scopes.
 
 FLEET serving files (records carrying `replica_id`) lay out one process
 per replica, each with the full tick/queue/slot track set; a request
@@ -34,12 +29,10 @@ after), which is how flight flushes land on the right engine lifetime
 when two lifetimes' tick counters both start at 0.
 
 Span assembly lives in `tiny_deepspeed_tpu/telemetry/trace.py`; the
-input comes from `examples/* --telemetry --metrics RUN.jsonl` (which
-also writes the `trace` span-template record), `bench.py`'s telemetry
-sidecar, or `scripts/serve_bench.py`'s sidecar.
+input comes from `scripts/serve_bench.py`'s sidecar.
 
 Exit codes: 0 ok; 1 parse errors in the JSONL; 2 missing/empty input or
-no timed step/tick/request records to lay out.
+no tick/request records to lay out.
 """
 
 from __future__ import annotations
@@ -72,7 +65,7 @@ trace = _load_trace_module()
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("jsonl", help="metrics JSONL from a training run")
+    ap.add_argument("jsonl", help="metrics JSONL from a serving run")
     ap.add_argument("-o", "--out", default=None,
                     help="write the Chrome-trace JSON here "
                          "(default: <input>.trace.json)")
@@ -87,31 +80,17 @@ def main(argv=None) -> int:
         print(f"{args.jsonl}: no records (empty or fully truncated "
               "metrics file)", file=sys.stderr)
         return 2
-    serving = trace.has_serving_records(metas)
-    timed_steps = any(
-        isinstance(r.get("ts"), (int, float))
-        and isinstance(r.get("step_s"), (int, float)) for r in steps
-    )
-    if serving and not timed_steps:
-        doc = trace.serving_chrome_trace(metas, source=args.jsonl)
-        laid_out = "tick(s)/request(s)"
-        n_laid = (doc["otherData"]["ticks"]
-                  + doc["otherData"]["requests"])
-    else:
-        doc = trace.chrome_trace(metas, steps, source=args.jsonl)
-        laid_out = "step(s)"
-        n_laid = len(steps)
-        if serving:
-            # a file carrying BOTH (a combined sidecar): serving tracks
-            # join the training timeline as their own process (pid 1)
-            doc["traceEvents"].extend(
-                trace.serving_chrome_trace(
-                    metas, source=args.jsonl)["traceEvents"])
+    if not trace.has_serving_records(metas):
+        print(f"{args.jsonl}: no serving tick/request records (a "
+              "training step's timeline is a profiler trace: --profile)",
+              file=sys.stderr)
+        return 2
+    doc = trace.serving_chrome_trace(metas, source=args.jsonl)
+    laid_out = "tick(s)/request(s)"
+    n_laid = doc["otherData"]["ticks"] + doc["otherData"]["requests"]
     n_spans = sum(1 for e in doc["traceEvents"] if e.get("ph") == "X")
     if not n_spans:
-        print(f"{args.jsonl}: no timed step records (run with "
-              "--telemetry --metrics to record step_s + wall segments) "
-              "and no serving tick/request records",
+        print(f"{args.jsonl}: no timed serving tick/request records",
               file=sys.stderr)
         return 2
     out = args.out or (os.path.splitext(args.jsonl)[0] + ".trace.json")

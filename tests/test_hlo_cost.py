@@ -252,23 +252,6 @@ class TestRoofline:
         assert s["top_cost_centers"][0]["share"] <= 1.0
         json.dumps(s)  # JSON-safe by construction
 
-    def test_compute_span_template(self):
-        from tiny_deepspeed_tpu.telemetry.trace import (
-            compute_span_template,
-        )
-        led = cost_ledger(SYN_LOOP)
-        spans = compute_span_template(
-            [lo for lo in led["loops"] if lo["flops"] > 0],
-            float(led["total_flops"]),
-        )
-        # 3 per-trip spans (trips=3 <= 64) + 1 top-level
-        loop_spans = [s for s in spans if s["loop_resident"]]
-        top = [s for s in spans if not s["loop_resident"]]
-        assert len(loop_spans) == 3 and len(top) == 1
-        assert sum(s["flops"] for s in spans) == pytest.approx(840.0)
-        assert top[0]["flops"] == pytest.approx(240.0)
-        assert all(s["schematic"] for s in spans)
-
 
 # ---------------------------------------------------------------------------
 # compiled-program pins (abstract state: eval_shape, no real buffers)

@@ -22,8 +22,6 @@ mesh or a compile:
   * geometry refusals (ValueError from the builder, ScheduleConflictError
     from build_schedule) and the pipe x {gather, grad, probe, MoE, busy
     axes} named refusals.
-  * the trace viewer's pipe track: per-stage rows, strict-JSON
-    round-trip, bubble visible as whitespace (idle ticks emit nothing).
 
 Engine-level parity across 1f1b / interleaved / zbub and the legacy HLO
 determinism pin are slow-marked (zero-sum tier-1 budget): they compile.
@@ -34,23 +32,19 @@ test_profiling xfails document).
 """
 
 import hashlib
-import importlib.util
 import json
 import os
 import subprocess
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tiny_deepspeed_tpu import AdamW, DDP, GPTConfig, GPT2Model, Telemetry
+from tiny_deepspeed_tpu import AdamW, DDP, GPTConfig, GPT2Model
 from tiny_deepspeed_tpu.parallel import schedule as S
 from tiny_deepspeed_tpu.parallel import pipe_schedule as PS
-from tiny_deepspeed_tpu.telemetry import schema, trace
-from tiny_deepspeed_tpu.utils import MetricsLogger
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -429,94 +423,6 @@ class TestEngineValidation:
             DDP(model4, AdamW(lr=1e-3), pipeline_parallel=2,
                 pipeline_microbatches=4,
                 pipeline_schedule="interleaved:2", grad_comm="int8")
-
-
-# ---------------------------------------------------------------------------
-# telemetry: the pipe trace track (quick — programs only, no engine)
-# ---------------------------------------------------------------------------
-
-def _fake_engine(prog):
-    return types.SimpleNamespace(
-        _schedule=types.SimpleNamespace(pipe_program=prog))
-
-
-class TestPipeTrace:
-    def test_pipe_trace_serializes_program(self):
-        prog = PS.build_pipe_program(2, 2, 4, split_w=True)
-        rec = Telemetry().pipe_trace(_fake_engine(prog))
-        assert rec["describe"] == prog.describe()
-        assert rec["n_ticks"] == prog.n_ticks
-        assert rec["bubble_frac"] == pytest.approx(prog.bubble_frac,
-                                                   abs=1e-6)
-        # row-major per STAGE (transposed from the (T, S) table)
-        assert len(rec["op"]) == 2 and len(rec["op"][0]) == prog.n_ticks
-        json.dumps(rec, allow_nan=False)   # strict-JSON serializable
-        assert Telemetry().pipe_trace(
-            types.SimpleNamespace(_schedule=None)) is None
-
-    def test_pipe_span_rows_skip_idle(self):
-        prog = PS.build_pipe_program(2, 1, 4)
-        rec = Telemetry().pipe_trace(_fake_engine(prog))
-        rows = trace.pipe_span_rows(rec)
-        assert len(rows) == 2
-        assert sum(len(r) for r in rows) == int(prog.busy.sum())
-        sp = rows[0][0]
-        assert sp["name"] == "F c0 m0" and sp["schematic"] is True
-        assert all(s["op"] in ("F", "B", "W") for r in rows for s in r)
-
-    def test_chrome_trace_pipe_track_strict_json(self, tmp_path):
-        """The full viewer path: JSONL -> schema-clean -> chrome trace
-        with one tid per stage, strict-JSON round-trip (the NaN-loss
-        postmortem case included)."""
-        prog = PS.build_pipe_program(2, 2, 4, split_w=True)
-        rec = Telemetry().pipe_trace(_fake_engine(prog))
-        path = str(tmp_path / "pipe.jsonl")
-        with MetricsLogger(path, stdout=False) as ml:
-            ml.log_meta(kind="trace", spans=[], pipe=rec)
-            for i in range(2):
-                ml.log(i, loss=(float("nan") if i else 2.5), step_s=0.5,
-                       tokens_per_s=1024.0, data_s=0.05, h2d_s=0.05,
-                       compute_s=0.4)
-        counts, errs = schema.validate_file(path)
-        assert errs == [] and counts["meta"] == 1 and counts["step"] == 2
-        metas, steps, lerrs = trace.load_run(path)
-        assert lerrs == []
-        doc = trace.chrome_trace(metas, steps, source=path)
-        assert doc["otherData"]["schematic_pipeline"] is True
-        assert doc["otherData"]["pipeline_bubble_frac"] == pytest.approx(
-            prog.bubble_frac, abs=1e-6)
-        names = [e["args"]["name"] for e in doc["traceEvents"]
-                 if e.get("name") == "thread_name"]
-        assert any(n.startswith("pipe stage 0") for n in names)
-        assert any(n.startswith("pipe stage 1") for n in names)
-        pipe_events = [e for e in doc["traceEvents"]
-                       if e.get("ph") == "X" and e.get("tid", 0) >= 4]
-        # per step: one span per non-idle tick across both stages
-        assert len(pipe_events) == 2 * int(prog.busy.sum())
-        assert {e["args"]["op"] for e in pipe_events} == {"F", "B", "W"}
-        # strict JSON: Perfetto/chrome reject bare NaN — the round-trip
-        # must survive json with NaN forbidden
-        json.loads(json.dumps(doc, allow_nan=False))
-
-    def test_trace_view_cli_renders_pipe(self, tmp_path):
-        prog = PS.build_pipe_program(2, 1, 2)
-        rec = Telemetry().pipe_trace(_fake_engine(prog))
-        path = str(tmp_path / "run.jsonl")
-        with MetricsLogger(path, stdout=False) as ml:
-            ml.log_meta(kind="trace", spans=[], pipe=rec)
-            ml.log(0, loss=2.0, step_s=0.3, tokens_per_s=512.0,
-                   compute_s=0.25)
-        spec = importlib.util.spec_from_file_location(
-            "trace_view_under_test",
-            os.path.join(REPO, "scripts", "trace_view.py"))
-        tv = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(tv)
-        out = str(tmp_path / "t.trace.json")
-        assert tv.main([path, "-o", out]) == 0
-        doc = json.load(open(out))
-        assert doc["otherData"]["schematic_pipeline"] is True
-        assert any(e.get("tid", 0) >= 4 and e.get("ph") == "X"
-                   for e in doc["traceEvents"])
 
 
 # ---------------------------------------------------------------------------

@@ -497,7 +497,9 @@ def test_hlo_cost_schema_v12_names():
     assert v12_gauges <= set(schema.GAUGES), (
         v12_gauges - set(schema.GAUGES))
     assert schema.META_FIELDS.get("hlo_cost") is dict
-    assert "compute_spans" in schema.META_FIELDS
+    # the schematic timeline's template fields went with it (schema v16)
+    assert "compute_spans" not in schema.META_FIELDS
+    assert "trace" not in schema.META_KINDS
     with open(os.path.join(
             REPO, "tiny_deepspeed_tpu", "telemetry", "registry.py")) as f:
         reg_src = f.read()

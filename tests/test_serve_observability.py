@@ -324,22 +324,31 @@ class TestServingTraceExport:
                   if e.get("ph") == "X" and "args" in e]
         assert "quarantined" in closed
 
-    def test_segment_spans_sum_within_tick_walls(self, trace_doc):
-        """Per tick: the laid-out sched/prefill/decode/fetch spans sum
-        to within the measured tick wall (their widths are measured,
-        only the position inside the tick is schematic)."""
+    def test_tick_parts_sit_at_their_measured_starts(self, trace_doc):
+        """Per tick: its parts (the tick record's `spans`, the engine's
+        tick_records segments) lie inside the measured tick wall, one
+        after the other, and nothing is schematic any more."""
         ev = trace_doc["traceEvents"]
         ticks = [e for e in ev if e.get("ph") == "X"
                  and str(e.get("name", "")).startswith("tick ")]
-        segs = [e for e in ev if e.get("ph") == "X"
-                and e.get("args", {}).get("schematic_position")]
+        segs = sorted((e for e in ev if e.get("ph") == "X"
+                       and e.get("tid") == 1 and "seconds" in e["args"]),
+                      key=lambda e: e["ts"])
         assert ticks and segs
+        assert not any("schematic" in k for e in ev
+                       for k in e.get("args", {}))
+        # (`observe` is still open when the record is written)
+        assert {"sched", "decode.operands", "decode.dispatch",
+                "decode.fetch", "commit"} <= {s["name"] for s in segs}
         for t in ticks:
             inside = [s for s in segs
                       if t["ts"] - 1 <= s["ts"] <= t["ts"] + t["dur"] + 1]
-            if not inside:
-                continue
-            assert sum(s["dur"] for s in inside) <= t["dur"] + 2e3, t
+            assert inside, t
+            for s in inside:
+                assert s["ts"] + s["dur"] <= t["ts"] + t["dur"] + 2
+            for a, b in zip(inside, inside[1:]):
+                assert a["ts"] + a["dur"] <= b["ts"] + 2   # disjoint
+            assert sum(s["dur"] for s in inside) <= t["dur"] + 2
 
     def test_queue_and_slot_walls_positive(self, trace_doc):
         spans = [e for e in trace_doc["traceEvents"]

@@ -183,7 +183,10 @@ class TestTelemetryOffIsFree:
         engines = {False: ddp_off, True: eng_on}
         for eng, state in engines.items():  # warm both compiles
             states[eng], _ = engines[eng].step(states[eng], batch)
-        for _ in range(16):
+        # 32 interleaved rounds: the minimum needs one quiet sample of each
+        # engine, and under the tier-1 run's six workers 16 rounds did not
+        # always hold one (PR 26: 15.7 ms against 10.3 ms, once)
+        for _ in range(32):
             for on in (False, True):
                 timer = timers[on]
                 with timer.step() as t:
@@ -440,8 +443,8 @@ class TestExampleEndToEnd:
         assert "telemetry=on" in r.stdout
         counts, errs = schema.validate_file(jsonl)
         assert errs == []
-        # run_meta + trace (span template) + straggler + telemetry_summary
-        assert counts["step"] == 4 and counts["meta"] == 4
+        # run_meta + straggler + telemetry_summary
+        assert counts["step"] == 4 and counts["meta"] == 3
         rr = _load_report_run()
         metas, steps, _ = rr.load_run(jsonl)
         report = rr.render_report(metas, steps, source=jsonl)
@@ -454,33 +457,6 @@ class TestExampleEndToEnd:
         assert meta["comm_measured"]["total_wire_bytes"] > 0
         assert meta["comm_model"]["grad_allreduce_bytes"] > 0
         assert meta["schema_version"] == schema.SCHEMA_VERSION
-        # acceptance (ISSUE 5): trace_view.py emits valid Chrome-trace
-        # JSON for this CPU-mesh ddp run, and every loop-resident
-        # collective span carries wire bytes matching the hlo_comm ledger
-        trace_json = str(tmp_path / "ddp_run.trace.json")
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts",
-                                          "trace_view.py"),
-             jsonl, "-o", trace_json],
-            capture_output=True, text=True, timeout=120,
-        )
-        assert r.returncode == 0, r.stderr[-2000:]
-        doc = json.load(open(trace_json))
-        assert doc["traceEvents"]
-        ledger_loops = meta["comm_measured"]["wire_bytes_in_loops"]
-        # collective spans only: with the FLOP ledger counting again the
-        # trace also carries loop-resident COMPUTE spans (flops, no wire)
-        loop_spans = [
-            e for e in doc["traceEvents"]
-            if e.get("ph") == "X"
-            and e.get("args", {}).get("loop_resident")
-            and "wire_bytes" in e["args"]
-        ]
-        assert loop_spans
-        for e in loop_spans:
-            assert e["args"]["wire_bytes"] == pytest.approx(
-                ledger_loops[e["args"]["op"]], rel=1e-6,
-            )
 
 
 class TestBenchTelemetrySidecar:
@@ -527,8 +503,8 @@ class TestBenchTelemetrySidecar:
         )
         counts, errs = schema.validate_file(path)
         assert errs == []
-        # run_meta + the trace span-template record
-        assert counts["step"] == 2 and counts["meta"] == 2
+        # run_meta alone: the trace span-template record went (PR 26)
+        assert counts["step"] == 2 and counts["meta"] == 1
         rr = _load_report_run()
         metas, steps, _ = rr.load_run(path)
         report = rr.render_report(metas, steps, source=path)

@@ -30,6 +30,10 @@ Public API shape mirrors the reference's flat surface
     )
 """
 
+import time as _time
+
+_import_begin = _time.monotonic()
+
 from .parallel.partition import partition_tensors, materialize_owned
 from .parallel.engine import SingleDevice, DDP, Zero1, Zero2, Zero3
 from .parallel.mesh import make_mesh, init_distributed
@@ -38,6 +42,10 @@ from .models import (
     GPTConfig, GPT2Model, MoEConfig, MoEGPT, LlamaConfig, LlamaModel,
 )
 from .telemetry import Telemetry
+from .utils import startup as _startup
+
+_startup.marks["import_begin"] = _import_begin
+_startup.marks["import_done"] = _time.monotonic()
 
 # Reference-shaped optimizer names (reference core/__init__.py:5-23 exports
 # DDPSGD/DDPAdamW/Zero{1,2,3}SGD/Zero{1,2,3}AdamW — one subclass per mode

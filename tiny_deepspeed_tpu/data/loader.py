@@ -29,6 +29,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ..utils.profiling import span
+
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
 _SRC = os.path.abspath(os.path.join(_NATIVE_DIR, "dataloader.cpp"))
 
@@ -146,6 +148,10 @@ class TokenLoader:
     # -- iteration ---------------------------------------------------------
 
     def next(self) -> Tuple[np.ndarray, np.ndarray]:
+        with span("tds.load"):
+            return self._next()
+
+    def _next(self) -> Tuple[np.ndarray, np.ndarray]:
         if self._handle is not None:
             x = np.empty((self.batch, self.seq), np.int32)
             y = np.empty((self.batch, self.seq), np.int32)

@@ -354,29 +354,44 @@ class GPT2Model:
         # dropout rides the stacked tree as a per-layer PRNG key; its
         # presence (static at trace time) is the train/eval switch
         dkey = bp.get("dropout_rng")
+        # named scopes: what a device trace's operations are told apart
+        # by (utils/profiling.TABLE); metadata only, the program is the same
+        scope = jax.named_scope
 
-        h = layernorm(x, bp["ln_1.w"], bp["ln_1.b"])
-        qkv = linear(h, self._bw(bp, "attn.qkv.w", pctx), bp.get("attn.qkv.b"))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with scope("tds.block"):
+            with scope("tds.ln"):
+                h = layernorm(x, bp["ln_1.w"], bp["ln_1.b"])
+            with scope("tds.attn.qkv"):
+                qkv = linear(h, self._bw(bp, "attn.qkv.w", pctx),
+                             bp.get("attn.qkv.b"))
+                q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads(z):  # (B, T, D) -> (B, H, T, Dh)
-            return z.reshape(b, t, c.n_head, c.head_dim).swapaxes(1, 2)
+                def heads(z):  # (B, T, D) -> (B, H, T, Dh)
+                    return z.reshape(
+                        b, t, c.n_head, c.head_dim).swapaxes(1, 2)
 
-        kh, vh = heads(k), heads(v)
-        y = sharded_attention(heads(q), kh, vh, c.attn_impl, pctx)
-        y = y.swapaxes(1, 2).reshape(b, t, d)
-        y = linear(y, self._bw(bp, "attn.proj.w", pctx), bp.get("attn.proj.b"))
-        if dkey is not None:
-            y = _dropout(y, jax.random.fold_in(dkey, 0), c.dropout)
-        x = x + y
+                qh, kh, vh = heads(q), heads(k), heads(v)
+            with scope("tds.attn.kernel"):
+                y = sharded_attention(qh, kh, vh, c.attn_impl, pctx)
+            with scope("tds.attn.proj"):
+                y = y.swapaxes(1, 2).reshape(b, t, d)
+                y = linear(y, self._bw(bp, "attn.proj.w", pctx),
+                           bp.get("attn.proj.b"))
+                if dkey is not None:
+                    y = _dropout(y, jax.random.fold_in(dkey, 0), c.dropout)
+                x = x + y
 
-        h = layernorm(x, bp["ln_2.w"], bp["ln_2.b"])
-        h = linear(h, self._bw(bp, "mlp.fc.w", pctx), bp.get("mlp.fc.b"))
-        h = jax.nn.gelu(h, approximate=True)
-        h = linear(h, self._bw(bp, "mlp.proj.w", pctx), bp.get("mlp.proj.b"))
-        if dkey is not None:
-            h = _dropout(h, jax.random.fold_in(dkey, 1), c.dropout)
-        x = x + h
+            with scope("tds.ln"):
+                h = layernorm(x, bp["ln_2.w"], bp["ln_2.b"])
+            with scope("tds.mlp"):
+                h = linear(h, self._bw(bp, "mlp.fc.w", pctx),
+                           bp.get("mlp.fc.b"))
+                h = jax.nn.gelu(h, approximate=True)
+                h = linear(h, self._bw(bp, "mlp.proj.w", pctx),
+                           bp.get("mlp.proj.b"))
+                if dkey is not None:
+                    h = _dropout(h, jax.random.fold_in(dkey, 1), c.dropout)
+                x = x + h
         return (x, (kh, vh)) if return_kv else x
 
     # -- KV-cache decode ---------------------------------------------------
@@ -521,6 +536,7 @@ class GPT2Model:
             unroll=self.config.scan_unroll)
         return x, ks, vs
 
+    @jax.named_scope("tds.embed")
     def _embed_decode(self, params, tok, pos):
         """One token per row -> (B, 1, D).  tok: (B,) ints; pos: scalar
         (every row at the same position — `generate`) or (B,) vector
@@ -610,26 +626,36 @@ class GPT2Model:
         + per-slot write coordinates, loop-invariant)."""
         c = self.config
         s = x.shape[0]
-        h = layernorm(x, bp["ln_1.w"], bp["ln_1.b"])
-        qkv = linear(h, self._bw(bp, "attn.qkv.w"), bp.get("attn.qkv.b"))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        scope = jax.named_scope
+        with scope("tds.ln"):
+            h = layernorm(x, bp["ln_1.w"], bp["ln_1.b"])
+        with scope("tds.attn.qkv"):
+            qkv = linear(h, self._bw(bp, "attn.qkv.w"),
+                         bp.get("attn.qkv.b"))
+            q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads1(z):
-            return z.reshape(s, 1, c.n_head, c.head_dim).swapaxes(1, 2)
+            def heads1(z):
+                return z.reshape(
+                    s, 1, c.n_head, c.head_dim).swapaxes(1, 2)
 
+            qh, kh, vh = heads1(q), heads1(k)[:, :, 0], heads1(v)[:, :, 0]
         from ..serving.pool import paged_append
-        view = paged_append(
-            view, heads1(k)[:, :, 0], heads1(v)[:, :, 0], l, page
-        )
-        y = self._paged_attention(heads1(q), view, l, page)
-        y = y.swapaxes(1, 2).reshape(s, 1, c.n_embd)
-        y = linear(y, self._bw(bp, "attn.proj.w"), bp.get("attn.proj.b"))
-        return x + y, view
+        with scope("tds.kv_write"):
+            view = paged_append(view, kh, vh, l, page)
+        with scope("tds.attn.kernel"):
+            y = self._paged_attention(qh, view, l, page)
+        with scope("tds.attn.proj"):
+            y = y.swapaxes(1, 2).reshape(s, 1, c.n_embd)
+            y = linear(y, self._bw(bp, "attn.proj.w"),
+                       bp.get("attn.proj.b"))
+            return x + y, view
 
     def _paged_block_decode(self, x, bp, view, l, page):
         """One block, one token per slot, cache in the paged pool."""
-        x, view = self._paged_attn_decode(x, bp, view, l, page)
-        return self._mlp_decode(x, bp), view
+        with jax.named_scope("tds.block"):
+            x, view = self._paged_attn_decode(x, bp, view, l, page)
+            with jax.named_scope("tds.mlp"):
+                return self._mlp_decode(x, bp), view
 
     def paged_decode(self, stacked, x, view, page):
         """Layer loop for one paged decode token — the pool view rides
@@ -645,9 +671,10 @@ class GPT2Model:
             x, view = self._paged_block_decode(x, bp, view, l, page)
             return (x, view), None
 
-        (x, view), _ = jax.lax.scan(
-            body, (x, view), jnp.arange(n_layer),
-            unroll=self.config.scan_unroll)
+        with jax.named_scope("tds.blocks"):
+            (x, view), _ = jax.lax.scan(
+                body, (x, view), jnp.arange(n_layer),
+                unroll=self.config.scan_unroll)
         return x, view
 
     # -- speculative verification (serving/spec.py) ------------------------
@@ -662,6 +689,7 @@ class GPT2Model:
     # scratch).  The attention math is `_decode_attention` extended to
     # K1 query positions; everything else reuses the paged machinery.
 
+    @jax.named_scope("tds.embed")
     def _embed_decode_span(self, params, toks, positions):
         """(S, K1) tokens at (S, K1) absolute positions -> (S, K1, D)
         compute-dtype activations (the span analogue of
@@ -759,6 +787,7 @@ class GPT2Model:
             unroll=self.config.scan_unroll)
         return x, sks, svs
 
+    @jax.named_scope("tds.head")
     def head_span(self, params, x):
         """Final norm + lm_head at EVERY position of x (S, K1, D) ->
         (S, K1, V) f32 — the verify step needs the target distribution
@@ -783,10 +812,12 @@ class GPT2Model:
         x = self.embed(params, idx)
         if stacked is None:
             stacked = self.stacked_compute_params(params)
-        x, (ks, vs) = jax.lax.scan(self._prefill_body, x, stacked,
-                                   unroll=self.config.scan_unroll)
+        with jax.named_scope("tds.blocks"):
+            x, (ks, vs) = jax.lax.scan(self._prefill_body, x, stacked,
+                                       unroll=self.config.scan_unroll)
         from ..serving.pool import paged_scatter
-        view = paged_scatter(view, ks, vs, block_ids, block_tokens)
+        with jax.named_scope("tds.kv_write"):
+            view = paged_scatter(view, ks, vs, block_ids, block_tokens)
         return self.head(params, x, position=last_pos)[:, 0], view
 
     def embed_tokens(self, params, idx):
@@ -818,6 +849,7 @@ class GPT2Model:
             )
         return x
 
+    @jax.named_scope("tds.embed")
     def embed(self, params, idx, pctx=None):
         """Token + position embedding -> (B, T, D) in compute dtype."""
         t = idx.shape[1]
@@ -831,6 +863,7 @@ class GPT2Model:
         return (self.config.gather_quant == "fp8"
                 and name.endswith(".w") and v.ndim >= 3)
 
+    @jax.named_scope("tds.cast")
     def stacked_compute_params(self, params):
         """The per-block scan xs: "h.*" tensors cast to compute dtype ONCE
         per step — per-layer casts inside the scan would re-read the float32
@@ -945,6 +978,7 @@ class GPT2Model:
         w = params["wte"].T if c.tie_weights else params["lm_head.w"]
         return w.astype(c.compute_dtype)
 
+    @jax.named_scope("tds.head")
     def head(self, params, x, targets: Optional[jax.Array] = None,
              pctx=None, position=None):
         """Final norm + lm_head (+ loss when targets given)."""
@@ -1017,8 +1051,9 @@ class GPT2Model:
                     "sched= (the in-scan collective scheduler) does not "
                     "compose with the pipeline forward"
                 )
-            x = sched.scan(block, stacked, x,
-                           unroll=self.config.scan_unroll)
+            with jax.named_scope("tds.blocks"):
+                x = sched.scan(block, stacked, x,
+                               unroll=self.config.scan_unroll)
             return self.head(params, x, targets, pctx, position)
 
         if pctx is not None and pctx.pipe_parallel:
@@ -1026,19 +1061,23 @@ class GPT2Model:
             # n_layer/S stacked layers, microbatches hop stage->stage via
             # ppermute (parallel/pipeline.py; absent from the reference).
             from ..parallel.pipeline import spmd_pipeline
-            x = spmd_pipeline(
-                block, stacked, x,
-                mesh=pctx.mesh, pipe_axis=pctx.pipe_axis,
-                data_axis=pctx.data_axis,
-                microbatches=pctx.pipe_microbatches or None,
-                seq_axis=pctx.seq_axis,
-            )
+            with jax.named_scope("tds.blocks"):
+                x = spmd_pipeline(
+                    block, stacked, x,
+                    mesh=pctx.mesh, pipe_axis=pctx.pipe_axis,
+                    data_axis=pctx.data_axis,
+                    microbatches=pctx.pipe_microbatches or None,
+                    seq_axis=pctx.seq_axis,
+                )
         else:
             def scan_body(x, bp):
                 return block(x, bp), None
 
-            x, _ = jax.lax.scan(scan_body, x, stacked,
-                                unroll=self.config.scan_unroll)
+            # tds.blocks: the layer loop with its own work (slicing the
+            # stacked weights, stacking their gradients) around tds.block
+            with jax.named_scope("tds.blocks"):
+                x, _ = jax.lax.scan(scan_body, x, stacked,
+                                    unroll=self.config.scan_unroll)
         return self.head(params, x, targets, pctx, position)
 
     def __call__(self, params, idx, targets=None, pctx=None, rng=None):

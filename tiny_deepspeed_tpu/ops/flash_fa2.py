@@ -144,6 +144,7 @@ def _fwd(q, k, v, *, scale, bq, bk, group=1, causal=True):
             pltpu.VMEM((bq, d), jnp.float32),    # acc
         ],
         interpret=_INTERPRET,
+        name="tds_fa2_fwd",
     )(q, k, v)
     return o, lse
 
@@ -275,6 +276,7 @@ def _dkv_call(q, k, v, do, lse, di, *, scale, bq, bk, group=1, causal=True):
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="tds_fa2_dkv",
     )(q, k, v, do, lse, di)
 
 
@@ -296,6 +298,7 @@ def _dq_call(q, k, v, do, lse, di, *, scale, bq, bk, group=1, causal=True):
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_INTERPRET,
+        name="tds_fa2_dq",
     )(q, k, v, do, lse, di)
 
 
@@ -641,6 +644,7 @@ def _fa2_bthd_fwd(q, k, v, block_q, block_k):
         ],
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=_INTERPRET,
+        name="tds_fa2_fwd_packed",
     )(flat(q), flat(k), flat(v))
     o = o.reshape(b, t, h, d)
     return o, (q, k, v, o, lse)
@@ -680,6 +684,7 @@ def _fa2_bthd_bwd(block_q, block_k, res, g):
             pltpu.VMEM((bk, hd), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="tds_fa2_dkv_packed",
     )(flat(q), flat(k), flat(v), do, lse, di)
     stat_blk = pl.BlockSpec((1, h, bq), lambda b_, i: (b_, 0, i))
     dq = pl.pallas_call(
@@ -691,6 +696,7 @@ def _fa2_bthd_bwd(block_q, block_k, res, g):
         out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=_INTERPRET,
+        name="tds_fa2_dq_packed",
     )(flat(q), flat(k), flat(v), do, lse, di)
     unflat = lambda x: x.reshape(b, t, h, d)
     return unflat(dq), unflat(dk), unflat(dv)

@@ -109,6 +109,7 @@ def ln_fwd_pallas(x, w, b, eps=1e-5):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         interpret=INTERPRET,
+        name="tds_ln_fwd",
     )(x2, w.reshape(1, n), b.reshape(1, n))
     return (
         y.reshape(orig_shape),
@@ -157,6 +158,7 @@ def ln_dx_pallas(gy, x, w, mean, rstd):
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         interpret=INTERPRET,
+        name="tds_ln_dx",
     )(
         gy.reshape(rows, n),
         x.reshape(rows, n),
@@ -212,6 +214,7 @@ def ln_dwdb_pallas(gy, x, mean, rstd):
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
         interpret=INTERPRET,
+        name="tds_ln_dwdb",
     )(
         gy.reshape(rows, n),
         x.reshape(rows, n),

@@ -309,5 +309,6 @@ def paged_attention(q, view, page, l, *, span_kv=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="tds_paged_attn",
     )(tables, pos, larr, *args)
     return out.reshape(s, kvh, g, k1, dh).reshape(s, hq, k1, dh)

@@ -81,5 +81,6 @@ def pallas_quantize_blockwise(x, mode: str, block: int = 256, dither=None):
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="tds_quant",
     )(*args)
     return q.reshape(-1), s

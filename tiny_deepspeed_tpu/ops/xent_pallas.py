@@ -139,6 +139,7 @@ def _fwd(x, w, targets, *, bs, bv):
             pltpu.VMEM((bs, 1), jnp.float32),   # gold
         ],
         interpret=_INTERPRET,
+        name="tds_xent_fwd",
     )(x, w, t2)
     return loss[:, 0], lse
 
@@ -229,6 +230,7 @@ def _bwd(x, w, targets, lse, gscale, *, bs, bv_dx, bv_dw):
         out_shape=jax.ShapeDtypeStruct((s, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bs, d), jnp.float32)],
         interpret=_INTERPRET,
+        name="tds_xent_dx",
     )(x, w, t2, lse, gs)
 
     tok = lambda j, i: (i, 0)
@@ -246,6 +248,7 @@ def _bwd(x, w, targets, lse, gscale, *, bs, bv_dx, bv_dw):
         out_shape=jax.ShapeDtypeStruct((d, v), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, bv_dw), jnp.float32)],
         interpret=_INTERPRET,
+        name="tds_xent_dw",
     )(x, w, t2, lse, gs)
     return dx, dw
 

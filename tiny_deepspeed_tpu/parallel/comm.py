@@ -267,6 +267,7 @@ def quantized_all_gather(chunk, axis: str, n: int, mode: str, *,
     return vals.reshape(-1)
 
 
+@jax.named_scope("tds.grad_sync")
 def quantized_grad_sync(grads, residual, axis: str, n: int, mode: str, *,
                         block: int = DEFAULT_BLOCK, rng=None,
                         inner: Optional[int] = None, mean: bool = True):

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..utils.profiling import span
 from .mesh import make_mesh, ParallelContext
 from .partition import partition_tensors
 
@@ -921,7 +922,7 @@ class ZeroEngine:
 
         self._build_step()
 
-        def _eval_impl(params, ix, tg):
+        def tds_eval(params, ix, tg):
             from ..ops.dispatch import gspmd_auto_region
             kw = {}
             if self._lowering == "prefetch":
@@ -935,7 +936,7 @@ class ZeroEngine:
         # forward-only loss (validation): no dropout (no rng), no grads, no
         # state change; always takes a plain (B, T) batch (no accum axis)
         self._eval = jax.jit(
-            _eval_impl,
+            tds_eval,
             in_shardings=(
                 self._param_shardings,
                 self._eval_batch_sharding, self._eval_batch_sharding,
@@ -949,8 +950,13 @@ class ZeroEngine:
         # the winner-table version this program was traced against; retune
         # rebuilds only when timing has produced new winners since
         self._tuner_version = getattr(tuner, "version", 0)
+        # the name a device trace's `XLA Modules` line reads:
+        # jit_tds_train_step (utils/profiling.TABLE)
+        def tds_train_step(state, batch):
+            return self._step_impl(state, batch)
+
         self._step = jax.jit(
-            self._step_impl,
+            tds_train_step,
             in_shardings=(
                 TrainState(
                     params=self._param_shardings,
@@ -1312,7 +1318,8 @@ class ZeroEngine:
             if self._lowering in ("plain", "probe", "prefetch", "pipe"):
                 # the explicit-schedule lowerings (composed / bucket /
                 # quant_mono) already unscaled before their collectives
-                grads = _rescale(grads, 1.0 / scale)
+                with jax.named_scope("tds.optim"):
+                    grads = _rescale(grads, 1.0 / scale)
             if layer_probe is not None:
                 # the backward ran on the scaled loss: the dact sq-sum
                 # column (2) carries scale^2; the non-finite counts stay
@@ -1327,28 +1334,31 @@ class ZeroEngine:
             for g in jax.tree.leaves(grads):
                 finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(g)))
         if self.grad_clip is not None:
-            gsq = sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads)
-            )
-            grads = _rescale(grads, jnp.minimum(
-                1.0, self.grad_clip / (jnp.sqrt(gsq) + 1e-6)
-            ))
+            with jax.named_scope("tds.optim"):
+                gsq = sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads)
+                )
+                grads = _rescale(grads, jnp.minimum(
+                    1.0, self.grad_clip / (jnp.sqrt(gsq) + 1e-6)
+                ))
 
         if self.stage >= 2:
             # ZeRO-2/3: gradient sharding — the all-reduce XLA would emit for
             # replicated-param grads becomes a reduce-scatter.
-            grads = self._constrain(grads, self._shard_shardings)
+            with jax.named_scope("tds.grad_sync"):
+                grads = self._constrain(grads, self._shard_shardings)
 
-        if self.offload_opt_state:
-            new_params, new_opt = self._offload_update(
-                params, grads, state.opt_state,
-                finite if dynamic else None,
-            )
-        else:
-            new_params, new_opt = self.optimizer.update(
-                params, grads, state.opt_state
-            )
+        with jax.named_scope("tds.optim"):
+            if self.offload_opt_state:
+                new_params, new_opt = self._offload_update(
+                    params, grads, state.opt_state,
+                    finite if dynamic else None,
+                )
+            else:
+                new_params, new_opt = self.optimizer.update(
+                    params, grads, state.opt_state
+                )
         new_scaler = state.scaler
         if dynamic:
             # overflow -> discard the whole update (params, moments, AND the
@@ -1385,7 +1395,8 @@ class ZeroEngine:
         # ZeRO-1/2: updated params all-gather back to replicated; ZeRO-3:
         # they stay sharded.  (The reference broadcasts per-param from the
         # owner in a python loop with no bucketing, zero1/optim.py:25-34.)
-        new_params = self._constrain(new_params, self._param_shardings)
+        with jax.named_scope("tds.gather"):
+            new_params = self._constrain(new_params, self._param_shardings)
         new_state = TrainState(params=new_params, opt_state=new_opt,
                                scaler=new_scaler,
                                dropout_base=state.dropout_base,
@@ -1412,15 +1423,18 @@ class ZeroEngine:
         either way; with the telemetry knob the step's packed health
         vector (and, in layers mode, the per-layer health matrix) is
         pushed into the telemetry object un-synced."""
-        if self._telemetry_on:
-            if self._layers_on:
-                state, loss, aux, mat = self._step(state, batch)
-                self.telemetry.on_step_output(aux, layers=mat)
-            else:
-                state, loss, aux = self._step(state, batch)
-                self.telemetry.on_step_output(aux)
-            return state, loss
-        return self._step(state, batch)
+        # tds.step: what enqueueing a step costs the host (argument
+        # sharding, dispatch, the telemetry hand-off); the device runs on
+        with span("tds.step"):
+            if self._telemetry_on:
+                if self._layers_on:
+                    state, loss, aux, mat = self._step(state, batch)
+                    self.telemetry.on_step_output(aux, layers=mat)
+                else:
+                    state, loss, aux = self._step(state, batch)
+                    self.telemetry.on_step_output(aux)
+                return state, loss
+            return self._step(state, batch)
 
     def eval_loss(self, state, batch):
         """Mean loss on one (B, T) batch — forward only: deterministic (no

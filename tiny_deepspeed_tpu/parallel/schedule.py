@@ -634,6 +634,7 @@ class GatherPrefetchScan:
         if not sharded:
             return out
 
+        @jax.named_scope("tds.gather")
         def local(vals, scs):
             res = {}
             for name, v in vals.items():
@@ -1864,6 +1865,7 @@ def composed_step(eng, state, idx, targets, rng, scale):
             x = jnp.swapaxes(x, d, d + 1)
             return x.reshape(s)
 
+        @jax.named_scope("tds.gather")
         def build_sec(sf_):
             """hpZ secondary partition: ONE inter-slice all-gather per
             leaf turns each rank's global 1/n shard into its slice's
@@ -1928,6 +1930,7 @@ def composed_step(eng, state, idx, targets, rng, scale):
                     axis_index_groups=inter)
             return out
 
+        @jax.named_scope("tds.gather")
         def gather_k(src, k):
             """Layer k's full weights from the gather source (the
             sharded stacked tree, or the hpZ secondary partition)."""
@@ -2172,6 +2175,7 @@ def composed_step(eng, state, idx, targets, rng, scale):
         if sc is not None:
             ops["scale"] = jnp.full((), sc, jnp.float32)
 
+        @jax.named_scope("tds.gather")
         def tail_full(tp_):
             if not stage3:
                 return tp_
@@ -2298,7 +2302,8 @@ def composed_step(eng, state, idx, targets, rng, scale):
             for nm, a in g_tail.items():
                 g32 = a.astype(jnp.float32)
                 if tdim[nm] is None:
-                    g32 = jax.lax.psum(g32, ax)
+                    with jax.named_scope("tds.grad_sync"):
+                        g32 = jax.lax.psum(g32, ax)
                 out[nm] = (g32 * (inv / n)).astype(a.dtype)
             g_tail = out
             new_tres = None

@@ -131,6 +131,7 @@ import numpy as np
 
 from ..models.gpt2 import resolved_cache_dtype
 from ..models.sampling import sample_logits_at, sample_logits_per_slot
+from ..utils import profiling
 from .guard import DecodeHealthGuard
 from .journal import RequestJournal, ServingKilled
 from .pool import (
@@ -143,6 +144,37 @@ from .tenancy import TenantPolicy, TenantQueue
 # decode-wall samples needed before deadline shedding trusts its price
 # estimate (a cold engine must not shed on compile-time noise)
 _MIN_GAP_SAMPLES = 5
+
+# ticks `ServingEngine.tick_records` keeps
+_TICK_RECORDS = 512
+# a tick record's segment -> the `tick` JSONL record's wall-split field
+# (what is in no field is sched_s, the remainder)
+_SEGMENT_OF = {"admit": "prefill_s", "prefill.dispatch": "prefill_s",
+               "prefill.fetch": "prefill_s", "decode.dispatch": "decode_s",
+               "decode.fetch": "fetch_s", "draft": "draft_s"}
+
+
+class _TickSpan:
+    """One named part of a tick, written twice from the same two instants:
+    as a `tds.tick.<name>` span on the profiler's clock (dead without a
+    session) and as (name, start, end) on time.monotonic() in the tick's
+    record."""
+
+    __slots__ = ("segments", "name", "ann", "t0")
+
+    def __init__(self, segments: list, name: str, ids: dict):
+        self.segments = segments
+        self.name = name
+        self.ann = profiling.span("tds.tick." + name, **ids)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.segments.append((self.name, self.t0, time.monotonic()))
+        self.ann.__exit__(*exc)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -488,9 +520,14 @@ class ServingEngine:
         else:
             self._flight = None
         self._flight_reason: Optional[str] = None
-        # per-tick wall split + scheduler counts (tick records + flight)
-        self._seg = {"prefill_s": 0.0, "decode_s": 0.0, "fetch_s": 0.0,
-                     "draft_s": 0.0}
+        # the last ticks, one plain host record each, kept with or
+        # without a logger: tick number, start `t0` and end `t1`
+        # (time.monotonic()), `segments` [(name, start, end), ..] as the
+        # tds.tick.* spans name them, `admitted` with each prefill
+        # `buckets`, `active` slots, tokens `produced`.  `_record_tick`
+        # and the flight ring read the newest; so can anyone else.
+        self.tick_records: Deque[dict] = deque(maxlen=_TICK_RECORDS)
+        self._tick: dict = {"tick": -1, "segments": [], "buckets": []}
         self._tick_counts = dict.fromkeys(
             ("admitted", "evicted", "preempted", "expired",
              "quarantined", "restarted"), 0)
@@ -551,37 +588,45 @@ class ServingEngine:
         temp, top_k = config.temperature, config.top_k
         base_key = jax.random.PRNGKey(config.seed)
 
-        def decode_step(params, stacked, view, tokens, pos, tables,
-                        seeds, nprod, poison):
-            x = model._embed_decode(params, tokens, pos)
-            page = page_ref(tables, pos, bt)
-            x, view = model.paged_decode(stacked, x, view, page)
-            logits = model.head(params, x)[:, 0]
-            # chaos operand: 0.0 off-path (tokens bit-identical — x+0.0
-            # never changes an argmax or a categorical draw), NaN on a
-            # poisoned slot.  The per-slot health flag rides the same
-            # computation the token fetch already synchronizes on.
-            logits = logits + poison[:, None]
-            bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
-            nxt = sample_logits_per_slot(
-                logits, base_key, seeds, nprod, temp, top_k)
+        # the programs' names are what a device trace's `XLA Modules` line
+        # reads (`jit_tds_decode`, `jit_tds_prefill`), the named scopes
+        # what its operations carry: utils/profiling.TABLE
+        def tds_decode(params, stacked, view, tokens, pos, tables,
+                       seeds, nprod, poison):
+            with jax.named_scope("tds.decode"):
+                x = model._embed_decode(params, tokens, pos)
+                page = page_ref(tables, pos, bt)
+                x, view = model.paged_decode(stacked, x, view, page)
+                logits = model.head(params, x)[:, 0]
+                # chaos operand: 0.0 off-path (tokens bit-identical —
+                # x+0.0 never changes an argmax or a categorical draw),
+                # NaN on a poisoned slot.  The per-slot health flag rides
+                # the same computation the token fetch already
+                # synchronizes on.
+                logits = logits + poison[:, None]
+                bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
+                with jax.named_scope("tds.sample"):
+                    nxt = sample_logits_per_slot(
+                        logits, base_key, seeds, nprod, temp, top_k)
             return nxt, logits, bad, view
 
-        def prefill_step(params, stacked, prompt, last_pos, block_ids,
-                         view, seed, nprod):
-            logits, view = model.paged_prefill(
-                params, prompt, last_pos, block_ids, view, bt,
-                stacked=stacked,
-            )
-            nxt = sample_logits_at(logits, base_key, seed, nprod, temp,
-                                   top_k)
+        def tds_prefill(params, stacked, prompt, last_pos, block_ids,
+                        view, seed, nprod):
+            with jax.named_scope("tds.prefill"):
+                logits, view = model.paged_prefill(
+                    params, prompt, last_pos, block_ids, view, bt,
+                    stacked=stacked,
+                )
+                with jax.named_scope("tds.sample"):
+                    nxt = sample_logits_at(logits, base_key, seed, nprod,
+                                           temp, top_k)
             return nxt, view
 
         # the pool view is DONATED through both programs: each step
         # aliases the pool buffers instead of copying the whole pool
-        self._decode_fn = _kwrap(jax.jit(decode_step, donate_argnums=(2,)))
+        self._decode_fn = _kwrap(jax.jit(tds_decode, donate_argnums=(2,)))
         self._prefill_fn = _kwrap(
-            jax.jit(prefill_step, donate_argnums=(5,)))
+            jax.jit(tds_prefill, donate_argnums=(5,)))
         # "h.*" compute-dtype cast once — params are frozen while serving
         self._stacked = jax.jit(model.stacked_compute_params)(params)
         # shared-prefix suffix prefill: when admission aliased m full
@@ -597,24 +642,29 @@ class ServingEngine:
         if config.prefix_cache:
             block_size = c.block_size
 
-            def prefill_suffix_step(params, stacked, span, tables, pos0,
-                                    last_off, count, view, seed, nprod):
-                k1 = span.shape[1]
-                positions = jnp.minimum(
-                    pos0[:, None] + jnp.arange(k1)[None, :],
-                    block_size - 1)
-                x = model._embed_decode_span(params, span, positions)
-                page = page_ref(tables, pos0, bt)
-                x, sks, svs = model.paged_verify(stacked, x, view, page)
-                logits = model.head(params, x, position=last_off)[:, 0]
-                nxt = sample_logits_at(logits, base_key, seed, nprod,
-                                       temp, top_k)
-                view = paged_append_span(view, sks, svs, tables, pos0,
-                                         count, bt)
+            def tds_prefill_suffix(params, stacked, span, tables, pos0,
+                                   last_off, count, view, seed, nprod):
+                with jax.named_scope("tds.prefill"):
+                    k1 = span.shape[1]
+                    positions = jnp.minimum(
+                        pos0[:, None] + jnp.arange(k1)[None, :],
+                        block_size - 1)
+                    x = model._embed_decode_span(params, span, positions)
+                    page = page_ref(tables, pos0, bt)
+                    x, sks, svs = model.paged_verify(stacked, x, view,
+                                                     page)
+                    logits = model.head(params, x,
+                                        position=last_off)[:, 0]
+                    with jax.named_scope("tds.sample"):
+                        nxt = sample_logits_at(logits, base_key, seed,
+                                               nprod, temp, top_k)
+                    with jax.named_scope("tds.kv_write"):
+                        view = paged_append_span(view, sks, svs, tables,
+                                                 pos0, count, bt)
                 return nxt, view
 
             self._prefill_suffix_fn = _kwrap(
-                jax.jit(prefill_suffix_step, donate_argnums=(7,)))
+                jax.jit(tds_prefill_suffix, donate_argnums=(7,)))
         else:
             self._prefill_suffix_fn = None
         # speculative decoding: the drafter + ONE compiled verify
@@ -641,23 +691,26 @@ class ServingEngine:
             # pos + spec_k so accepted drafts' K/V always land in-table
             self._span_k = config.spec_k
 
-            def prefill_step_spec(params, stacked, prompt, last_pos,
-                                  block_ids, view, seed, nprod, prop):
-                logits, view = model.paged_prefill(
-                    params, prompt, last_pos, block_ids, view, bt,
-                    stacked=stacked,
-                )
-                # a spec engine commits EVERY position through the one
-                # accept-or-residual rule — `prop` is the drafter's
-                # proposal for this position, so a re-admission (whose
-                # first token lands here instead of mid-verify) draws
-                # the same token the undisturbed run committed
-                nxt = spec_prefill_commit(logits, prop, base_key, seed,
-                                          nprod, temp, top_k)
+            def tds_prefill_spec(params, stacked, prompt, last_pos,
+                                 block_ids, view, seed, nprod, prop):
+                with jax.named_scope("tds.prefill"):
+                    logits, view = model.paged_prefill(
+                        params, prompt, last_pos, block_ids, view, bt,
+                        stacked=stacked,
+                    )
+                    # a spec engine commits EVERY position through the
+                    # one accept-or-residual rule — `prop` is the
+                    # drafter's proposal for this position, so a
+                    # re-admission (whose first token lands here instead
+                    # of mid-verify) draws the same token the undisturbed
+                    # run committed
+                    with jax.named_scope("tds.sample"):
+                        nxt = spec_prefill_commit(logits, prop, base_key,
+                                                  seed, nprod, temp, top_k)
                 return nxt, view
 
             self._prefill_fn = _kwrap(
-                jax.jit(prefill_step_spec, donate_argnums=(5,)))
+                jax.jit(tds_prefill_spec, donate_argnums=(5,)))
         else:
             self._spec = None
             self._span_k = 0
@@ -719,6 +772,12 @@ class ServingEngine:
         status "shed" — check `req.status`, not an exception: overload
         is an expected outcome, a malformed request is not (those still
         raise ValueError)."""
+        with profiling.span("tds.submit"):
+            return self._submit(prompt, max_new_tokens, deadline_s, seed,
+                                tenant)
+
+    def _submit(self, prompt, max_new_tokens, deadline_s, seed,
+                tenant) -> Request:
         c = self.model.config
         if len(prompt) < 1 or max_new_tokens < 1:
             raise ValueError("need a non-empty prompt and >= 1 new token")
@@ -793,34 +852,52 @@ class ServingEngine:
         front-of-line and continue token-exact.  `ServingKilled` (the
         chaos stand-in for process death) always propagates — a real
         kill leaves no engine to restart."""
-        t0 = time.monotonic()
         tick_i = self._ticks
-        self._seg = {"prefill_s": 0.0, "decode_s": 0.0, "fetch_s": 0.0,
-                     "draft_s": 0.0}
-        self._tick_counts = dict.fromkeys(self._tick_counts, 0)
-        try:
-            produced = self._tick_body(decode=decode)
-        except ServingKilled:
-            raise
-        except Exception as e:
-            if self._guard is None:
+        with profiling.span("tds.tick", tick=tick_i):
+            t0 = time.monotonic()
+            rec = self._tick = {"tick": tick_i, "t0": t0, "t1": t0,
+                                "segments": [], "admitted": 0,
+                                "buckets": [], "active": 0, "produced": 0}
+            try:
+                produced = self._tick_body(decode=decode)
+            except ServingKilled:
                 raise
-            self._warm_restart(f"tick exception: {type(e).__name__}: {e}")
-            produced = 0
-        if self.journal is not None:
-            self.journal.commit()
-        self._ticks += 1
-        if produced:
-            self._restarts_since_progress = 0
-        self._update_gauges()
-        self._record_tick(tick_i, t0, produced)
-        if self.live is not None and self.telemetry is not None:
-            # push the tick's registry snapshot into the live plane:
-            # plain host dicts (floats), so the exporter thread can
-            # never reach a device value through the aggregator
-            self.live.ingest(self.telemetry.snapshot(),
-                             replica=self.replica_id)
+            except Exception as e:
+                if self._guard is None:
+                    raise
+                self._warm_restart(
+                    f"tick exception: {type(e).__name__}: {e}")
+                produced = 0
+            if self.journal is not None:
+                with self._span("commit"):
+                    self.journal.commit()
+            with self._span("observe"):
+                self._ticks += 1
+                if produced:
+                    self._restarts_since_progress = 0
+                rec["admitted"] = self._tick_counts["admitted"]
+                rec["active"] = self.n_active
+                rec["produced"] = produced
+                self._update_gauges()
+                self._record_tick(rec)
+                if self.live is not None and self.telemetry is not None:
+                    # push the tick's registry snapshot into the live
+                    # plane: plain host dicts (floats), so the exporter
+                    # thread can never reach a device value through the
+                    # aggregator
+                    self.live.ingest(self.telemetry.snapshot(),
+                                     replica=self.replica_id)
+            rec["t1"] = time.monotonic()
+            self.tick_records.append(rec)
         return produced
+
+    def _span(self, name: str, **ids) -> _TickSpan:
+        """A part of the running tick: `tds.tick.<name>` in a trace, and
+        (name, start, end) in the tick's record.  A request's span carries
+        its id; the others the tick number."""
+        if not ids:
+            ids = {"tick": self._tick["tick"]}
+        return _TickSpan(self._tick["segments"], name, ids)
 
     def drain(self, max_ticks: Optional[int] = None) -> int:
         """Tick until every submitted request is done; returns total
@@ -1201,15 +1278,18 @@ class ServingEngine:
     # -- scheduler internals ------------------------------------------------
 
     def _tick_body(self, decode: bool = True) -> int:
-        if isinstance(self._queue, TenantQueue):
-            self._queue.on_tick()  # per-tenant budget accrual
-        self._enforce_deadlines(time.monotonic())
-        # growth first: existing slots claim the blocks their next write
-        # needs BEFORE admission can take them — the other order lets a
-        # fresh admission strand a grower, whose preempt-youngest victim
-        # is then the just-prefilled request (a full prefill thrown away
-        # per block boundary while the pool is tight)
-        self._grow()
+        with self._span("sched"):
+            self._tick_counts = dict.fromkeys(self._tick_counts, 0)
+            if isinstance(self._queue, TenantQueue):
+                self._queue.on_tick()  # per-tenant budget accrual
+            self._enforce_deadlines(time.monotonic())
+            # growth first: existing slots claim the blocks their next
+            # write needs BEFORE admission can take them — the other order
+            # lets a fresh admission strand a grower, whose
+            # preempt-youngest victim is then the just-prefilled request
+            # (a full prefill thrown away per block boundary while the
+            # pool is tight)
+            self._grow()
         produced = self._admit()
         active = [(i, s) for i, s in enumerate(self._slots)
                   if s is not None]
@@ -1251,47 +1331,47 @@ class ServingEngine:
         """One token for every active slot — the exact pre-speculation
         decode tick (spec off compiles and runs only this path)."""
         produced = 0
-        tokens, pos, seeds, nprod, poison, tables = \
-            self._slot_arrays(active)
-        t_dec = time.monotonic()
-        nxt, logits, bad, view = self._decode_fn(
-            self.params, self._stacked, self.pool.view,
-            tokens, pos, tables, seeds, nprod, poison,
-        )
+        with self._span("decode.operands"):
+            tokens, pos, seeds, nprod, poison, tables = \
+                self._slot_arrays(active)
         # dispatch returns before the device finishes (async); the
-        # np.asarray token fetch below is the sync — the tick record
-        # splits the two (decode_s vs fetch_s)
-        t_disp = time.monotonic()
-        self.pool.view = view
-        self.last_logits = logits
-        nxt = np.asarray(nxt)
-        # same computation, already synchronized by the token fetch
-        bad = np.asarray(bad)
-        tnow = time.monotonic()
-        self._seg["decode_s"] += t_disp - t_dec
-        self._seg["fetch_s"] += tnow - t_disp
-        self._gap_hist.append(tnow - t_dec)
-        poisoned = (set(self._guard.observe(bad, [i for i, _ in
-                                                  active]))
-                    if self._guard is not None else set())
-        for i, s in active:
-            if i in poisoned:
-                self._quarantine(i, s)
-                continue
-            t = int(nxt[i])
-            s.pos += 1
-            s.last = t
-            self._append_token(s.req, t, tnow)
-            if self.journal is not None:
-                self.journal.tokens(s.req.id, [t])
-            produced += 1
-            if self._finished(s.req):
-                self._finish(i, s)
-        if self._guard is not None and self._guard.should_restart:
-            self._warm_restart(
-                f"{self._guard.consecutive_poisoned} consecutive "
-                "poisoned decode ticks"
+        # np.asarray token fetch is the sync — the tick record splits
+        # the two (decode.dispatch vs decode.fetch)
+        with self._span("decode.dispatch") as disp:
+            nxt, logits, bad, view = self._decode_fn(
+                self.params, self._stacked, self.pool.view,
+                tokens, pos, tables, seeds, nprod, poison,
             )
+            self.pool.view = view
+            self.last_logits = logits
+        with self._span("decode.fetch"):
+            nxt = np.asarray(nxt)
+            # same computation, already synchronized by the token fetch
+            bad = np.asarray(bad)
+            tnow = time.monotonic()
+        self._gap_hist.append(tnow - disp.t0)
+        with self._span("commit"):
+            poisoned = (set(self._guard.observe(bad, [i for i, _ in
+                                                      active]))
+                        if self._guard is not None else set())
+            for i, s in active:
+                if i in poisoned:
+                    self._quarantine(i, s)
+                    continue
+                t = int(nxt[i])
+                s.pos += 1
+                s.last = t
+                self._append_token(s.req, t, tnow)
+                if self.journal is not None:
+                    self.journal.tokens(s.req.id, [t])
+                produced += 1
+                if self._finished(s.req):
+                    self._finish(i, s)
+            if self._guard is not None and self._guard.should_restart:
+                self._warm_restart(
+                    f"{self._guard.consecutive_poisoned} consecutive "
+                    "poisoned decode ticks"
+                )
         return produced
 
     def _decode_spec(self, active) -> int:
@@ -1304,81 +1384,80 @@ class ServingEngine:
         same per-slot surface as the plain path."""
         k = self._spec.k
         produced = 0
-        t_draft = time.monotonic()
-        drafts = self._spec.propose(self._slots)  # (S, K+1) int32
-        t_mid = time.monotonic()
-        self._seg["draft_s"] += t_mid - t_draft
-        tokens, pos, seeds, nprod, poison, tables = \
-            self._slot_arrays(active)
-        S = self.config.max_active
-        # [head, d_1..d_K, extra]: columns 0..K are the scored span,
-        # the trailing extra is the bonus position's proposal
-        span = np.zeros((S, k + 2), np.int32)
-        span[:, 0] = tokens
-        span[:, 1:] = drafts
-        # the last position whose K/V this request will ever need
-        # (total-2: the final token's K/V is never read); -1 parks
-        # empty slots at count 0 — every write routes to scratch
-        limit_kv = np.full((S,), -1, np.int32)
-        for i, s in active:
-            limit_kv[i] = (len(s.req.prompt) + s.req.max_new_tokens - 2)
-        t_dec = time.monotonic()
-        acc, final, bad, view = self._spec.verify(
-            self.params, self._stacked, self.pool.view,
-            span, pos, tables, seeds, nprod, limit_kv, poison,
-        )
-        t_disp = time.monotonic()
-        self.pool.view = view
-        acc = np.asarray(acc)
-        final = np.asarray(final)
-        bad = np.asarray(bad)
-        tnow = time.monotonic()
-        self._seg["decode_s"] += t_disp - t_dec
-        self._seg["fetch_s"] += tnow - t_disp
-        poisoned = (set(self._guard.observe(bad, [i for i, _ in
-                                                  active]))
-                    if self._guard is not None else set())
-        eos = self.config.eos_id
-        committed = 0
-        for i, s in active:
-            if i in poisoned:
-                self._quarantine(i, s)
-                continue
-            n_acc = int(acc[i])
-            toks = [int(t) for t in span[i, 1:1 + n_acc]]
-            toks.append(int(final[i]))
-            remaining = s.req.max_new_tokens - len(s.req.tokens)
-            toks = toks[:remaining]
-            if eos is not None and eos in toks:
-                toks = toks[:toks.index(eos) + 1]  # keep the eos itself
-            s.req.spec_proposed += k
-            s.req.spec_accepted += min(n_acc, len(toks))
-            self._spec_proposed += k
-            self._spec_accepted += min(n_acc, len(toks))
-            for t in toks:
-                self._append_token(s.req, t, tnow)
-            if self.journal is not None:
-                self.journal.tokens(s.req.id, toks)
-            s.pos += len(toks)
-            s.last = toks[-1]
-            produced += len(toks)
-            committed += len(toks)
-            if self._finished(s.req):
-                self._finish(i, s)
-        # deadline price: this tick's wall per COMMITTED token — the
-        # draft+verify wall amortizes over the span yield, so a
-        # high-acceptance tick prices CHEAPER per token than its raw
-        # (bimodal) wall suggests
-        wall = tnow - t_draft
-        if committed:
-            self._gap_hist.append(wall * len(active) / committed)
-            self._spec_ticks += 1
-            self._spec_tokens += committed
-        if self._guard is not None and self._guard.should_restart:
-            self._warm_restart(
-                f"{self._guard.consecutive_poisoned} consecutive "
-                "poisoned decode ticks"
+        with self._span("draft") as draft:
+            drafts = self._spec.propose(self._slots)  # (S, K+1) int32
+        with self._span("decode.operands"):
+            tokens, pos, seeds, nprod, poison, tables = \
+                self._slot_arrays(active)
+            S = self.config.max_active
+            # [head, d_1..d_K, extra]: columns 0..K are the scored span,
+            # the trailing extra is the bonus position's proposal
+            span = np.zeros((S, k + 2), np.int32)
+            span[:, 0] = tokens
+            span[:, 1:] = drafts
+            # the last position whose K/V this request will ever need
+            # (total-2: the final token's K/V is never read); -1 parks
+            # empty slots at count 0 — every write routes to scratch
+            limit_kv = np.full((S,), -1, np.int32)
+            for i, s in active:
+                limit_kv[i] = (len(s.req.prompt)
+                               + s.req.max_new_tokens - 2)
+        with self._span("decode.dispatch"):
+            acc, final, bad, view = self._spec.verify(
+                self.params, self._stacked, self.pool.view,
+                span, pos, tables, seeds, nprod, limit_kv, poison,
             )
+            self.pool.view = view
+        with self._span("decode.fetch"):
+            acc = np.asarray(acc)
+            final = np.asarray(final)
+            bad = np.asarray(bad)
+            tnow = time.monotonic()
+        with self._span("commit"):
+            poisoned = (set(self._guard.observe(bad, [i for i, _ in
+                                                      active]))
+                        if self._guard is not None else set())
+            eos = self.config.eos_id
+            committed = 0
+            for i, s in active:
+                if i in poisoned:
+                    self._quarantine(i, s)
+                    continue
+                n_acc = int(acc[i])
+                toks = [int(t) for t in span[i, 1:1 + n_acc]]
+                toks.append(int(final[i]))
+                remaining = s.req.max_new_tokens - len(s.req.tokens)
+                toks = toks[:remaining]
+                if eos is not None and eos in toks:
+                    toks = toks[:toks.index(eos) + 1]  # keep the eos
+                s.req.spec_proposed += k
+                s.req.spec_accepted += min(n_acc, len(toks))
+                self._spec_proposed += k
+                self._spec_accepted += min(n_acc, len(toks))
+                for t in toks:
+                    self._append_token(s.req, t, tnow)
+                if self.journal is not None:
+                    self.journal.tokens(s.req.id, toks)
+                s.pos += len(toks)
+                s.last = toks[-1]
+                produced += len(toks)
+                committed += len(toks)
+                if self._finished(s.req):
+                    self._finish(i, s)
+            # deadline price: this tick's wall per COMMITTED token — the
+            # draft+verify wall amortizes over the span yield, so a
+            # high-acceptance tick prices CHEAPER per token than its raw
+            # (bimodal) wall suggests
+            wall = tnow - draft.t0
+            if committed:
+                self._gap_hist.append(wall * len(active) / committed)
+                self._spec_ticks += 1
+                self._spec_tokens += committed
+            if self._guard is not None and self._guard.should_restart:
+                self._warm_restart(
+                    f"{self._guard.consecutive_poisoned} consecutive "
+                    "poisoned decode ticks"
+                )
         return produced
 
     def _gap_p50(self) -> Optional[float]:
@@ -1520,108 +1599,120 @@ class ServingEngine:
             prompt_now = req.prompt + req.tokens  # preemption continuation
             p = len(prompt_now)
             bt = self.config.block_tokens
-            # shared-prefix match: alias at most (p-1)//bt full blocks
-            # — at least one prompt token always remains for the
-            # suffix program (which also samples the first token), and
-            # every block the request will WRITE stays private
-            alias: List[int] = []
-            if self._prefix is not None:
-                alias = self._prefix.match(
-                    prompt_now, limit=(p - 1) // bt, tick=self._ticks)
-                if alias:
-                    # pin the aliased blocks (this table's refcount)
-                    # BEFORE allocating: the fresh-block alloc may
-                    # evict tree leaves, and a matched node must not
-                    # be reclaimed out from under its own admission
-                    self.pool.share(alias)
-            # blocks for the prompt AND its first decode write (position
-            # p): same count as ceil(p/bt) except when p lands exactly
-            # on a block boundary — without the extra block that first
-            # decode write would land in the scratch block (lost K/V),
-            # or need a _grow after admission that can preempt the
-            # admission itself.  Under speculation the first write is a
-            # whole span (positions p..p+spec_k), so the horizon —
-            # clamped to the request's final position — replaces p:
-            # same worst-case block count as the plain path, claimed up
-            # front instead of across the first few grows
-            ids_new = self._alloc(
-                self._write_horizon(req, p) // bt + 1 - len(alias))
-            if ids_new is None:
-                if alias:
-                    self.pool.free_blocks(alias)  # roll the pin back
-                break
-            ids = alias + ids_new
-            self._pop_queued(req)
-            if self._prefill_exc is not None:
-                # chaos: the prefill "fails"; put everything back the
-                # way a real mid-admission fault would find it and let
-                # the watchdog take it from here
-                exc, self._prefill_exc = self._prefill_exc, None
-                self.pool.free_blocks(ids)
-                if isinstance(self._queue, TenantQueue):
-                    self._queue.refund(req)  # no work happened
-                self._queue.appendleft(req)
-                raise exc
-            t_adm = time.monotonic()
-            if req.t_admitted is None:
-                req.t_admitted = t_adm
-            # the wait window (queue / preempted-wait / restart-overhead,
-            # whichever re-queued it) closes at the same stamp the active
-            # window opens — the attribution components telescope
-            if req._wait_since is not None:
-                req.lat_components[req._wait_kind] += t_adm - req._wait_since
-                req._wait_since = None
-            req.event("admitted", t_adm, slot_i)
-            req.last_slot = slot_i
+            # popped and stamped: a failure from here on puts it back
+            taken = False
             try:
-                if alias:
-                    # suffix prefill: the aliased blocks already hold
-                    # positions < p0 — only the unmatched suffix runs,
-                    # through the span program (padded to a power-of-
-                    # two suffix bucket; pad offsets commit nothing)
-                    p0 = len(alias) * bt
-                    suffix = prompt_now[p0:]
-                    k1 = self._bucket_span(len(suffix))
-                    span = np.zeros((1, k1), np.int32)
-                    span[0, :len(suffix)] = suffix
-                    tables = np.full((1, self.max_blocks_per_req),
-                                     SCRATCH_BLOCK, np.int32)
-                    tables[0, :len(ids)] = ids
-                    nxt, view = self._prefill_suffix_fn(
-                        self.params, self._stacked, span, tables,
-                        np.asarray([p0], np.int32),
-                        np.int32(p - 1 - p0),
-                        np.asarray([len(suffix)], np.int32),
-                        self.pool.view, np.int32(req.seed),
-                        np.int32(len(req.tokens)),
-                    )
-                elif self._spec is not None:
-                    # the drafter rebuilds this slot's draft cache from
-                    # the SAME committed prefix — the one admission
-                    # path every resume (preemption, warm restart,
-                    # recovery) rides, so drafter state never needs
-                    # separate fault handling — and hands back its
-                    # proposal for the first post-prefix position (the
-                    # spec prefill's accept-or-residual operand)
-                    prop = self._spec.on_admit(slot_i, prompt_now)
-                    padded, block_ids = self._prefill_operands(
-                        prompt_now, ids)
-                    nxt, view = self._prefill_fn(
-                        self.params, self._stacked, padded, p - 1,
-                        block_ids, self.pool.view, np.int32(req.seed),
-                        np.int32(len(req.tokens)), np.int32(prop),
-                    )
-                else:
-                    padded, block_ids = self._prefill_operands(
-                        prompt_now, ids)
-                    nxt, view = self._prefill_fn(
-                        self.params, self._stacked, padded, p - 1,
-                        block_ids, self.pool.view, np.int32(req.seed),
-                        np.int32(len(req.tokens)),
-                    )
-                self.pool.view = view
-                tok = int(np.asarray(nxt)[0])
+                with self._span("admit", request=req.id):
+                    # shared-prefix match: alias at most (p-1)//bt full
+                    # blocks — at least one prompt token always remains
+                    # for the suffix program (which also samples the
+                    # first token), and every block the request will
+                    # WRITE stays private
+                    alias: List[int] = []
+                    if self._prefix is not None:
+                        alias = self._prefix.match(
+                            prompt_now, limit=(p - 1) // bt,
+                            tick=self._ticks)
+                        if alias:
+                            # pin the aliased blocks (this table's
+                            # refcount) BEFORE allocating: the fresh-block
+                            # alloc may evict tree leaves, and a matched
+                            # node must not be reclaimed out from under
+                            # its own admission
+                            self.pool.share(alias)
+                    # blocks for the prompt AND its first decode write
+                    # (position p): same count as ceil(p/bt) except when
+                    # p lands exactly on a block boundary — without the
+                    # extra block that first decode write would land in
+                    # the scratch block (lost K/V), or need a _grow after
+                    # admission that can preempt the admission itself.
+                    # Under speculation the first write is a whole span
+                    # (positions p..p+spec_k), so the horizon — clamped
+                    # to the request's final position — replaces p: same
+                    # worst-case block count as the plain path, claimed
+                    # up front instead of across the first few grows
+                    ids_new = self._alloc(
+                        self._write_horizon(req, p) // bt + 1 - len(alias))
+                    if ids_new is None:
+                        if alias:
+                            self.pool.free_blocks(alias)  # roll the pin back
+                        break
+                    ids = alias + ids_new
+                    self._pop_queued(req)
+                    if self._prefill_exc is not None:
+                        # chaos: the prefill "fails"; put everything back
+                        # the way a real mid-admission fault would find
+                        # it and let the watchdog take it from here
+                        exc, self._prefill_exc = self._prefill_exc, None
+                        self.pool.free_blocks(ids)
+                        if isinstance(self._queue, TenantQueue):
+                            self._queue.refund(req)  # no work happened
+                        self._queue.appendleft(req)
+                        raise exc
+                    t_adm = time.monotonic()
+                    if req.t_admitted is None:
+                        req.t_admitted = t_adm
+                    # the wait window (queue / preempted-wait / restart-
+                    # overhead, whichever re-queued it) closes at the
+                    # same stamp the active window opens — the
+                    # attribution components telescope
+                    if req._wait_since is not None:
+                        req.lat_components[req._wait_kind] += (
+                            t_adm - req._wait_since)
+                        req._wait_since = None
+                    req.event("admitted", t_adm, slot_i)
+                    req.last_slot = slot_i
+                    taken = True
+                    if alias:
+                        # suffix prefill: the aliased blocks already hold
+                        # positions < p0 — only the unmatched suffix
+                        # runs, through the span program (padded to a
+                        # power-of-two suffix bucket; pad offsets commit
+                        # nothing)
+                        p0 = len(alias) * bt
+                        suffix = prompt_now[p0:]
+                        bucket = self._bucket_span(len(suffix))
+                        span = np.zeros((1, bucket), np.int32)
+                        span[0, :len(suffix)] = suffix
+                        tables = np.full((1, self.max_blocks_per_req),
+                                         SCRATCH_BLOCK, np.int32)
+                        tables[0, :len(ids)] = ids
+                        fn, args = self._prefill_suffix_fn, (
+                            self.params, self._stacked, span, tables,
+                            np.asarray([p0], np.int32),
+                            np.int32(p - 1 - p0),
+                            np.asarray([len(suffix)], np.int32),
+                            self.pool.view, np.int32(req.seed),
+                            np.int32(len(req.tokens)),
+                        )
+                    else:
+                        # under speculation the drafter rebuilds this
+                        # slot's draft cache from the SAME committed
+                        # prefix — the one admission path every resume
+                        # (preemption, warm restart, recovery) rides, so
+                        # drafter state never needs separate fault
+                        # handling — and hands back its proposal for the
+                        # first post-prefix position (the spec prefill's
+                        # accept-or-residual operand)
+                        prop = (() if self._spec is None else (np.int32(
+                            self._spec.on_admit(slot_i, prompt_now)),))
+                        padded, block_ids = self._prefill_operands(
+                            prompt_now, ids)
+                        bucket = padded.shape[1]
+                        fn, args = self._prefill_fn, (
+                            self.params, self._stacked, padded, p - 1,
+                            block_ids, self.pool.view, np.int32(req.seed),
+                            np.int32(len(req.tokens)), *prop,
+                        )
+                with self._span("prefill.dispatch", request=req.id,
+                                bucket=bucket):
+                    nxt, view = fn(*args)
+                    self.pool.view = view
+                with self._span("prefill.fetch", request=req.id):
+                    tok = int(np.asarray(nxt)[0])
             except Exception:
+                if not taken:
+                    raise
                 # a REAL prefill failure (transient XLA error, wedged
                 # view): put the request back exactly like the chaos
                 # path does, or the watchdog's restart — which only
@@ -1637,30 +1728,31 @@ class ServingEngine:
                     self._queue.refund(req)  # no work happened
                 self._queue.appendleft(req)
                 raise
-            pf = time.monotonic() - t_adm
-            self._seg["prefill_s"] += pf
-            req.lat_components["prefill"] += pf
-            if self._prefix is not None:
-                # commit the prompt's full blocks to the radix tree —
-                # new nodes take their own refcount, which is what
-                # keeps them warm after this request's table frees
-                self._prefix.insert(prompt_now, ids[:p // bt],
-                                    self.pool, tick=self._ticks)
-                self._prefix.note_admission(len(alias), p)
-                req.prefix_blocks += len(alias)
-                req.prefix_tokens += len(alias) * bt
-            slot = _Slot(req, table=ids, pos=p, last_token=tok,
-                         admitted_at=t_adm, prefill_s=pf)
-            self._slots[slot_i] = slot
-            req.state = "active"
-            self._count("serve_admissions")
-            self._tick_counts["admitted"] += 1
-            self._append_token(req, tok, time.monotonic())
-            if self.journal is not None:
-                self.journal.tokens(req.id, [tok])
-            produced += 1
-            if self._finished(req):
-                self._finish(slot_i, slot)
+            with self._span("commit", request=req.id):
+                pf = time.monotonic() - t_adm
+                self._tick["buckets"].append(bucket)
+                req.lat_components["prefill"] += pf
+                if self._prefix is not None:
+                    # commit the prompt's full blocks to the radix tree
+                    # — new nodes take their own refcount, which is what
+                    # keeps them warm after this request's table frees
+                    self._prefix.insert(prompt_now, ids[:p // bt],
+                                        self.pool, tick=self._ticks)
+                    self._prefix.note_admission(len(alias), p)
+                    req.prefix_blocks += len(alias)
+                    req.prefix_tokens += len(alias) * bt
+                slot = _Slot(req, table=ids, pos=p, last_token=tok,
+                             admitted_at=t_adm, prefill_s=pf)
+                self._slots[slot_i] = slot
+                req.state = "active"
+                self._count("serve_admissions")
+                self._tick_counts["admitted"] += 1
+                self._append_token(req, tok, time.monotonic())
+                if self.journal is not None:
+                    self.journal.tokens(req.id, [tok])
+                produced += 1
+                if self._finished(req):
+                    self._finish(slot_i, slot)
         return produced
 
     def _write_horizon(self, req: Request, pos: int) -> int:
@@ -2018,17 +2110,23 @@ class ServingEngine:
         if self._FLIGHT_PRIORITY[reason] > cur:
             self._flight_reason = reason
 
-    def _record_tick(self, tick_i: int, t0: float, produced: int) -> None:
-        """End-of-tick bookkeeping: append the tick entry to the flight
-        ring (host dicts, no device sync), emit a `tick` JSONL record
-        when the tick was eventful or the sampling cadence hit, and
-        flush the flight ring if a fault trigger armed it this tick.
+    def _record_tick(self, rec: dict) -> None:
+        """End-of-tick bookkeeping, from the tick's own record (`rec`,
+        the entry `tick_records` is about to take): append the tick
+        entry to the flight ring (host dicts, no device sync), emit a
+        `tick` JSONL record when the tick was eventful or the sampling
+        cadence hit, and flush the flight ring if a fault trigger armed
+        it this tick.
 
-        The wall split: prefill/decode/fetch are measured around the two
-        compiled programs (dispatch vs the token-fetch sync); sched_s is
-        the remainder — deadline enforcement, growth, admission
-        bookkeeping, journal commit, gauge updates.  Submit-time sheds
-        happen OUTSIDE ticks and land on the next tick's `shed` count.
+        The wall split sums the record's segments: prefill_s is each
+        admission from its match to its first token on the host (admit +
+        prefill.dispatch + prefill.fetch), decode_s the decode program's
+        dispatch, fetch_s the token-fetch sync, draft_s the drafter;
+        sched_s is the remainder — deadline enforcement, growth, operand
+        building, commits, gauge updates.  A `tick` record also carries
+        the segments themselves, `spans` = [[name, start - t_s, seconds],
+        ..], for the timeline.  Submit-time sheds happen OUTSIDE ticks
+        and land on the next tick's `shed` count.
 
         Without a logger none of this can ever be emitted (every flush
         path needs the sink), so it is skipped wholesale — a production
@@ -2040,10 +2138,15 @@ class ServingEngine:
             self._flight_reason = None
             self._shed_seen = self._shed
             return
+        tick_i, t0, produced = rec["tick"], rec["t0"], rec["produced"]
         wall = time.monotonic() - t0
-        seg = self._seg
-        sched = max(0.0, wall - seg["prefill_s"] - seg["decode_s"]
-                    - seg["fetch_s"] - seg["draft_s"])
+        seg = dict.fromkeys(("prefill_s", "decode_s", "fetch_s",
+                             "draft_s"), 0.0)
+        for name, start, end in rec["segments"]:
+            key = _SEGMENT_OF.get(name)
+            if key is not None:
+                seg[key] += end - start
+        sched = max(0.0, wall - sum(seg.values()))
         shed_delta = self._shed - self._shed_seen
         self._shed_seen = self._shed
         if shed_delta >= self.config.shed_burst:
@@ -2089,6 +2192,8 @@ class ServingEngine:
                 kind="tick", tick=tick_i,
                 t_s=round(t0, 6), wall_s=round(wall, 6),
                 **segments, **state, **counts, **extra,
+                spans=[[name, round(start - t0, 6), round(end - start, 6)]
+                       for name, start, end in rec["segments"]],
                 emit="event" if eventful else "sample",
             )
         if self._flight_reason is not None:

@@ -79,8 +79,8 @@ class SpecDecoder:
         temp, top_k = config.temperature, config.top_k
         block_size = model.config.block_size
 
-        def verify_step(params, stacked, view, spanx, pos0, tables,
-                        seeds, nprod, limit_kv, poison):
+        def tds_verify(params, stacked, view, spanx, pos0, tables,
+                       seeds, nprod, limit_kv, poison):
             """spanx (S, K1+1) = [committed head, d_1..d_K, extra] —
             the scored span is the first K1 columns; the trailing
             `extra` is the drafter's bonus-position proposal, consumed
@@ -114,7 +114,7 @@ class SpecDecoder:
         # ENGINE, which wraps this program (and a model drafter's) with
         # the same _kwrap bracketing as its own decode/prefill jits —
         # one copy of the discipline, in one place (engine.__init__)
-        self._verify = jax.jit(verify_step, donate_argnums=(2,))
+        self._verify = jax.jit(tds_verify, donate_argnums=(2,))
 
     def describe(self) -> str:
         return f"spec(k={self.k}, drafter={self.drafter.describe()})"

@@ -24,9 +24,9 @@ package instruments a training run end to end:
     `utils.profiling.MetricsLogger`; `scripts/report_run.py --check`
     validates files against it and `scripts/report_run.py RUN.jsonl`
     renders the markdown run report.
-  * `trace` — step-trace timeline assembly: measured wall segments +
-    schematic collective spans cross-referenced to the compiled HLO
-    ledger, exported as Chrome-trace JSON by `scripts/trace_view.py`.
+  * `trace` — the serving timeline: request windows and each tick's
+    measured parts, exported as Chrome-trace JSON by
+    `scripts/trace_view.py`.
   * `flight` (FlightRecorder) — ring buffer of the last N steps' health
     (+ per-layer health in layers mode), flushed as one `flight` JSONL
     record when the anomaly detector fires on a slow step or non-finite

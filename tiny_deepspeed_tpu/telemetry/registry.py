@@ -157,9 +157,6 @@ class Telemetry:
         self.trace_path: Optional[str] = None
         self._trace_logged = False
         self._comm: Optional[Dict[str, object]] = None
-        # per-layer loop attribution from the last cost ledger
-        # (capture_compiled) — the source of trace_view's compute spans
-        self._cost_loops: Optional[list] = None
 
     # -- registry -----------------------------------------------------------
 
@@ -629,10 +626,6 @@ class Telemetry:
             if step_s > 0:
                 self.gauge("step_mfu_hlo",
                            cost["total_flops"] / step_s / peak)
-        # per-layer attribution for trace_view's compute spans
-        self._cost_loops = [
-            dict(l) for l in cled["loops"] if l.get("flops", 0.0) > 0
-        ]
         self._comm = out
         return out
 
@@ -658,58 +651,6 @@ class Telemetry:
             )
         meta.update(extra)
         return meta
-
-    def trace_spans(self) -> Optional[list]:
-        """Schematic collective span template (telemetry/trace.py) from
-        the last `capture_compiled` ledger, or None before one ran — the
-        payload of the `trace` meta record that `scripts/trace_view.py`
-        joins with the per-step wall segments into a Chrome-trace
-        timeline."""
-        if not self._comm or "comm_measured" not in self._comm:
-            return None
-        from .trace import collective_span_template
-        return collective_span_template(self._comm["comm_measured"])
-
-    def compute_trace_spans(self) -> Optional[list]:
-        """Schematic FLOP-sized compute span template from the last
-        `capture_compiled` cost ledger (utils/hlo_cost loop attribution),
-        or None before one ran — trace_view renders these next to the
-        wire-sized collective spans."""
-        if not self._comm or "hlo_cost" not in self._comm:
-            return None
-        from .trace import compute_span_template
-        return compute_span_template(
-            self._cost_loops or [],
-            float(self._comm["hlo_cost"]["total_flops"]),
-        )
-
-    def pipe_trace(self, engine=None) -> Optional[dict]:
-        """The attached engine's compiled pipeline tick program
-        (parallel/pipe_schedule.PipeProgram) serialized for the trace
-        record's `pipe` field — stage-major op/vchunk/mb rows plus the
-        occupancy numbers, all plain JSON types so trace_view.py's
-        jax-free path-import can render the per-stage pipeline track.
-        None when no table schedule compiled (gpipe/1f1b/unpipelined)."""
-        engine = engine or self._engine
-        prog = getattr(
-            getattr(engine, "_schedule", None), "pipe_program", None
-        )
-        if prog is None:
-            return None
-        return {
-            "describe": prog.describe(),
-            "stages": int(prog.stages),
-            "virtual": int(prog.virtual),
-            "microbatches": int(prog.microbatches),
-            "split_w": bool(prog.split_w),
-            "n_ticks": int(prog.n_ticks),
-            "bubble_frac": round(float(prog.bubble_frac), 6),
-            "busy": [int(b) for b in prog.busy],
-            # (T, S) arrays transposed stage-major: row s = stage s's ticks
-            "op": prog.op.T.tolist(),
-            "vchunk": prog.vchunk.T.tolist(),
-            "mb": prog.mb.T.tolist(),
-        }
 
     # -- sinks --------------------------------------------------------------
 

@@ -148,7 +148,11 @@ _NUM = (int, float)
 #      wins shared gauges) — all emitted only by live/SLO-configured or
 #      fleet runs, so plain serving files stay byte-compatible with
 #      v14 readers
-SCHEMA_VERSION = 15
+#  16: - the `trace` meta kind (the schematic train timeline's span
+#      templates: spans / compute_spans / pipe), removed with the
+#      timeline it fed; + `spans` on `tick` records: the tick's parts at
+#      their measured starts (serving/engine.py tick_records)
+SCHEMA_VERSION = 16
 
 # step-record fields beyond the required step/ts; values are allowed types
 STEP_FIELDS: Dict[str, tuple] = {
@@ -176,9 +180,6 @@ STEP_FIELDS: Dict[str, tuple] = {
 
 META_KINDS = (
     "run_meta", "telemetry_summary",
-    # schematic collective span template from the compiled step's HLO
-    # ledger (telemetry/trace.py; rendered by scripts/trace_view.py)
-    "trace",
     # flight-recorder flush: the last N steps' health vectors + wall
     # segments (+ per-layer health), written when the anomaly detector
     # fires (telemetry/flight.py)
@@ -213,15 +214,6 @@ META_FIELDS: Dict[str, tuple] = {
     "devices": int,
     # SCHEMA_VERSION stamp (run_meta; --check warns on mismatch)
     "schema_version": int,
-    # trace record: the collective span template
-    "spans": list,
-    # trace record: per-layer FLOP-sized compute spans from the HLO cost
-    # ledger's loop attribution (utils/hlo_cost; telemetry/trace.py)
-    "compute_spans": list,
-    # trace record: the compiled pipeline tick program's per-stage
-    # occupancy rows (telemetry/trace.py::pipe_trace; rendered by
-    # trace_view.py as one timeline row per pipeline stage)
-    "pipe": dict,
     # flight record (telemetry/flight.py)
     "reason": str,
     "steps": list,
@@ -383,6 +375,11 @@ META_FIELDS: Dict[str, tuple] = {
     "prefill_s": _NUM,
     "decode_s": _NUM,
     "fetch_s": _NUM,
+    # the tick's parts as the engine's tick_records has them (schema
+    # v16): [[name, seconds from t_s, seconds], ..] -- the instants the
+    # `tds.tick.*` profiler spans carry; the timeline draws each at its
+    # measured start
+    "spans": list,
     # drafter proposal wall (schema v7, spec-enabled engines only) —
     # the draft side of the draft-vs-verify tick split; decode_s +
     # fetch_s are the verify program's dispatch + sync walls

@@ -1,79 +1,34 @@
 # Copyright 2026 tiny-deepspeed-tpu authors
 # SPDX-License-Identifier: Apache-2.0
 
-"""Step-trace timeline: Chrome-trace / Perfetto span assembly.
+"""Serving timeline: Chrome-trace / Perfetto span assembly from a
+run's metrics JSONL, every span a host-clock measurement.
 
-`scripts/report_run.py` answers "how fast and how healthy"; this module
-answers "WHERE inside a step" — the trace-timeline view production TPU
-stacks debug performance with (cf. the per-stage timeline analysis in
-arXiv:2412.14374).  Two span sources join into one timeline:
-
-  * **measured wall segments** per step — the StepTimer `mark()` splits
-    already in every step record (`data_s` loader wait, `h2d_s` staging,
-    `compute_s` device dispatch + sync).  These are real host-clock
-    windows.
-  * **schematic collective spans** — the compiled step's HLO collective
-    ledger (`utils/hlo_comm.py`) split by (op, loop residency), each span
-    cross-referenced to its ledger entry: wire bytes, op count, per-dtype
-    wire split, and the loop-resident flag (= issued inside the layer
-    scan, where the scheduler can hide its wire behind compute).  The
-    host cannot clock device-internal phases, so these spans subdivide
-    each step's `compute_s` window PROPORTIONALLY BY WIRE BYTES — their
-    widths are schematic (every span carries "schematic": true), their
-    byte/count annotations are exact ledger values.
-
-Pipelined runs add a third source: the compiled tick program
-(parallel/pipe_schedule.py) persisted as the trace record's `pipe` dict
-lays out one timeline row PER PIPELINE STAGE — each tick an equal slice
-of the step's compute window, labeled {F/B/W, chunk, microbatch}, idle
-ticks left as gaps so the schedule bubble is visible whitespace.
-
-`scripts/trace_view.py` turns a run's metrics JSONL into Chrome-trace
-JSON (chrome://tracing, https://ui.perfetto.dev) using this module; the
-`trace` meta record (schema.py) persists the span template so the viewer
-needs no recompile.  tests/test_trace_flight.py pins that every
-loop-resident span's wire bytes match the ledger.
-
-SERVING runs get their own timeline (`serving_chrome_trace`): the
-request-lifecycle `events` on each `request` record and the per-tick
-`tick` records (serving/engine.py, schema v6) lay out as scheduler-tick
-spans with their measured wall split, a queue track (one span per wait
-window, labeled with WHY the request waited: queue / preempted /
+`serving_chrome_trace`: the request-lifecycle `events` on each `request`
+record and the per-tick `tick` records (serving/engine.py) lay out as
+scheduler-tick spans, the parts of each tick (`spans` on the tick record:
+the engine's `tick_records` segments, the same instants its `tds.tick.*`
+profiler spans carry) at their measured starts, a queue track (one span
+per wait window, labeled with WHY the request waited: queue / preempted /
 restart), and one track per decode slot (one span per active window,
 closed with how it ended — finished, preempted, quarantined, expired).
 Quarantines and watchdog restarts are instant markers, so "what led up
 to that restart" is visible at a glance.  All serving stamps share one
-monotonic clock, so the tracks align exactly; only the POSITION of the
-sched/prefill/decode/fetch sub-walls inside a tick is schematic (their
-widths are measured, the true interleave is not recorded).
+monotonic clock, so the tracks align exactly.
+
+`scripts/trace_view.py` turns a serving run's JSONL into Chrome-trace
+JSON (chrome://tracing, https://ui.perfetto.dev) using this module.
+WHERE inside a training step the device's time goes is read from a
+profiler trace (`jax.profiler`; the program's `tds.*` spans and scopes,
+utils/profiling.TABLE), not drawn from a model of it: the schematic
+train timeline that stood here (collective and compute spans sized by
+wire bytes and FLOPs) went with PR 26.
 """
 
 from __future__ import annotations
 
 import json
 from typing import Dict, List, Optional, Tuple
-
-# friendly names per (op, loop_resident): what the schedule MEANS in this
-# codebase — reducing collectives inside the scan are the bucketed/implicit
-# grad release, top-level ones the post-backward sync; all-gathers inside
-# the scan are the ZeRO-3 per-layer weight gathers, top-level ones the
-# ZeRO-1/2 param broadcast
-_SPAN_LABELS = {
-    ("all-reduce", True): "grad all-reduce (in-scan)",
-    ("all-reduce", False): "grad all-reduce (post-backward)",
-    ("reduce-scatter", True): "grad reduce-scatter (in-scan)",
-    ("reduce-scatter", False): "grad reduce-scatter (post-backward)",
-    # all-to-all is the quantized grad schedule's hop when grad_comm is
-    # on — but GSPMD also emits it for plain reshards, so the label stays
-    # op-literal (the args carry the exact bytes either way)
-    ("all-to-all", True): "all-to-all (in-scan)",
-    ("all-to-all", False): "all-to-all (post-backward)",
-    ("all-gather", True): "weight gather (in-scan)",
-    ("all-gather", False): "param broadcast (all-gather)",
-    ("collective-permute", True): "ring/pipeline permute (in-scan)",
-    ("collective-permute", False): "ring/pipeline permute",
-}
-
 
 def _quantile(xs, q: float) -> float:
     """Linear-interpolated quantile, mirror of
@@ -90,101 +45,6 @@ def _quantile(xs, q: float) -> float:
     lo = int(pos)
     hi = min(lo + 1, len(ys) - 1)
     return ys[lo] + (ys[hi] - ys[lo]) * (pos - lo)
-
-
-def collective_span_template(measured: Dict[str, object]) -> List[dict]:
-    """Schematic span template from a `ledger_summary` dict: one span per
-    (collective op, placement), loop-resident first.  Each span:
-
-      {"name", "op", "loop_resident", "wire_bytes", "count",
-       "wire_bytes_by_dtype", "schematic": True}
-
-    `wire_bytes` is the EXACT ledger value for that (op, placement) —
-    the cross-reference tests pin.  The per-dtype split is the op's whole
-    split (the ledger does not subdivide it by placement).  Async
-    start→done window data lives in the `run_meta` record's
-    `comm_overlap` field in the same JSONL, not here."""
-    spans: List[dict] = []
-    wire = measured.get("wire_bytes", {}) or {}
-    in_loop = measured.get("wire_bytes_in_loops", {}) or {}
-    counts = measured.get("count", {}) or {}
-    loop_counts = measured.get("count_in_loops", {}) or {}
-    by_op_dtype = measured.get("wire_bytes_by_op_dtype", {}) or {}
-    for op in sorted(wire):
-        total = float(wire[op])
-        loop_w = float(in_loop.get(op, 0.0))
-        top_w = total - loop_w
-        n_loop = float(loop_counts.get(op, 0.0))
-        n_top = float(counts.get(op, 0.0)) - n_loop
-        for resident, w, n in ((True, loop_w, n_loop),
-                               (False, top_w, n_top)):
-            if w <= 0.0 and n <= 0.0:
-                continue
-            spans.append({
-                "name": _SPAN_LABELS.get((op, resident), op),
-                "op": op,
-                "loop_resident": resident,
-                "wire_bytes": round(w, 3),
-                "count": round(n, 3),
-                "wire_bytes_by_dtype": {
-                    k: round(float(v), 3)
-                    for k, v in by_op_dtype.get(op, {}).items()
-                },
-                "schematic": True,
-            })
-    # loop-resident spans lead: they are issued before the scan finishes
-    spans.sort(key=lambda s: (not s["loop_resident"], s["op"]))
-    return spans
-
-
-def compute_span_template(loops: List[dict],
-                          total_flops: float) -> List[dict]:
-    """Schematic FLOP-sized compute span template from the HLO cost
-    ledger's loop attribution (utils/hlo_cost.cost_ledger `loops`): one
-    span per scan trip for short loops (the n_layer scans — this is the
-    per-layer attribution riding the scan structure), one aggregate span
-    for long loops, and one top-level span for the FLOPs outside every
-    loop (the head/loss matmuls).  Each span:
-
-      {"name", "flops", "loop_resident", "schematic": True}
-      (+ "body", "trips", "trip" on loop spans)
-
-    Widths in the timeline are proportional to `flops` — schematic, like
-    the wire-sized collective spans; the FLOP values are exact ledger
-    numbers."""
-    spans: List[dict] = []
-    loop_total = 0.0
-    for li, lp in enumerate(loops or []):
-        fl = float(lp.get("flops", 0.0))
-        if fl <= 0.0:
-            continue
-        loop_total += fl
-        trips = int(lp.get("trips", 1) or 1)
-        body = str(lp.get("body", f"loop{li}"))
-        if 1 < trips <= 64:
-            per = fl / trips
-            for t in range(trips):
-                spans.append({
-                    "name": f"scan{li} layer {t}",
-                    "body": body, "trips": trips, "trip": t,
-                    "flops": round(per, 3),
-                    "loop_resident": True, "schematic": True,
-                })
-        else:
-            spans.append({
-                "name": f"scan{li} x{trips}",
-                "body": body, "trips": trips,
-                "flops": round(fl, 3),
-                "loop_resident": True, "schematic": True,
-            })
-    top = float(total_flops) - loop_total
-    if top > 0.0:
-        spans.append({
-            "name": "top-level compute (head/loss)",
-            "flops": round(top, 3),
-            "loop_resident": False, "schematic": True,
-        })
-    return spans
 
 
 def load_run(path: str) -> Tuple[List[dict], List[dict], List[str]]:
@@ -214,13 +74,6 @@ def _find(metas: List[dict], kind: str) -> Optional[dict]:
     return None
 
 
-_SEG_NAMES = {
-    "data_s": "data wait",
-    "h2d_s": "host->device",
-    "compute_s": "device compute (+sync)",
-}
-
-
 def _json_safe(v):
     """Non-finite floats become their string names: Python's json happily
     writes bare `NaN`, but chrome://tracing and Perfetto parse STRICT
@@ -234,220 +87,6 @@ def _json_safe(v):
         return [_json_safe(x) for x in v]
     return v
 
-# Chrome-trace track (tid) layout
-_TID_STEP = 0        # whole-step spans
-_TID_SEG = 1         # wall segments
-_TID_COMM = 2        # schematic collective spans
-_TID_FLOPS = 3       # schematic FLOP-sized compute spans (cost ledger)
-_TID_PIPE0 = 4       # pipeline stage s -> tid _TID_PIPE0 + s (tick table)
-
-# the pipe track's op code -> glyph map (parallel/pipe_schedule.OP_*;
-# inlined here so the standalone path-import stays jax-free)
-_PIPE_OPS = {0: "idle", 1: "F", 2: "B", 3: "W"}
-
-
-def pipe_span_rows(pipe: Dict[str, object]) -> List[List[dict]]:
-    """Per-stage span rows from a trace record's `pipe` dict (the
-    compiled tick program serialized by Telemetry.pipe_trace): one list
-    per stage, one span per NON-IDLE tick:
-
-      {"name": "F c3 m1", "op", "tick", "vchunk", "mb",
-       "ticks": T, "schematic": True}
-
-    Tick positions are schedule coordinates — the viewer scales them
-    into each step's compute window (every tick the same width), so the
-    layout is schematic like the wire/FLOP spans; the op/chunk/
-    microbatch labels are the exact compiled program."""
-    ops = pipe.get("op") or []
-    vchunk = pipe.get("vchunk") or []
-    mb = pipe.get("mb") or []
-    n_ticks = int(pipe.get("n_ticks") or (len(ops[0]) if ops else 0))
-    rows: List[List[dict]] = []
-    for st, row in enumerate(ops):
-        spans: List[dict] = []
-        for t, op in enumerate(row):
-            op = int(op)
-            if op == 0:
-                continue
-            c = int(vchunk[st][t]) if vchunk else -1
-            j = int(mb[st][t]) if mb else -1
-            spans.append({
-                "name": f"{_PIPE_OPS.get(op, '?')} c{c} m{j}",
-                "op": _PIPE_OPS.get(op, "?"), "tick": t,
-                "vchunk": c, "mb": j, "ticks": n_ticks,
-                "schematic": True,
-            })
-        rows.append(spans)
-    return rows
-
-
-def chrome_trace(metas: List[dict], steps: List[dict],
-                 source: str = "") -> Dict[str, object]:
-    """Chrome-trace JSON (the `traceEvents` array format) for one run's
-    records: per step a whole-step span + its wall segments on real
-    host-clock time, and the collective span template instantiated inside
-    each step's compute window (widths proportional to wire bytes,
-    schematic).  Timestamps are microseconds from the first record."""
-    spans = None
-    cspans = None
-    pipe = None
-    tr = _find(metas, "trace")
-    if tr is not None:
-        spans = tr.get("spans")
-        cspans = tr.get("compute_spans")
-        pipe = tr.get("pipe")
-    run = _find(metas, "run_meta") or {}
-    if spans is None:
-        measured = run.get("comm_measured")
-        if measured:
-            spans = collective_span_template(measured)
-    spans = spans or []
-    total_wire = sum(s.get("wire_bytes", 0.0) for s in spans) or 1.0
-    cspans = cspans or []
-    total_flops = sum(s.get("flops", 0.0) for s in cspans) or 1.0
-    pipe_rows = pipe_span_rows(pipe) if pipe else []
-    pipe_ticks = int(pipe.get("n_ticks") or 1) if pipe else 1
-
-    events: List[dict] = [
-        {"ph": "M", "pid": 0, "name": "process_name",
-         "args": {"name": f"tiny-deepspeed-tpu run {source}".strip()}},
-        {"ph": "M", "pid": 0, "tid": _TID_STEP, "name": "thread_name",
-         "args": {"name": "step"}},
-        {"ph": "M", "pid": 0, "tid": _TID_SEG, "name": "thread_name",
-         "args": {"name": "host wall segments"}},
-        {"ph": "M", "pid": 0, "tid": _TID_COMM, "name": "thread_name",
-         "args": {"name": "collectives (schematic, HLO ledger)"}},
-    ]
-    if cspans:
-        events.append(
-            {"ph": "M", "pid": 0, "tid": _TID_FLOPS,
-             "name": "thread_name",
-             "args": {"name": "compute (schematic, HLO cost ledger)"}})
-    for st in range(len(pipe_rows)):
-        events.append(
-            {"ph": "M", "pid": 0, "tid": _TID_PIPE0 + st,
-             "name": "thread_name",
-             "args": {"name": f"pipe stage {st} "
-                              f"({pipe.get('describe', 'tick table')})"}})
-
-    timed = [r for r in steps if isinstance(r.get("ts"), (int, float))
-             and isinstance(r.get("step_s"), (int, float))]
-    t0 = min((r["ts"] - r["step_s"] for r in timed), default=0.0)
-
-    def us(seconds: float) -> float:
-        return round(seconds * 1e6, 3)
-
-    for rec in timed:
-        start = rec["ts"] - rec["step_s"] - t0
-        dur = rec["step_s"]
-        step_i = rec.get("step", 0)
-        events.append({
-            "ph": "X", "pid": 0, "tid": _TID_STEP,
-            "name": f"step {step_i}",
-            "ts": us(start), "dur": us(dur),
-            "args": _json_safe({
-                k: rec[k] for k in
-                ("loss", "tokens_per_s", "grad_norm", "nonfinite_grads",
-                 "compiled")
-                if k in rec
-            }),
-        })
-        cursor = start
-        compute_win = (start, dur)
-        for key in ("data_s", "h2d_s", "compute_s"):
-            seg = rec.get(key)
-            if not isinstance(seg, (int, float)):
-                continue
-            events.append({
-                "ph": "X", "pid": 0, "tid": _TID_SEG,
-                "name": _SEG_NAMES[key],
-                "ts": us(cursor), "dur": us(seg),
-                "args": {"seconds": seg},
-            })
-            if key == "compute_s":
-                compute_win = (cursor, seg)
-            cursor += seg
-        # schematic collective sub-spans fill the compute window
-        # proportionally by wire bytes — widths schematic, byte/count
-        # args exact ledger values
-        c0, cdur = compute_win
-        ccursor = c0
-        for sp in spans:
-            w = float(sp.get("wire_bytes", 0.0))
-            sdur = cdur * w / total_wire
-            events.append({
-                "ph": "X", "pid": 0, "tid": _TID_COMM,
-                "name": sp.get("name", sp.get("op", "collective")),
-                "ts": us(ccursor), "dur": us(sdur),
-                "args": _json_safe(
-                    {k: v for k, v in sp.items() if k != "name"}
-                ),
-            })
-            ccursor += sdur
-        # schematic compute sub-spans fill the same compute window
-        # proportionally by FLOPs (cost ledger per-layer attribution) —
-        # the per-layer compute next to the per-layer weight gathers
-        fcursor = c0
-        for sp in cspans:
-            fl = float(sp.get("flops", 0.0))
-            fdur = cdur * fl / total_flops
-            events.append({
-                "ph": "X", "pid": 0, "tid": _TID_FLOPS,
-                "name": sp.get("name", "compute"),
-                "ts": us(fcursor), "dur": us(fdur),
-                "args": _json_safe(
-                    {k: v for k, v in sp.items() if k != "name"}
-                ),
-            })
-            fcursor += fdur
-        # the pipeline tick table: one row per stage, each tick an equal
-        # slice of the compute window (schedule coordinates — schematic
-        # widths, exact op/chunk/microbatch labels); idle ticks render as
-        # gaps, so the bubble is VISIBLE as whitespace on the track
-        tick_dur = cdur / pipe_ticks
-        for st, row in enumerate(pipe_rows):
-            for sp in row:
-                events.append({
-                    "ph": "X", "pid": 0, "tid": _TID_PIPE0 + st,
-                    "name": sp["name"],
-                    "ts": us(c0 + sp["tick"] * tick_dur),
-                    "dur": us(tick_dur),
-                    "args": _json_safe(
-                        {k: v for k, v in sp.items() if k != "name"}
-                    ),
-                })
-
-    flight = _find(metas, "flight")
-    if flight is not None:
-        # instant event marking the flush (the anomaly's log-time stamp)
-        events.append({
-            "ph": "i", "pid": 0, "tid": _TID_STEP, "s": "g",
-            "name": f"flight flush ({flight.get('reason', '?')})",
-            "ts": us(max((r["ts"] - t0 for r in timed), default=0.0)),
-        })
-
-    return {
-        "traceEvents": events,
-        "displayTimeUnit": "ms",
-        "otherData": {
-            "source": source,
-            "schematic_collectives": bool(spans),
-            "schematic_compute": bool(cspans),
-            "schematic_pipeline": bool(pipe_rows),
-            "pipeline_bubble_frac": (
-                round(float(pipe.get("bubble_frac", 0.0)), 6)
-                if pipe else 0.0
-            ),
-            "spans_total_wire_bytes": round(float(sum(
-                s.get("wire_bytes", 0.0) for s in spans
-            )), 3),
-            "spans_total_flops": round(float(sum(
-                s.get("flops", 0.0) for s in cspans
-            )), 3),
-        },
-    }
-
-
 # -- serving timeline ---------------------------------------------------------
 
 # serving Chrome-trace track (tid) layout, pid 1 (pid 0 is training).
@@ -458,7 +97,7 @@ def chrome_trace(metas: List[dict], steps: List[dict],
 _PID_SERVE = 1       # single-engine serving / records with no replica
 _PID_REPLICA0 = 2    # replica r -> pid _PID_REPLICA0 + r
 _TID_TICK = 0        # scheduler ticks
-_TID_TICK_SEG = 1    # per-tick wall split (sched/prefill/decode/fetch)
+_TID_TICK_SEG = 1    # the parts of each tick (the tick record's `spans`)
 _TID_QUEUE = 2       # request wait windows
 _TID_SLOT0 = 3       # decode slot s -> tid _TID_SLOT0 + s
 
@@ -507,14 +146,6 @@ def _event_replicas(events: List[list], record_replica) -> List[object]:
     for j in pending:
         reps[j] = record_replica
     return reps
-_TICK_SEG_ORDER = ("sched_s", "draft_s", "prefill_s", "decode_s",
-                   "fetch_s")
-_TICK_SEG_NAMES = {"sched_s": "host scheduling", "prefill_s": "prefill",
-                   "decode_s": "decode dispatch", "fetch_s": "token fetch",
-                   # speculative engines only (schema v7): the drafter's
-                   # proposal wall; decode dispatch + token fetch are
-                   # then the VERIFY program's spans
-                   "draft_s": "draft propose"}
 
 
 def has_serving_records(metas: List[dict]) -> bool:
@@ -721,21 +352,14 @@ def serving_chrome_trace(metas: List[dict],
                 if k in rec
             }),
         })
-        # measured sub-walls laid out sequentially (position schematic:
-        # the true interleave of scheduling/prefill/decode isn't
-        # recorded; the WIDTHS are the measured splits)
-        cursor = start
-        for key in _TICK_SEG_ORDER:
-            seg = rec.get(key)
-            if not isinstance(seg, (int, float)) or seg <= 0.0:
-                continue
+        # the tick's parts at their measured starts (`spans`: [name,
+        # seconds from the tick's start, seconds])
+        for name, rel, dur in rec.get("spans") or ():
             events.append({
                 "ph": "X", "pid": pid, "tid": _TID_TICK_SEG,
-                "name": _TICK_SEG_NAMES[key],
-                "ts": us(cursor), "dur": us(seg),
-                "args": {"seconds": seg, "schematic_position": True},
+                "name": name, "ts": us(start + rel), "dur": us(dur),
+                "args": {"seconds": dur},
             })
-            cursor += seg
         if rec.get("restarted"):
             events.append({
                 "ph": "i", "pid": pid, "tid": _TID_TICK, "s": "p",

@@ -52,8 +52,7 @@ HBM bytes (a traffic model, not a profile)
 Everything is loop-aware: while bodies multiply by `_trip_count` trips
 (the 12-layer scan, the seq-length scatter loops), with an in-loop vs
 top-level split mirroring the wire ledger, and a per-loop attribution
-list (`loops`) that trace_view uses to size per-layer compute spans next
-to the wire-sized collective spans.
+list (`loops`).
 
 tests/test_hlo_cost.py pins the dot math exactly on tiny synthetic HLO,
 pins trip-count multiplication against the scan length, and pins the
@@ -152,6 +151,29 @@ _HBM_SKIP_OPS = frozenset({
 })
 # container ops whose bodies are walked separately
 _HBM_CONTAINER_OPS = frozenset({"while", "conditional", "call"})
+
+
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: op_name} over every computation of a compiled
+    module's text: the `metadata={op_name="jit(tds_train_step)/..."}` the
+    parsers below strip is where jax.named_scope (`tds.*`) and the
+    transform (`jvp`, `transpose`, `checkpoint`) of each instruction are
+    written.  An instruction without one is left out.  The v5e's device
+    trace carries the same string as `tf_op` on each operation
+    (benchmarks/reduce/spans.py reads it there); this is the same join on
+    a program that has not run."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        d = _DEF_RE.match(line)
+        if d is None:
+            continue
+        m = _OP_NAME_RE.search(line)
+        if m is not None:
+            out[d.group(1)] = m.group(1)
+    return out
 
 
 def _strip_metadata(line: str) -> str:

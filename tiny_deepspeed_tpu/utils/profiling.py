@@ -10,6 +10,9 @@ Here those become real subsystems:
 
   * `trace(logdir)`     — context manager around jax.profiler (XPlane/
     TensorBoard format) for device timelines.
+  * `span(name, **ids)` — the program's own host spans (`tds.*`), on the
+    device trace's clock; `TABLE` names every span, scope, program,
+    kernel and counter with its layer and the metric that reads it.
   * `StepTimer`         — per-step wall timing closed by a device sync (a
     1-element device->host transfer is the barrier).
   * `comm_report(engine)` — the reference's "g"/"2g" comments as computed
@@ -39,6 +42,84 @@ def trace(logdir: str):
         yield logdir
     finally:
         jax.profiler.stop_trace()
+
+
+# Every name the program writes into a profiler trace, or counts for one:
+# name -> (kind, layer as PERF.md section 3 has it, the metric that reads
+# it).  A `span` is a host TraceAnnotation (`span()` below), a `scope` a
+# jax.named_scope inside a compiled program, a `program` the name of a
+# jitted function (the trace's `XLA Modules` line reads `jit_<name>`), a
+# `kernel` the name= of a pallas_call, a `counter` a host clock reading.
+# tests/test_spans.py holds the code to this table in both directions.
+_TICK, _STEP = "serving scheduler", "engine step"
+TABLE = {
+    "tds.submit": ("span", _TICK, "tick_host_ms"),
+    "tds.tick": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.sched": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.admit": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.prefill.dispatch": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.prefill.fetch": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.draft": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.decode.operands": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.decode.dispatch": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.decode.fetch": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.commit": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.observe": ("span", _TICK, "tick_host_ms"),
+    "tds.step": ("span", _STEP, "idle_share.train"),
+    "tds.load": ("span", "input", "input_wait_ms"),
+    "tds.h2d": ("span", "input", "input_wait_ms"),
+    "tds.sync": ("span", _STEP, "idle_share.train"),
+    "tds.embed": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.blocks": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.block": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.ln": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.attn.qkv": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.attn.kernel": ("scope", "kernels (train)", "attn_fwd_ms"),
+    "tds.attn.proj": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.mlp": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.head": ("scope", "kernels (train)", "head_ms"),
+    "tds.cast": ("scope", _STEP, "fwd_ms"),
+    "tds.optim": ("scope", _STEP, "optim_ms"),
+    "tds.gather": ("scope", "collectives", "coll_gather_ms"),
+    "tds.grad_sync": ("scope", "collectives", "coll_grad_ms"),
+    "tds.decode": ("scope", "kernels (serve)", "decode_ms"),
+    "tds.prefill": ("scope", "kernels (serve)", "prefill_ms"),
+    "tds.kv_write": ("scope", "kernels (serve)", "copies_ms.decode"),
+    "tds.sample": ("scope", "kernels (serve)", "decode_ms"),
+    "tds_train_step": ("program", _STEP, "fwd_ms"),
+    "tds_eval": ("program", _STEP, None),
+    "tds_decode": ("program", "kernels (serve)", "decode_ms"),
+    "tds_prefill": ("program", "kernels (serve)", "prefill_ms"),
+    "tds_prefill_suffix": ("program", "kernels (serve)", None),
+    "tds_prefill_spec": ("program", "kernels (serve)", None),
+    "tds_verify": ("program", "kernels (serve)", None),
+    "tds_fa2_fwd": ("kernel", "kernels (train)", "attn_fwd_ms"),
+    "tds_fa2_dkv": ("kernel", "kernels (train)", "attn_bwd_ms"),
+    "tds_fa2_dq": ("kernel", "kernels (train)", "attn_bwd_ms"),
+    "tds_fa2_fwd_packed": ("kernel", "kernels (train)", "attn_fwd_ms"),
+    "tds_fa2_dkv_packed": ("kernel", "kernels (train)", "attn_bwd_ms"),
+    "tds_fa2_dq_packed": ("kernel", "kernels (train)", "attn_bwd_ms"),
+    "tds_ln_fwd": ("kernel", "kernels (train)", "fwd_ms"),
+    "tds_ln_dx": ("kernel", "kernels (train)", "bwd_ms"),
+    "tds_ln_dwdb": ("kernel", "kernels (train)", "bwd_ms"),
+    "tds_paged_attn": ("kernel", "kernels (serve)", "decode_ms"),
+    "tds_quant": ("kernel", "collectives", None),
+    "tds_xent_fwd": ("kernel", "kernels (train)", "head_ms"),
+    "tds_xent_dx": ("kernel", "kernels (train)", "head_ms"),
+    "tds_xent_dw": ("kernel", "kernels (train)", "head_ms"),
+    "import_begin": ("counter", "entry / start-up", "import_s"),
+    "import_done": ("counter", "entry / start-up", "import_s"),
+    "select_platform": ("counter", "entry / start-up", "backend_init_s"),
+    "backend_up": ("counter", "entry / start-up", "backend_init_s"),
+}
+
+
+def span(name: str, **ids):
+    """A host span on the profiler's clock, the same clock as the device's
+    operations: `with span("tds.tick.admit", request=7): ...`.  With no
+    profiler session active this is a dead TraceAnnotation (well under a
+    microsecond, nothing kept); there is nothing to switch on."""
+    return jax.profiler.TraceAnnotation(name, **ids)
 
 
 def device_sync(x) -> float:
@@ -144,10 +225,11 @@ class StepTimer:
             raise
         if self._last_out is not None:
             leaf = jax.tree.leaves(self._last_out)[0]
-            if self.fetch_full and leaf.size <= 1024:
-                host = np.asarray(leaf).ravel()
-            else:
-                host = np.asarray(leaf.ravel()[0:1])
+            with span("tds.sync"):
+                if self.fetch_full and leaf.size <= 1024:
+                    host = np.asarray(leaf).ravel()
+                else:
+                    host = np.asarray(leaf.ravel()[0:1])
             self.last_host = host
             self.last_value = float(host[0])
             self._last_out = None

@@ -20,8 +20,16 @@ Two decisions an entry point must not make on its own:
 from __future__ import annotations
 
 import os
+import time
 
 import jax
+
+# Instants on time.monotonic()'s clock, taken before any profiler session
+# can exist: `import_begin` / `import_done` (tiny_deepspeed_tpu/__init__.py,
+# around the package's own imports), `select_platform` (entered) and
+# `backend_up` (jax.default_backend() has returned).  Read by the
+# benchmark's `import_s` and `backend_init_s`.
+marks = {}
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -46,12 +54,14 @@ def select_platform(cpu_devices: int = 0, cpu: bool = False,
     With neither, the TPU is required: SystemExit otherwise, naming
     `cpu_flag` (the caller's flag spelling, if it has one).  Returns the
     backend name."""
+    marks["select_platform"] = time.monotonic()
     if cpu_devices or cpu:
         jax.config.update("jax_platforms", "cpu")
     if cpu_devices:
         jax.config.update("jax_num_cpu_devices", int(cpu_devices))
     compile_cache_dir()
     backend = jax.default_backend()
+    marks["backend_up"] = time.monotonic()
     if not (cpu_devices or cpu) and backend != "tpu":
         raise SystemExit(
             f"no TPU: jax.default_backend() is {backend!r} "
