@@ -429,17 +429,21 @@ class TestTpuTopologyHLO:
         assert compiled.as_text().count("tpu_custom_call") >= 3
 
     def test_paged_attention_compiles_on_tpu(self, topo_mesh):
-        """Mosaic accepts the paged-attention kernel at the gpt2-124m
-        serving shapes chip_smoke.py runs (12 heads of 64, 16-token
-        blocks, 21-entry tables, 4 slots): the decode variant and a
-        5-wide verify span, bf16 and f32 pools — interpret mode cannot
-        check tiling rules."""
+        """Mosaic accepts the paged-attention kernel over the pool's
+        resting (blocks, 16, L * KVH * Dh) at the gpt2-124m serving
+        shapes chip_smoke.py runs (12 heads of 64, 16-token blocks,
+        21-entry tables, 4 slots) and at a Llama GQA shape (8 query
+        heads over 2 KV heads of 128): the decode variant and a 5-wide
+        verify span; bf16, f32, int8 and fp8 pools — interpret mode
+        cannot check tiling rules."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import SingleDeviceSharding
 
         from tiny_deepspeed_tpu.ops.paged_attn_pallas import paged_attention
-        from tiny_deepspeed_tpu.serving.pool import KVPoolView, page_ref
+        from tiny_deepspeed_tpu.serving.pool import (
+            KVPoolView, page_ref, pool_shape,
+        )
 
         sh = SingleDeviceSharding(
             np.asarray(topo_mesh.devices).reshape(-1)[0])
@@ -447,22 +451,106 @@ class TestTpuTopologyHLO:
         def struct(shape, dt):
             return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 
-        def attend(q, k, v, tables, pos, l, sk=None, sv=None):
-            view = KVPoolView(k, v, None, None)
-            span = None if sk is None else (sk, sv)
+        def attend(kvh, q, k, v, ks, vs, tables, pos, l, sk, sv):
+            view = KVPoolView(k, v, ks, vs)
             return paged_attention(q, view, page_ref(tables, pos, 16), l,
-                                   span_kv=span)
+                                   (sk, sv), kv_heads=kvh)
 
-        for dt in (jnp.bfloat16, jnp.float32):
-            pool = struct((97, 16, 12, 12, 64), dt)
-            ints = (struct((4, 21), jnp.int32), struct((4,), jnp.int32),
-                    struct((), jnp.int32))
-            for k1 in (1, 5):
-                q = struct((4, 12, k1, 64), dt)
-                span = () if k1 == 1 else (struct((4, 12, k1, 64), dt),) * 2
-                text = jax.jit(attend).lower(
-                    q, pool, pool, *ints, *span).compile().as_text()
-                assert "tpu_custom_call" in text, (dt, k1)
+        ints = (struct((4, 21), jnp.int32), struct((4,), jnp.int32),
+                struct((), jnp.int32))
+        for hq, kvh, dh in ((12, 12, 64), (8, 2, 128)):
+            for dt in (jnp.bfloat16, jnp.float32, jnp.int8,
+                       jnp.float8_e4m3fn):
+                quant = jnp.dtype(dt).itemsize == 1
+                cdt = jnp.bfloat16 if quant else dt
+                pool = struct(pool_shape(97, 16, 12, kvh, dh), dt)
+                scale = (struct(pool_shape(97, 16, 12, kvh, 1),
+                                jnp.float32) if quant else None)
+                for k1 in (1, 5):
+                    q = struct((4, hq, k1, dh), cdt)
+                    span = (struct((4, kvh, k1, dh), cdt),) * 2
+                    text = jax.jit(attend, static_argnums=0).lower(
+                        kvh, q, pool, pool, scale, scale, *ints, *span
+                    ).compile().as_text()
+                    assert "tpu_custom_call" in text, (hq, dt, k1)
+
+    def test_serve_programs_never_copy_the_pool(self, topo_mesh):
+        """`tds_decode` and `tds_prefill` compiled for the v5e at the
+        sizes of the benchmark's serve cell (gpt2-124m, 64 slots, 4097
+        blocks of 16 tokens, bf16): no `copy` or `transpose`, alone or
+        inside a fusion, touches anything with the pool's element count,
+        and the pool's arguments are the unpadded 2 x 4097 x 16 x 12 x
+        12 x 64 x 2 bytes, aliased to the outputs.  The engine is built
+        with a few blocks (its arrays are real, on the CPU); its two
+        programs are lowered from shapes."""
+        import re
+
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import SingleDeviceSharding
+
+        from tiny_deepspeed_tpu.models import build_model
+        from tiny_deepspeed_tpu.serving import ServeConfig, ServingEngine
+        from tiny_deepspeed_tpu.serving.pool import KVPoolView
+
+        sh = SingleDeviceSharding(
+            np.asarray(topo_mesh.devices).reshape(-1)[0])
+        slots, bt, blocks, bucket = 64, 16, 4097, 512
+        cfg = GPTConfig(block_size=1024, vocab_size=50304, n_layer=12,
+                        n_head=12, n_embd=768, param_dtype=jnp.bfloat16)
+        model = build_model(cfg)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0))
+        eng = ServingEngine(model, params, ServeConfig(
+            max_active=slots, num_blocks=4, block_tokens=bt,
+            temperature=0.0, eos_id=None))
+
+        def like(a, shape=None):
+            return jax.ShapeDtypeStruct(shape or a.shape, a.dtype,
+                                        sharding=sh)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+
+        small = eng.pool.view
+        view = KVPoolView(
+            like(small.k, (blocks,) + small.k.shape[1:]),
+            like(small.v, (blocks,) + small.v.shape[1:]), None, None)
+        elems = int(np.prod(view.k.shape))
+        pool_bytes = 2 * blocks * bt * 12 * 12 * 64 * 2
+        assert 2 * elems * 2 == pool_bytes == eng.pool.kv_bytes()[
+            "kv_block_bytes"] // 5 * blocks
+        p, st = jax.tree.map(like, params), jax.tree.map(like, eng._stacked)
+        programs = {
+            "tds_decode": (eng._decode_fn, (
+                p, st, view, ints(slots), ints(slots),
+                ints(slots, cfg.block_size // bt), ints(slots), ints(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=sh))),
+            "tds_prefill": (eng._prefill_fn, (
+                p, st, ints(1, bucket), ints(), ints(bucket // bt), view,
+                ints(), ints())),
+        }
+        other = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                    for a in jax.tree.leaves((p, st)))
+        shape_re = re.compile(r"\w+\[([\d,]+)\]")
+        for name, (fn, args) in programs.items():
+            with kernel_target_forced("tpu"):
+                compiled = fn.lower(*args).compile()
+            text = compiled.as_text()
+            assert f"jit_{name}" in text and "tpu_custom_call" in text
+            for line in text.splitlines():
+                if not re.search(r"= .*\b(copy|transpose)\(", line):
+                    continue
+                sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+                         for dims in shape_re.findall(line)]
+                assert max(sizes, default=0) < elems, (name, line[:200])
+            mem = compiled.memory_analysis()
+            assert mem.alias_size_in_bytes == pool_bytes, name
+            # what else comes in is weights (a program takes the ones
+            # it reads) and a few integers: no padding hides in the sum
+            assert (pool_bytes < mem.argument_size_in_bytes
+                    < pool_bytes + other + (1 << 20)), name
+            # nothing pool-sized in flight either
+            assert mem.temp_size_in_bytes < (64 << 20), name
 
     def test_off_grid_lengths_compile_on_tpu(self, topo_mesh):
         """A forward at a sequence length off the kernels' 128 grid (a

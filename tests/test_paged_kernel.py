@@ -6,7 +6,7 @@
 Acceptance pins:
   * the Pallas paged-attention kernel (ops/paged_attn_pallas.py, run in
     interpret mode on the CPU CI mesh) matches the XLA reference —
-    `paged_panel` + `_decode_attention` / `_span_attention` — to float
+    `paged_panel` + `_span_attention` — to float
     tolerance on random pool contents, GQA and quantized pools
     included, and is greedy TOKEN-IDENTICAL through a real
     ServingEngine staggered-admission trace (plain decode AND the
@@ -76,14 +76,21 @@ def _pool_view(quant, kvh=2, dh=16, L=2, bt=8, blocks=16):
                       dtype=jnp.float32, quant=quant)
     view = pool.view
     k1, k2 = jax.random.split(jax.random.PRNGKey(7))
-    raw_k = jax.random.normal(k1, view.k.shape, jnp.float32)
-    raw_v = jax.random.normal(k2, view.v.shape, jnp.float32)
+    # drawn (and quantized) per head vector, then merged into the
+    # pool's resting (blocks, bt, L * KVH * Dh)
+    raw = (blocks + 1, bt, L, kvh, dh)
+    raw_k = jax.random.normal(k1, raw, jnp.float32)
+    raw_v = jax.random.normal(k2, raw, jnp.float32)
     if quant:
         from tiny_deepspeed_tpu.serving.pool import _quant_vectors
         qk, sk = _quant_vectors(raw_k, quant)
         qv, sv = _quant_vectors(raw_v, quant)
-        return view._replace(k=qk, v=qv, k_scale=sk, v_scale=sv)
-    return view._replace(k=raw_k, v=raw_v)
+        return view._replace(
+            k=qk.reshape(view.k.shape), v=qv.reshape(view.v.shape),
+            k_scale=sk.reshape(view.k_scale.shape),
+            v_scale=sv.reshape(view.v_scale.shape))
+    return view._replace(k=raw_k.reshape(view.k.shape),
+                         v=raw_v.reshape(view.v.shape))
 
 
 _TABLES = [[1, 2, 3, 0], [4, 5, 0, 0], [6, 0, 0, 0]]
@@ -104,16 +111,21 @@ class TestPagedKernelParity:
         pytest.param("fp8", 4, marks=pytest.mark.slow),
     ])
     def test_decode_matches_xla(self, model, quant, hq):
+        """The decode step: a span of ONE token per slot, its own K/V
+        beside the committed prefix in the pool."""
         view = _pool_view(quant)
         tables = jnp.asarray(_TABLES, jnp.int32)
         pos = jnp.asarray([25, 9, 0], jnp.int32)  # mid/partial/first token
         page = page_ref(tables, pos, 8)
-        q = jax.random.normal(jax.random.PRNGKey(3), (3, hq, 1, 16),
-                              jnp.float32)
+        ks = jax.random.split(jax.random.PRNGKey(3), 3)
+        q = jax.random.normal(ks[0], (3, hq, 1, 16), jnp.float32)
+        sk = jax.random.normal(ks[1], (3, 2, 1, 16), jnp.float32)
+        sv = jax.random.normal(ks[2], (3, 2, 1, 16), jnp.float32)
         for layer in range(2):
-            ck, cv = paged_panel(view, layer, page, jnp.float32)
-            ref = model._decode_attention(q, ck, cv, pos)
-            got = PAP.paged_attention(q, view, page, layer)
+            ck, cv = paged_panel(view, layer, page, 2, 16, jnp.float32)
+            ref = model._span_attention(q, ck, cv, sk, sv, pos)
+            got = PAP.paged_attention(q, view, page, layer, (sk, sv),
+                                      kv_heads=2)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=2e-5, atol=2e-5)
 
@@ -137,13 +149,13 @@ class TestPagedKernelParity:
         def run(view, q, sk, sv, page):
             def body(c, layer):
                 return c, PAP.paged_attention(q, view, page, layer,
-                                              span_kv=(sk, sv))
+                                              (sk, sv), kv_heads=2)
             _, ys = jax.lax.scan(body, 0, jnp.arange(2))
             return ys
 
         ys = jax.jit(run)(view, q, sk, sv, page)
         for layer in range(2):
-            ck, cv = paged_panel(view, layer, page, jnp.float32)
+            ck, cv = paged_panel(view, layer, page, 2, 16, jnp.float32)
             ref = model._span_attention(q, ck, cv, sk, sv, pos0)
             np.testing.assert_allclose(np.asarray(ys[layer]),
                                        np.asarray(ref),

@@ -285,9 +285,9 @@ class TestQuantizedCache:
         v = rng.normal(size=(s, kvh, dh)).astype(np.float32)
         tables = np.asarray([[1, 0], [2, 0], [3, 0]], np.int32)
         ref = page_ref(jnp.asarray(tables), jnp.zeros((s,), jnp.int32), 4)
-        view = paged_append(pool.view, jnp.asarray(k), jnp.asarray(v), 0,
-                            ref)
-        ck, cv = paged_panel(view, 0, ref, jnp.float32)
+        view = paged_append(pool.view, jnp.asarray(k)[None],
+                            jnp.asarray(v)[None], ref)
+        ck, cv = paged_panel(view, 0, ref, kvh, dh, jnp.float32)
         got_k = np.asarray(ck)[:, :, 0, :]  # position 0 of each panel
         got_v = np.asarray(cv)[:, :, 0, :]
         for got, ref_a in ((got_k, k), (got_v, v)):

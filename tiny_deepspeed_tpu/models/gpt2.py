@@ -594,87 +594,47 @@ class GPT2Model:
     # SHARED preallocated block pool (serving/pool.py): each slot's K/V
     # panel is gathered through its block table instead of sliced from a
     # per-request max-length buffer, and every slot sits at its OWN
-    # position (vector `pos`).  The attention itself is the existing GQA
-    # `_decode_attention`; only the cache read/write changes.
+    # position (vector `pos`).  ONE layer loop serves every program that
+    # reads the pool (`paged_verify`, below): it scores a span of tokens
+    # per slot against the committed prefix plus the span itself and
+    # never writes; the caller commits the span's K/V afterwards.
 
-    def _paged_attention(self, q, view, l, page, span_kv=None):
-        """ONE dispatch seam for pool-panel attention, shared by the
-        paged decode and spec-verify/suffix-prefill paths of every
-        family: the Pallas fused gather+attention kernel when the gate
-        says so (ops/paged_attn_pallas.use_paged_kernel — TPU targets,
-        or forced via ServeConfig.paged_kernel), else the XLA reference
-        (materialized `paged_panel` + `_decode_attention` /
-        `_span_attention`).  q (S, Hq, K1, Dh); span_kv = (sk, sv) span
-        K/V switches to the span-verify mask."""
+    def _paged_attention(self, q, view, l, page, span_kv):
+        """ONE dispatch seam for attention over the pool, shared by the
+        decode, spec-verify and suffix-prefill programs of every family:
+        the Pallas fused gather+attention kernel when the gate says so
+        (ops/paged_attn_pallas.use_paged_kernel — TPU targets, or forced
+        via ServeConfig.paged_kernel), else the XLA reference
+        (materialized `paged_panel` + `_span_attention`).  q
+        (S, Hq, K1, Dh) span queries; span_kv = (sk, sv) the span's own
+        K/V, each (S, KVH, K1, Dh): the pool gives the committed prefix,
+        positions < page.pos."""
         from ..ops.dispatch import note_kernel
         from ..ops.paged_attn_pallas import paged_attention, use_paged_kernel
+        # the pool's merged minor dimension shows neither size
+        kvh = getattr(self.config, "kv_heads", self.config.n_head)
         if use_paged_kernel():
             note_kernel("paged_attention", "pallas:paged_attention")
-            return paged_attention(q, view, page, l, span_kv=span_kv)
+            return paged_attention(q, view, page, l, span_kv, kv_heads=kvh)
         note_kernel("paged_attention", "xla:paged_panel")
         from ..serving.pool import paged_panel
-        ck, cv = paged_panel(view, l, page, self.config.compute_dtype)
-        if span_kv is None:
-            return self._decode_attention(q, ck, cv, page.pos)
-        sk, sv = span_kv
-        return self._span_attention(q, ck, cv, sk, sv, page.pos)
-
-    def _paged_attn_decode(self, x, bp, view, l, page):
-        """Attention half of one paged decode step.  x: (S, 1, D); view:
-        serving.pool.KVPoolView (the pool arrays, riding the layer-scan
-        carry so writes alias); page: serving.pool.PageRef (block tables
-        + per-slot write coordinates, loop-invariant)."""
-        c = self.config
-        s = x.shape[0]
-        scope = jax.named_scope
-        with scope("tds.ln"):
-            h = layernorm(x, bp["ln_1.w"], bp["ln_1.b"])
-        with scope("tds.attn.qkv"):
-            qkv = linear(h, self._bw(bp, "attn.qkv.w"),
-                         bp.get("attn.qkv.b"))
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-
-            def heads1(z):
-                return z.reshape(
-                    s, 1, c.n_head, c.head_dim).swapaxes(1, 2)
-
-            qh, kh, vh = heads1(q), heads1(k)[:, :, 0], heads1(v)[:, :, 0]
-        from ..serving.pool import paged_append
-        with scope("tds.kv_write"):
-            view = paged_append(view, kh, vh, l, page)
-        with scope("tds.attn.kernel"):
-            y = self._paged_attention(qh, view, l, page)
-        with scope("tds.attn.proj"):
-            y = y.swapaxes(1, 2).reshape(s, 1, c.n_embd)
-            y = linear(y, self._bw(bp, "attn.proj.w"),
-                       bp.get("attn.proj.b"))
-            return x + y, view
-
-    def _paged_block_decode(self, x, bp, view, l, page):
-        """One block, one token per slot, cache in the paged pool."""
-        with jax.named_scope("tds.block"):
-            x, view = self._paged_attn_decode(x, bp, view, l, page)
-            with jax.named_scope("tds.mlp"):
-                return self._mlp_decode(x, bp), view
+        ck, cv = paged_panel(view, l, page, kvh, q.shape[-1],
+                             self.config.compute_dtype)
+        return self._span_attention(q, ck, cv, *span_kv, page.pos)
 
     def paged_decode(self, stacked, x, view, page):
-        """Layer loop for one paged decode token — the pool view rides
-        the CARRY (like `_decode_blocks`' contiguous caches) so each
-        layer's block write aliases the pool instead of restacking it."""
-        n_layer = jax.tree.leaves(stacked)[0].shape[0]
-
-        def body(carry, l):
-            x, view = carry
-            bp = jax.tree.map(
-                lambda t: jax.lax.dynamic_index_in_dim(
-                    t, l, 0, keepdims=False), stacked)
-            x, view = self._paged_block_decode(x, bp, view, l, page)
-            return (x, view), None
-
-        with jax.named_scope("tds.blocks"):
-            (x, view), _ = jax.lax.scan(
-                body, (x, view), jnp.arange(n_layer),
-                unroll=self.config.scan_unroll)
+        """One paged decode token per slot, x (S, 1, D): the verify pass
+        below over a span of ONE — every layer reads the committed
+        prefix through the block tables and the token's own K/V from
+        the span — then ONE write of the token's K/V rows of all layers
+        at (page.blk, page.off).  A write per layer would touch the
+        pool L times a token; the token's rows of every layer lie side
+        by side in the pool's minor dimension, so one scatter of whole
+        rows places them."""
+        from ..serving.pool import paged_append
+        x, ks, vs = self.paged_verify(stacked, x, view, page)
+        with jax.named_scope("tds.kv_write"):
+            view = paged_append(view, ks[:, :, :, 0], vs[:, :, :, 0], page)
         return x, view
 
     # -- speculative verification (serving/spec.py) ------------------------
@@ -749,30 +709,39 @@ class GPT2Model:
         acceptance commit."""
         c = self.config
         s, k1, _ = x.shape
-        h = layernorm(x, bp["ln_1.w"], bp["ln_1.b"])
-        qkv = linear(h, self._bw(bp, "attn.qkv.w"), bp.get("attn.qkv.b"))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        scope = jax.named_scope
+        with scope("tds.ln"):
+            h = layernorm(x, bp["ln_1.w"], bp["ln_1.b"])
+        with scope("tds.attn.qkv"):
+            qkv = linear(h, self._bw(bp, "attn.qkv.w"),
+                         bp.get("attn.qkv.b"))
+            q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads(z):
-            return z.reshape(s, k1, c.n_head, c.head_dim).swapaxes(1, 2)
+            def heads(z):
+                return z.reshape(s, k1, c.n_head, c.head_dim).swapaxes(1, 2)
 
-        kh, vh = heads(k), heads(v)
-        y = self._paged_attention(heads(q), view, l, page,
-                                  span_kv=(kh, vh))
-        y = y.swapaxes(1, 2).reshape(s, k1, c.n_embd)
-        y = linear(y, self._bw(bp, "attn.proj.w"), bp.get("attn.proj.b"))
-        return x + y, (kh, vh)
+            qh, kh, vh = heads(q), heads(k), heads(v)
+        with scope("tds.attn.kernel"):
+            y = self._paged_attention(qh, view, l, page, span_kv=(kh, vh))
+        with scope("tds.attn.proj"):
+            y = y.swapaxes(1, 2).reshape(s, k1, c.n_embd)
+            y = linear(y, self._bw(bp, "attn.proj.w"),
+                       bp.get("attn.proj.b"))
+            return x + y, (kh, vh)
 
     def _paged_verify_block(self, x, bp, view, l, page):
-        x, kv = self._paged_verify_attn(x, bp, view, l, page)
-        return self._mlp_decode(x, bp), kv
+        with jax.named_scope("tds.block"):
+            x, kv = self._paged_verify_attn(x, bp, view, l, page)
+            with jax.named_scope("tds.mlp"):
+                return self._mlp_decode(x, bp), kv
 
     def paged_verify(self, stacked, x, view, page):
-        """Layer loop for one speculative verify: x (S, K1, D) span
-        activations.  The view is never written (it rides the closure,
-        not the carry); each layer's span K/V stack as scan ys —
-        (L, S, KVH, K1, Dh) per side — for `paged_append_span` to commit
-        the accepted prefix."""
+        """Layer loop over a span per slot, x (S, K1, D) span
+        activations: the plain decode token (K1 = 1, `paged_decode`), a
+        speculative verify, a shared-prefix suffix prefill.  The view is
+        never written (it rides the closure, not the carry); each
+        layer's span K/V stack as scan ys — (L, S, KVH, K1, Dh) per side
+        — for `paged_append` / `paged_append_span` to commit."""
         n_layer = jax.tree.leaves(stacked)[0].shape[0]
 
         def body(x, l):
@@ -782,9 +751,10 @@ class GPT2Model:
             x, kv = self._paged_verify_block(x, bp, view, l, page)
             return x, kv
 
-        x, (sks, svs) = jax.lax.scan(
-            body, x, jnp.arange(n_layer),
-            unroll=self.config.scan_unroll)
+        with jax.named_scope("tds.blocks"):
+            x, (sks, svs) = jax.lax.scan(
+                body, x, jnp.arange(n_layer),
+                unroll=self.config.scan_unroll)
         return x, sks, svs
 
     @jax.named_scope("tds.head")

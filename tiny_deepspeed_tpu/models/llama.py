@@ -134,7 +134,7 @@ class LlamaModel(GPT2Model):
     grad_bucket_capable = True
     gather_prefetch_capable = True
     layer_health_capable = True
-    # paged decode: _paged_attn_decode below (RoPE at per-slot positions)
+    # paged decode: _paged_verify_attn below (RoPE at each span position)
     paged_decode_capable = True
 
     def __init__(self, config: LlamaConfig):
@@ -285,28 +285,6 @@ class LlamaModel(GPT2Model):
     def _block_decode(self, x, bp, ks, vs, l, pos):
         x, ks, vs = self._attn_decode(x, bp, ks, vs, l, pos)
         return self._mlp_decode(x, bp), ks, vs
-
-    def _paged_attn_decode(self, x, bp, view, l, page):
-        """Paged-pool decode attention (GPT2Model contract): separate
-        q/k/v projections, per-row RoPE at each slot's own position,
-        grouped attention over the gathered block panel."""
-        c = self.config
-        b = x.shape[0]
-        hd = c.head_dim
-        h = rmsnorm(x, bp["ln_1.w"])
-        q = linear(h, self._bw(bp, "attn.q.w"), None)
-        k = linear(h, self._bw(bp, "attn.k.w"), None)
-        v = linear(h, self._bw(bp, "attn.v.w"), None)
-        q = q.reshape(b, 1, c.n_head, hd).swapaxes(1, 2)
-        k = k.reshape(b, 1, c.kv_heads, hd).swapaxes(1, 2)
-        v = v.reshape(b, 1, c.kv_heads, hd).swapaxes(1, 2)
-        q = rope_at(q, page.pos, c.rope_theta)
-        k = rope_at(k, page.pos, c.rope_theta)
-        from ..serving.pool import paged_append
-        view = paged_append(view, k[:, :, 0], v[:, :, 0], l, page)
-        y = self._paged_attention(q, view, l, page)
-        y = y.swapaxes(1, 2).reshape(b, 1, c.n_embd)
-        return x + linear(y, self._bw(bp, "attn.o.w"), None), view
 
     def _paged_verify_attn(self, x, bp, view, l, page):
         """Speculative-verify attention (GPT2Model contract): separate

@@ -20,9 +20,11 @@ Phase split, two compiled programs:
     Prompts pad to power-of-two block-multiple buckets, so distinct
     compiled shapes stay O(log block_size).
   * DECODE — ONE token for EVERY active slot: (S, 1, D) activations,
-    each slot reading/writing the pool through its block table at its
-    own position (vector `pos`).  Invalid slots carry scratch
-    coordinates; no branch, no recompile as occupancy changes.
+    each slot reading the pool through its block table at its own
+    position (vector `pos`) — a span of one through the same layer
+    loop the verify and suffix-prefill programs use — and its K/V rows
+    of all layers written once, after the loop.  Invalid slots carry
+    scratch coordinates; no branch, no recompile as occupancy changes.
 
 Block exhaustion preempts the YOUNGEST active request (its blocks free
 immediately; it re-queues at the FRONT and later re-prefills from
@@ -622,8 +624,11 @@ class ServingEngine:
                                            temp, top_k)
             return nxt, view
 
-        # the pool view is DONATED through both programs: each step
-        # aliases the pool buffers instead of copying the whole pool
+        # the pool view is DONATED through both programs, so each step
+        # writes into the pool's own buffers; and it rests in the shape
+        # (blocks, bt, L * KVH * Dh) whose default device layout is the
+        # one both programs index (serving/pool.py), so neither converts
+        # it on entry or exit: donation alone could not spare that copy
         self._decode_fn = _kwrap(jax.jit(tds_decode, donate_argnums=(2,)))
         self._prefill_fn = _kwrap(
             jax.jit(tds_prefill, donate_argnums=(5,)))

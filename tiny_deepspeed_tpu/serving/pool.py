@@ -7,7 +7,7 @@ The contiguous decode cache (`GPT2Model._prefill`) allocates
 (L, B, Hkv, T_max, Dh) per generate() call — every request pays for its
 MAXIMUM length up front, and concurrent requests of different lengths
 cannot share the allocation.  Serving traffic needs the opposite: the
-pool here is ONE (num_blocks, block_tokens, L, KVH, Dh) K/V pair sized
+pool here is ONE (num_blocks, block_tokens, L * KVH * Dh) K/V pair sized
 for the whole engine, carved into fixed `block_tokens`-token blocks.  A
 request owns just the blocks its current length needs (a host-side block
 table of physical block ids); a finished request's blocks return to the
@@ -15,6 +15,23 @@ free list and the next admission reuses them.  On TPU this is the
 decode-throughput design point (the Gemma serving comparison, PAPERS.md
 arXiv:2605.25645): HBM stays densely packed with live cache, so batch
 occupancy — not per-request padding — bounds tokens/s.
+
+The resting shape is the one both compiled programs index as it stands
+(`pool_shape`): a token's K (or V) vectors of every layer and head lie
+side by side in the minor dimension, layer-major then head then Dh, so
+layer l is the column block [l * KVH * Dh, (l + 1) * KVH * Dh).  With
+KVH * Dh a multiple of 128 and `block_tokens` a multiple of the dtype's
+sublane tile (16 rows of bf16) the last two dimensions are whole TPU
+tiles: the device's default layout is row-major with no padding, a
+(block, layer) is one contiguous run of tiles for the attention
+kernel's DMA, and neither program has to convert the pool on entry or
+exit (a trailing (KVH, Dh) = (12, 64) would pad to (16, 128) tiles, and
+the layout the runtime picks to avoid that is one no program uses).
+Where KVH * Dh is not a multiple of 128 (the tiny CPU test models) or
+the dtype's tile is taller than a block (int8, fp8: 32 rows), the
+device pads the pool and everything stays correct.  Nothing here
+reshapes or transposes a pool array: writes are scatters of slivers
+reshaped to meet it, reads are gathers of column windows.
 
 Physical block 0 is SCRATCH: never allocated, it absorbs the writes of
 invalid slots and bucket-padding positions so the compiled step stays
@@ -24,10 +41,11 @@ every read path masks by true position before the softmax.
 Quantized cache blocks (`quant="int8" | "fp8"`) rest the pool at 1
 byte/element, reusing the blockwise-absmax codec from `parallel/comm.py`
 (the grad_comm PR's machinery) with the codec block = one (Dh,) head
-vector and the f32 scale stored per (block, token, layer, head) — the
+vector and the f32 scale stored per (block, token, layer, head) as
+(num_blocks, block_tokens, L * KVH), the same rule one Dh shorter — the
 place a per-vector scale gets to live that the contiguous in-scan cache
 never had.  Dequantization happens at attention time on the gathered
-panel; `_decode_attention` then accumulates in f32 as always.
+panel; `_span_attention` then accumulates in f32 as always.
 
 Everything jit-traceable is a pure function over `KVPoolView` (a pytree
 riding the decode scan's carry); `PagedKVPool` is the host-side owner:
@@ -51,9 +69,9 @@ _QDTYPE = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 class KVPoolView(NamedTuple):
     """The pool's device arrays, as traced through the compiled steps.
 
-    k/v: (num_blocks, block_tokens, L, KVH, Dh) in the resting dtype
+    k/v: (num_blocks, block_tokens, L * KVH * Dh) in the resting dtype
     (resolved_cache_dtype, or int8/e4m3 when quantized); k_scale/v_scale:
-    (num_blocks, block_tokens, L, KVH) f32 per-head-vector absmax scales,
+    (num_blocks, block_tokens, L * KVH) f32 per-head-vector absmax scales,
     None on the unquantized path (None prunes to an empty pytree subtree,
     so the compiled step never sees the operands)."""
 
@@ -93,6 +111,14 @@ def quant_mode(view: KVPoolView) -> Optional[str]:
     return "int8" if view.k.dtype == jnp.int8 else "fp8"
 
 
+def pool_shape(num_blocks: int, block_tokens: int, n_layer: int,
+               kv_heads: int, head_dim: int) -> tuple:
+    """THE resting shape of a K (or V) pool array — the one rule, from
+    the sizes alone.  The scales of a quantized pool follow it with
+    head_dim = 1."""
+    return (num_blocks, block_tokens, n_layer * kv_heads * head_dim)
+
+
 def _quant_vectors(x, mode: str):
     """(..., Dh) f32-able -> (q same shape, scales (...,)) via the
     grad-comm blockwise-absmax codec with codec block = the Dh head
@@ -107,44 +133,75 @@ def _quant_vectors(x, mode: str):
     return q.reshape(x.shape), s.reshape(x.shape[:-1])
 
 
-def paged_append(view: KVPoolView, k, v, l, page: PageRef) -> KVPoolView:
-    """Write one token's K/V sliver per slot — k/v (S, KVH, Dh) — at
-    (page.blk, page.off, l).  Invalid slots' coordinates point at the
-    scratch block, so the scatter is branch-free."""
+def _rest(view: KVPoolView, k, v, lead: tuple):
+    """K/V (..., KVH, Dh) in compute dtype -> the four arrays as the
+    pool rests them, the trailing dimensions of each merged into the
+    pool's minor one and the leading ones reshaped to `lead`:
+    (k, v, k_scale, v_scale), the scales None on an unquantized pool."""
     mode = quant_mode(view)
     if mode is None:
-        return view._replace(
-            k=view.k.at[page.blk, page.off, l].set(k.astype(view.k.dtype)),
-            v=view.v.at[page.blk, page.off, l].set(v.astype(view.v.dtype)),
-        )
+        return (k.astype(view.k.dtype).reshape(*lead, -1),
+                v.astype(view.v.dtype).reshape(*lead, -1), None, None)
     qk, sk = _quant_vectors(k, mode)
     qv, sv = _quant_vectors(v, mode)
-    return KVPoolView(
-        k=view.k.at[page.blk, page.off, l].set(qk),
-        v=view.v.at[page.blk, page.off, l].set(qv),
-        k_scale=view.k_scale.at[page.blk, page.off, l].set(sk),
-        v_scale=view.v_scale.at[page.blk, page.off, l].set(sv),
-    )
+    return (qk.reshape(*lead, -1), qv.reshape(*lead, -1),
+            sk.reshape(*lead, -1), sv.reshape(*lead, -1))
 
 
-def paged_panel(view: KVPoolView, l, page: PageRef, out_dtype):
+def _set_rows(view: KVPoolView, idx: tuple, rk, rv, sk, sv) -> KVPoolView:
+    """view[idx] = rows, every layer's columns at once: `idx` indexes
+    the leading dimension(s), the rows span the whole minor one."""
+    new = view._replace(k=view.k.at[idx].set(rk), v=view.v.at[idx].set(rv))
+    if sk is None:
+        return new
+    return new._replace(k_scale=view.k_scale.at[idx].set(sk),
+                        v_scale=view.v_scale.at[idx].set(sv))
+
+
+def _get_columns(pool, tables, col, width: int):
+    """pool[tables[s, j], :, col : col + width] -> (S, W, bt, width):
+    one gather of block-high column windows, nothing pool-sized made."""
+    idx = jnp.stack(
+        [tables, jnp.broadcast_to(col, tables.shape)], axis=-1
+    ).astype(jnp.int32)
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(2, 3), collapsed_slice_dims=(0,),
+        start_index_map=(0, 2))
+    return jax.lax.gather(pool, idx, dn,
+                          slice_sizes=(1, pool.shape[1], width))
+
+
+def paged_append(view: KVPoolView, ks, vs, page: PageRef) -> KVPoolView:
+    """Write one token's K/V per slot, every layer at once — ks/vs
+    (L, S, KVH, Dh), the decode step's scan ys — as ONE row per slot
+    at (page.blk, page.off).  Invalid slots' coordinates point at the
+    scratch block, so the scatter is branch-free."""
+    rows = _rest(view, ks.swapaxes(0, 1), vs.swapaxes(0, 1),
+                 (ks.shape[1],))
+    return _set_rows(view, (page.blk, page.off), *rows)
+
+
+def paged_panel(view: KVPoolView, l, page: PageRef, kv_heads: int,
+                head_dim: int, out_dtype):
     """Gather layer l's K/V panels through the block tables:
     (S, KVH, max_blocks * block_tokens, Dh) per side, ready for
-    `_decode_attention`.  Unquantized panels stay in the pool's resting
-    dtype (the attention consumes it directly); quantized panels
-    dequantize to `out_dtype` here — the 1-byte blocks are what crossed
-    HBM, the dequantized panel is attention-local."""
+    `_span_attention`.  `kv_heads` / `head_dim` are the caller's
+    static sizes: the merged minor dimension no longer shows them.
+    Unquantized panels stay in the pool's resting dtype (the attention
+    consumes it directly); quantized panels dequantize to `out_dtype`
+    here — the 1-byte blocks are what crossed HBM, the dequantized
+    panel is attention-local."""
     mode = quant_mode(view)
+    kvh, dh = kv_heads, head_dim
 
     def panel(pool, scale):
-        pl = jax.lax.dynamic_index_in_dim(pool, l, 2, keepdims=False)
-        g = pl[page.tables]  # (S, Bmax, bt, KVH, Dh)
-        s, bmax, bt, kvh, dh = g.shape
+        g = _get_columns(pool, page.tables, l * (kvh * dh), kvh * dh)
+        s, bmax, bt, _ = g.shape
         g = g.reshape(s, bmax * bt, kvh, dh).swapaxes(1, 2)
         if mode is None:
             return g
-        sl = jax.lax.dynamic_index_in_dim(scale, l, 2, keepdims=False)
-        sg = sl[page.tables].reshape(s, bmax * bt, kvh).swapaxes(1, 2)
+        sg = _get_columns(scale, page.tables, l * kvh, kvh)
+        sg = sg.reshape(s, bmax * bt, kvh).swapaxes(1, 2)
         return (g.astype(jnp.float32) * sg[..., None]).astype(out_dtype)
 
     return panel(view.k, view.k_scale), panel(view.v, view.v_scale)
@@ -176,25 +233,11 @@ def paged_append_span(view: KVPoolView, ks, vs, tables, pos0, count,
     blk = jnp.where(valid, blk, SCRATCH_BLOCK)
     off = jnp.where(valid, wpos % block_tokens, 0)
 
-    def prep(a):  # (L, S, KVH, K1, Dh) -> (S*K1, L, KVH, Dh) slabs
-        return a.transpose(1, 3, 0, 2, 4).reshape(S * K1, L, KVH, Dh)
+    def prep(a):  # (L, S, KVH, K1, Dh) -> (S, K1, L, KVH, Dh) token rows
+        return a.transpose(1, 3, 0, 2, 4)
 
-    kb, vb = prep(ks), prep(vs)
-    bf, of = blk.reshape(-1), off.reshape(-1)
-    mode = quant_mode(view)
-    if mode is None:
-        return view._replace(
-            k=view.k.at[bf, of].set(kb.astype(view.k.dtype)),
-            v=view.v.at[bf, of].set(vb.astype(view.v.dtype)),
-        )
-    qk, sk = _quant_vectors(kb, mode)
-    qv, sv = _quant_vectors(vb, mode)
-    return KVPoolView(
-        k=view.k.at[bf, of].set(qk),
-        v=view.v.at[bf, of].set(qv),
-        k_scale=view.k_scale.at[bf, of].set(sk),
-        v_scale=view.v_scale.at[bf, of].set(sv),
-    )
+    rows = _rest(view, prep(ks), prep(vs), (S * K1,))
+    return _set_rows(view, (blk.reshape(-1), off.reshape(-1)), *rows)
 
 
 class BlockPayload(NamedTuple):
@@ -202,9 +245,10 @@ class BlockPayload(NamedTuple):
     engines' pools — the disaggregated prefill->decode migration unit
     (fleet/disagg.py).  Arrays keep the pool's RESTING dtype: a
     quantized pool hands off 1-byte blocks plus their f32 scales, so
-    migrated bytes get the same 4x compression as pool bytes.  k/v:
-    (n_blocks, block_tokens, L, KVH, Dh); scales (n_blocks, block_tokens,
-    L, KVH) or None on the unquantized path."""
+    migrated bytes get the same 4x compression as pool bytes — and the
+    pool's resting SHAPE, so neither side converts anything.  k/v:
+    (n_blocks, block_tokens, L * KVH * Dh); scales (n_blocks,
+    block_tokens, L * KVH) or None on the unquantized path."""
 
     k: jax.Array
     v: jax.Array
@@ -249,7 +293,7 @@ def import_blocks(view: KVPoolView, ids: List[int],
     if tuple(payload.k.shape[1:]) != tuple(view.k.shape[1:]):
         raise ValueError(
             f"paged-KV migration geometry mismatch: payload blocks are "
-            f"{tuple(payload.k.shape[1:])} (block_tokens, L, KVH, Dh) "
+            f"{tuple(payload.k.shape[1:])} (block_tokens, L * KVH * Dh) "
             f"but this pool's are {tuple(view.k.shape[1:])}"
         )
     if len(ids) != payload.k.shape[0]:
@@ -257,17 +301,7 @@ def import_blocks(view: KVPoolView, ids: List[int],
             f"{len(ids)} destination blocks for a "
             f"{payload.k.shape[0]}-block payload"
         )
-    idx = jnp.asarray(list(ids), jnp.int32)
-    new = view._replace(
-        k=view.k.at[idx].set(payload.k),
-        v=view.v.at[idx].set(payload.v),
-    )
-    if view.k_scale is not None:
-        new = new._replace(
-            k_scale=view.k_scale.at[idx].set(payload.k_scale),
-            v_scale=view.v_scale.at[idx].set(payload.v_scale),
-        )
-    return new
+    return _set_rows(view, (jnp.asarray(list(ids), jnp.int32),), *payload)
 
 
 def payload_bytes(payload: BlockPayload) -> int:
@@ -286,28 +320,16 @@ def paged_scatter(view: KVPoolView, ks, vs, block_ids,
     """Scatter a prefill's full-prompt K/V — ks/vs (L, 1, KVH, P, Dh)
     from the `return_kv` forward hook — into the pool blocks `block_ids`
     ((P / block_tokens,) physical ids; bucket-padding tail entries point
-    at scratch).  P is the bucket length, always a block multiple."""
-    mode = quant_mode(view)
+    at scratch).  P is the bucket length, always a block multiple.  The
+    slab is what is transposed to meet the pool, never the pool."""
+    p = ks.shape[3]
 
-    def prep(a):
-        L, b, kvh, p, dh = a.shape  # b == 1: prefill is per-request
-        a = a[:, 0].transpose(2, 0, 1, 3)  # (P, L, KVH, Dh)
-        return a.reshape(p // block_tokens, block_tokens, L, kvh, dh)
+    def prep(a):  # b == 1: prefill is per-request
+        return a[:, 0].transpose(2, 0, 1, 3)  # (P, L, KVH, Dh)
 
-    kb, vb = prep(ks), prep(vs)
-    if mode is None:
-        return view._replace(
-            k=view.k.at[block_ids].set(kb.astype(view.k.dtype)),
-            v=view.v.at[block_ids].set(vb.astype(view.v.dtype)),
-        )
-    qk, sk = _quant_vectors(kb, mode)
-    qv, sv = _quant_vectors(vb, mode)
-    return KVPoolView(
-        k=view.k.at[block_ids].set(qk),
-        v=view.v.at[block_ids].set(qv),
-        k_scale=view.k_scale.at[block_ids].set(sk),
-        v_scale=view.v_scale.at[block_ids].set(sv),
-    )
+    rows = _rest(view, prep(ks), prep(vs),
+                 (p // block_tokens, block_tokens))
+    return _set_rows(view, (block_ids,), *rows)
 
 
 class PagedKVPool:
@@ -343,14 +365,18 @@ class PagedKVPool:
         self.block_tokens = int(block_tokens)
         self.quant = quant
         total = self.num_usable + 1  # + scratch
-        shape = (total, block_tokens, n_layer, kv_heads, head_dim)
+        shape = pool_shape(total, block_tokens, n_layer, kv_heads, head_dim)
         rest = _QDTYPE.get(quant, dtype)
 
         def scale():
             # distinct arrays per side: the view is DONATED through the
             # compiled steps, and two fields aliasing one zeros buffer
             # would be a double donation
-            return jnp.zeros(shape[:-1], jnp.float32) if quant else None
+            if not quant:
+                return None
+            return jnp.zeros(
+                pool_shape(total, block_tokens, n_layer, kv_heads, 1),
+                jnp.float32)
 
         self.view = KVPoolView(
             k=jnp.zeros(shape, rest), v=jnp.zeros(shape, rest),
