@@ -624,3 +624,58 @@ class TestTpuTopologyHLO:
         grouped = wire(hkv)
         expanded = wire(hq)
         assert grouped < 0.35 * expanded, (grouped, expanded)
+
+
+def test_eva_decode_step_compiles_for_the_chip_at_published_widths(
+        topo_mesh):
+    """EvaByte's decode program at the benchmark cell's sizes (6 layers,
+    d 4096, 32 heads of 128, 16 slots, 16-row blocks, the pool of 16 x 256
+    blocks): Mosaic takes `tds_eva_paged_attn` (16 K and 16 V blocks of
+    (16, 4096) bf16 a grid step), the pool is aliased from argument to
+    result, and no temporary of the pool's size is made."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tiny_deepspeed_tpu.models import ALL_PRESETS, build_model
+    from tiny_deepspeed_tpu.serving.pool import KVPoolView, pool_shape
+
+    one = SingleDeviceSharding(topo_mesh.devices.reshape(-1)[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = dataclasses.replace(ALL_PRESETS["evabyte-6.5b-6l"],
+                              param_dtype=jnp.bfloat16)
+    model = build_model(cfg)
+    params = {k: sds(v.shape, v.dtype) for k, v in jax.eval_shape(
+        model.init, jax.random.PRNGKey(0)).items()}
+    stacked = {k[2:]: v for k, v in params.items() if k.startswith("h.")}
+    slots, bt = 16, 16
+    lay = model.paged_layout(cfg.block_size, bt)
+    assert sum(lay.need(cfg.block_size - 1)) == 256
+    shape = pool_shape(slots * 256 + 1, bt, cfg.n_layer, cfg.n_head,
+                       cfg.head_dim)
+    view = KVPoolView(sds(shape, jnp.bfloat16), sds(shape, jnp.bfloat16),
+                      None, None)
+
+    def decode(params, stacked, view, tokens, pos, tables):
+        x = model._embed_decode(params, tokens, pos)
+        page = model.paged_page_ref(tables, pos, bt)
+        x, view = model.paged_decode(stacked, x, view, page)
+        return model.head(params, x)[:, 0], view
+
+    ints = sds((slots,), jnp.int32)
+    with kernel_target_forced("tpu"):
+        compiled = jax.jit(decode, donate_argnums=(2,)).lower(
+            params, stacked, view, ints, ints,
+            sds((slots, lay.width), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "tds_eva_paged_attn" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(shape)) * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20

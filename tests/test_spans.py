@@ -105,8 +105,21 @@ def tick_spans(model_params, tmp_path_factory):
         spec.submit([5, 6, 7] * 6, 6)
         spec.drain()
 
+    # a model with two kinds of cache (the only writer of tds.tick.roll)
+    eva_model = build_model(ALL_PRESETS["evabyte-tiny"])
+    eva = ServingEngine(eva_model, eva_model.init(jax.random.PRNGKey(0)),
+                        ServeConfig(max_active=2, num_blocks=64,
+                                    block_tokens=8, temperature=0.0))
+    eva.submit(list(range(1, 30)), 3)
+    eva.drain()
+
+    def eva_body():
+        eva.submit(list(range(1, 30)), 8)
+        eva.drain()
+
     found = _traced(tmp_path_factory.mktemp("tick"), body)
     drafted = _traced(tmp_path_factory.mktemp("spec"), spec_body)
+    drafted += _traced(tmp_path_factory.mktemp("eva"), eva_body)
     assert warm.done and all(r.done for r in reqs)
     return eng, reqs, found, drafted
 
@@ -146,6 +159,12 @@ def test_every_span_of_the_table_is_written_and_none_besides(
     written = {e.name for e in ticks + drafted + step_spans}
     assert written == _names("span")
     assert "tds.tick.draft" in {e.name for e in drafted}
+    # a window that starts over is counted on the span, and in the record
+    rolls = [dict(e.stats) for e in drafted if e.name == "tds.tick.roll"]
+    assert len(rolls) == 7 and sum(r["windows_rolled"] for r in rolls) == 1
+    assert all(r["active"] == 1 and r["window_blocks"] == 4 for r in rolls)
+    assert [r["rows"] for r in rolls] == [
+        n % 32 + n // 32 * 8 for n in range(29, 36)]
 
 
 def test_tick_spans_nest_as_the_table_says_and_carry_the_tick_number(
@@ -275,8 +294,11 @@ def test_a_dead_span_costs_under_two_microseconds_and_keeps_nothing():
         with span("tds.tick.admit", request=7):
             pass
 
-    n = 20000
-    best = min(timeit.repeat(one, number=n, repeat=5)) / n
+    # the best of many short batches: on a CPU that six test workers
+    # share, one batch can be slow throughout; a span that allocates or
+    # records is slow in every batch
+    n = 2000
+    best = min(timeit.repeat(one, number=n, repeat=40)) / n
     assert best < 2e-6, f"{best * 1e6:.2f} us"
     import gc
     gc.collect()
@@ -457,7 +479,7 @@ def test_every_pallas_kernel_has_its_table_name():
         names = re.findall(r'\bname="(tds_\w+)"', src)
         assert len(names) == calls, path
         found.update(names)
-    assert found == _names("kernel") and len(found) == 14
+    assert found == _names("kernel") and len(found) == 15
 
 
 def test_the_table_names_layers_and_metrics_that_exist():

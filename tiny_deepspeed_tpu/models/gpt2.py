@@ -1293,3 +1293,25 @@ class GPT2Model:
 
         buf, _ = jax.lax.fori_loop(t0, t0 + max_new_tokens, body, (buf, key))
         return buf[:, : t0 + max_new_tokens]
+
+    # -- what a family may state differently about the paged pool ----------
+    # (kept last: the Mosaic kernels' cache keys hold their callers' line
+    # numbers, so a method put above them would re-key every program)
+
+    def paged_layout(self, max_seq: int, block_tokens: int):
+        """How a slot's blocks are laid out where one block table of
+        ceil(length / block_tokens) entries does not say it: None here
+        (K and V of the whole context), `models/evabyte.EvaLayout` for a
+        family that keeps a window and chunk summaries."""
+        return None
+
+    def paged_page_ref(self, tables, pos, block_tokens: int):
+        """The decode step's write coordinates (serving/pool.page_ref:
+        position p lands in table entry p // block_tokens)."""
+        from ..serving.pool import page_ref
+        return page_ref(tables, pos, block_tokens)
+
+    def sampling_logits(self, logits):
+        """The columns of the head's output that score the next token:
+        all of them here."""
+        return logits

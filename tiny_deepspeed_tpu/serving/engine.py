@@ -391,9 +391,13 @@ class _Slot:
 
     def __init__(self, req: Request, table: List[int], pos: int,
                  last_token: int, admitted_at: float,
-                 prefill_s: float = 0.0):
+                 prefill_s: float = 0.0,
+                 summary: Optional[List[int]] = None):
         self.req = req
         self.table = table
+        # a second table, of summary blocks, where the model keeps two
+        # kinds of cache (models/evabyte.EvaLayout); else empty
+        self.summary: List[int] = summary or []
         self.pos = pos
         self.last = last_token
         self.admitted_at = admitted_at
@@ -401,6 +405,11 @@ class _Slot:
         # window when it closes, so the decode-active component never
         # double-counts the prefill component
         self.prefill_s = prefill_s
+
+    @property
+    def blocks(self) -> List[int]:
+        """Every block the slot owns, of either table."""
+        return self.table + self.summary
 
 
 @dataclasses.dataclass
@@ -454,6 +463,26 @@ class ServingEngine:
                 "is not wired through the suffix path — run one or "
                 "the other"
             )
+        # a model that keeps a window and chunk summaries (two block
+        # tables a slot) says so; what cannot follow it yet is refused
+        # here, by the mechanism's name
+        self._layout = model.paged_layout(
+            config.max_seq_tokens or c.block_size, config.block_tokens)
+        if self._layout is not None:
+            for on, what in (
+                    (config.prefix_cache, "prefix_cache: the radix tree "
+                     "shares blocks of K/V by token prefix, and knows no "
+                     "window ring or summary rows"),
+                    (config.spec_draft is not None, "spec_draft: the "
+                     "verify program scores a span per slot, and the "
+                     "window ring and summary rows take one position"),
+                    (config.quant is not None, "quant: a quantized pool "
+                     "keeps per-vector scales the summary rows and the "
+                     "EVA decode kernel do not read")):
+                if on:
+                    raise ValueError(
+                        f"{type(model).__name__} cannot be served with "
+                        + what)
         self.model = model
         self.params = params
         self.config = config
@@ -484,16 +513,28 @@ class ServingEngine:
         # file) — after max_seq so the stamp reflects the real geometry
         self.journal = journal
         kv_heads = getattr(c, "kv_heads", c.n_head)
+        # one block table row per slot, wide enough for a max_seq
+        # request (both tables side by side where there are two);
+        # unused entries point at scratch
+        self.max_blocks_per_req = (
+            -(-self.max_seq // config.block_tokens)
+            if self._layout is None else self._layout.width)
+        # a model that states what a slot CAN hold bounds the pool,
+        # whatever num_blocks says: a block beyond max_active slots'
+        # worst case could never be allocated (a caller that sizes the
+        # pool as slots x context / block_tokens would ask for 8 times
+        # what a window and its summaries take)
+        num_blocks = config.num_blocks
+        if self._layout is not None:
+            num_blocks = min(num_blocks, config.max_active * sum(
+                self._need(self.max_seq - 1)))
         self._pool_args = dict(
             n_layer=c.n_layer, kv_heads=kv_heads, head_dim=c.head_dim,
-            num_blocks=config.num_blocks,
+            num_blocks=num_blocks,
             block_tokens=config.block_tokens,
             dtype=resolved_cache_dtype(c), quant=config.quant,
         )
         self.pool = PagedKVPool(**self._pool_args)
-        # one block table row per slot, wide enough for a max_seq
-        # request; unused entries point at scratch
-        self.max_blocks_per_req = -(-self.max_seq // config.block_tokens)
         self._slots: List[Optional[_Slot]] = [None] * config.max_active
         # admission queue: plain FIFO, or the weighted-fair per-tenant
         # stride scheduler when tenants are configured
@@ -597,7 +638,7 @@ class ServingEngine:
                        seeds, nprod, poison):
             with jax.named_scope("tds.decode"):
                 x = model._embed_decode(params, tokens, pos)
-                page = page_ref(tables, pos, bt)
+                page = model.paged_page_ref(tables, pos, bt)
                 x, view = model.paged_decode(stacked, x, view, page)
                 logits = model.head(params, x)[:, 0]
                 # chaos operand: 0.0 off-path (tokens bit-identical —
@@ -609,7 +650,8 @@ class ServingEngine:
                 bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
                 with jax.named_scope("tds.sample"):
                     nxt = sample_logits_per_slot(
-                        logits, base_key, seeds, nprod, temp, top_k)
+                        model.sampling_logits(logits), base_key, seeds,
+                        nprod, temp, top_k)
             return nxt, logits, bad, view
 
         def tds_prefill(params, stacked, prompt, last_pos, block_ids,
@@ -620,8 +662,9 @@ class ServingEngine:
                     stacked=stacked,
                 )
                 with jax.named_scope("tds.sample"):
-                    nxt = sample_logits_at(logits, base_key, seed, nprod,
-                                           temp, top_k)
+                    nxt = sample_logits_at(model.sampling_logits(logits),
+                                           base_key, seed, nprod, temp,
+                                           top_k)
             return nxt, view
 
         # the pool view is DONATED through both programs, so each step
@@ -632,8 +675,11 @@ class ServingEngine:
         self._decode_fn = _kwrap(jax.jit(tds_decode, donate_argnums=(2,)))
         self._prefill_fn = _kwrap(
             jax.jit(tds_prefill, donate_argnums=(5,)))
-        # "h.*" compute-dtype cast once — params are frozen while serving
-        self._stacked = jax.jit(model.stacked_compute_params)(params)
+        # "h.*" compute-dtype cast once — params are frozen while serving.
+        # Not jitted: a tensor that already rests in compute dtype is
+        # then handed on as it is, where a jitted cast would rest the
+        # block weights a second time (2.2 GiB of evabyte-6.5b-6l in bf16)
+        self._stacked = model.stacked_compute_params(params)
         # shared-prefix suffix prefill: when admission aliased m full
         # blocks, only the UNMATCHED suffix runs — a span program (the
         # spec-verify attention pointed at prefill): suffix tokens
@@ -794,7 +840,7 @@ class ServingEngine:
                    if self.max_seq < c.block_size
                    else f"block_size {c.block_size}")
             )
-        worst = -(-total // self.config.block_tokens)
+        worst = sum(self._need(total - 1))
         if worst > self.pool.num_usable:
             raise ValueError(
                 f"request needs up to {worst} blocks but the pool has "
@@ -1066,8 +1112,13 @@ class ServingEngine:
             raise ValueError(f"slot {i} is empty — nothing to export")
         req = slot.req
         now = time.monotonic()
+        if self._layout is not None:
+            raise ValueError(
+                f"{type(self.model).__name__} cannot export a request's "
+                "blocks: export_blocks / import_blocks move one table of "
+                "K/V blocks, not a window ring and summary rows")
         payload = export_blocks(self.pool.view, slot.table)
-        self.pool.free_blocks(slot.table)
+        self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
         self._close_active(req, slot, now)
         req.state = "queued"
@@ -1098,6 +1149,11 @@ class ServingEngine:
         False (nothing consumed) when no slot or blocks are free;
         geometry/dtype mismatches between the pools raise with both
         sides named (serving/pool.import_blocks)."""
+        if self._layout is not None:
+            raise ValueError(
+                f"{type(self.model).__name__} cannot import a request's "
+                "blocks: export_blocks / import_blocks move one table of "
+                "K/V blocks, not a window ring and summary rows")
         if self._spec is not None:
             raise ValueError(
                 "import_request on a speculative engine is unsupported "
@@ -1326,6 +1382,9 @@ class ServingEngine:
             seeds[i] = s.req.seed
             nprod[i] = len(s.req.tokens)
             tables[i, :len(s.table)] = s.table
+            if s.summary:
+                at = self._layout.window
+                tables[i, at:at + len(s.summary)] = s.summary
         if self._poison_pending:
             for i in self._poison_pending:
                 poison[i] = np.nan
@@ -1339,6 +1398,8 @@ class ServingEngine:
         with self._span("decode.operands"):
             tokens, pos, seeds, nprod, poison, tables = \
                 self._slot_arrays(active)
+        if self._layout is not None:
+            self._note_cache(active)
         # dispatch returns before the device finishes (async); the
         # np.asarray token fetch is the sync — the tick record splits
         # the two (decode.dispatch vs decode.fetch)
@@ -1378,6 +1439,25 @@ class ServingEngine:
                     "poisoned decode ticks"
                 )
         return produced
+
+    def _note_cache(self, active) -> None:
+        """What the slots of a model with two kinds of cache hold this
+        tick, counted into the tick's record and written as the ids of
+        `tds.tick.roll`: blocks by kind, the rows the decode step will
+        attend (live window rows and visible summaries), and how many
+        slots START A NEW WINDOW with this step -- their ring is written
+        from row 0 again, with no free and no alloc."""
+        w = self._layout.window_size
+        per = w // self._layout.chunk_size
+        counts = dict(
+            window_blocks=sum(len(s.table) for _, s in active),
+            summary_blocks=sum(len(s.summary) for _, s in active),
+            windows_rolled=sum(s.pos > 0 and s.pos % w == 0
+                               for _, s in active))
+        with self._span("roll", tick=self._tick["tick"], active=len(active),
+                        rows=sum(s.pos % w + s.pos // w * per
+                                 for _, s in active), **counts):
+            self._tick.update(counts)
 
     def _decode_spec(self, active) -> int:
         """Speculative tick: drafter proposes up to K tokens per slot,
@@ -1530,21 +1610,35 @@ class ServingEngine:
             b *= 2
         return min(b, self.model.config.block_size)
 
-    def _prefill_operands(self, prompt_now: List[int], ids: List[int]):
+    def _need(self, horizon: int):
+        """(blocks of the table, summary blocks) a slot owns before it
+        writes position `horizon`: one block per block_tokens positions
+        and no second table, unless the model's layout says otherwise."""
+        if self._layout is None:
+            return horizon // self.config.block_tokens + 1, 0
+        return self._layout.need(horizon)
+
+    def _prefill_operands(self, prompt_now: List[int], ids: List[int],
+                          summary: Sequence[int] = ()):
         """The full-prompt prefill program's (padded prompt, block-id
         panel) operands — shared by the plain and spec admission
-        paths."""
+        paths.  With a layout the panel is two, side by side: the blocks
+        of one window, then a summary row per chunk of the bucket."""
         p = len(prompt_now)
         bt = self.config.block_tokens
         bucket = self._bucket(p)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt_now
-        block_ids = np.full((bucket // bt,), SCRATCH_BLOCK, np.int32)
+        nw, ns = ((bucket // bt, 0) if self._layout is None
+                  else self._layout.prefill_panel(bucket))
+        block_ids = np.full((nw + ns,), SCRATCH_BLOCK, np.int32)
         # the prefill panel only spans the bucket; the +1 decode
         # block can lie past it (boundary p == bucket) — it is
         # reached through the slot table, not the prefill scatter
-        k = min(len(ids), bucket // bt)
+        k = min(len(ids), nw)
         block_ids[:k] = ids[:k]
+        k = min(len(summary), ns)
+        block_ids[nw:nw + k] = summary[:k]
         return padded, block_ids
 
     def _next_queued(self) -> Optional[Request]:
@@ -1636,20 +1730,23 @@ class ServingEngine:
                     # to the request's final position — replaces p: same
                     # worst-case block count as the plain path, claimed
                     # up front instead of across the first few grows
+                    n_table, n_summary = self._need(
+                        self._write_horizon(req, p))
                     ids_new = self._alloc(
-                        self._write_horizon(req, p) // bt + 1 - len(alias))
+                        n_table + n_summary - len(alias))
                     if ids_new is None:
                         if alias:
                             self.pool.free_blocks(alias)  # roll the pin back
                         break
                     ids = alias + ids_new
+                    ids, summary = ids[:n_table], ids[n_table:]
                     self._pop_queued(req)
                     if self._prefill_exc is not None:
                         # chaos: the prefill "fails"; put everything back
                         # the way a real mid-admission fault would find
                         # it and let the watchdog take it from here
                         exc, self._prefill_exc = self._prefill_exc, None
-                        self.pool.free_blocks(ids)
+                        self.pool.free_blocks(ids + summary)
                         if isinstance(self._queue, TenantQueue):
                             self._queue.refund(req)  # no work happened
                         self._queue.appendleft(req)
@@ -1702,7 +1799,7 @@ class ServingEngine:
                         prop = (() if self._spec is None else (np.int32(
                             self._spec.on_admit(slot_i, prompt_now)),))
                         padded, block_ids = self._prefill_operands(
-                            prompt_now, ids)
+                            prompt_now, ids, summary)
                         bucket = padded.shape[1]
                         fn, args = self._prefill_fn, (
                             self.params, self._stacked, padded, p - 1,
@@ -1710,7 +1807,7 @@ class ServingEngine:
                             np.int32(len(req.tokens)), *prop,
                         )
                 with self._span("prefill.dispatch", request=req.id,
-                                bucket=bucket):
+                                bucket=bucket, tokens=p):
                     nxt, view = fn(*args)
                     self.pool.view = view
                 with self._span("prefill.fetch", request=req.id):
@@ -1726,7 +1823,7 @@ class ServingEngine:
                 # window at the admission stamp keeps the latency
                 # partition telescoping (the aborted window bills to
                 # the wait bucket it interrupted).
-                self.pool.free_blocks(ids)
+                self.pool.free_blocks(ids + summary)
                 req.event("admission_aborted", time.monotonic(), slot_i)
                 req._wait_since = t_adm
                 if isinstance(self._queue, TenantQueue):
@@ -1747,7 +1844,8 @@ class ServingEngine:
                     req.prefix_blocks += len(alias)
                     req.prefix_tokens += len(alias) * bt
                 slot = _Slot(req, table=ids, pos=p, last_token=tok,
-                             admitted_at=t_adm, prefill_s=pf)
+                             admitted_at=t_adm, prefill_s=pf,
+                             summary=summary)
                 self._slots[slot_i] = slot
                 req.state = "active"
                 self._count("serve_admissions")
@@ -1779,16 +1877,25 @@ class ServingEngine:
         """Allocate the next block for any slot whose write horizon
         crossed a block boundary; on exhaustion, preempt the youngest
         active request until the grower fits (or is itself preempted)."""
+        def short(slot):
+            """The table that lacks a block for the next write, if one
+            does (a window ring that is whole never grows again)."""
+            n_table, n_summary = self._need(
+                self._write_horizon(slot.req, slot.pos))
+            if len(slot.table) < n_table:
+                return slot.table
+            return slot.summary if len(slot.summary) < n_summary else None
+
         for i, slot in enumerate(self._slots):
             if slot is None or self._slots[i] is not slot:
                 continue
-            while (self._slots[i] is slot
-                   and len(slot.table)
-                   < self._write_horizon(slot.req, slot.pos)
-                   // self.config.block_tokens + 1):
+            while self._slots[i] is slot:
+                table = short(slot)
+                if table is None:
+                    break
                 ids = self._alloc(1)  # prefix tree yields before preemption
                 if ids is not None:
-                    slot.table.extend(ids)
+                    table.extend(ids)
                     continue
                 victim_i, victim = max(
                     ((j, s) for j, s in enumerate(self._slots)
@@ -1809,7 +1916,7 @@ class ServingEngine:
     def _preempt(self, i: int, slot: _Slot) -> None:
         req = slot.req
         now = time.monotonic()
-        self.pool.free_blocks(slot.table)
+        self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
         req.state = "queued"
         self._close_active(req, slot, now)
@@ -1889,7 +1996,7 @@ class ServingEngine:
     def _finish(self, i: int, slot: _Slot) -> None:
         req = slot.req
         now = time.monotonic()
-        self.pool.free_blocks(slot.table)
+        self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
         self._evictions += 1
         self._count("serve_evictions")
@@ -1901,7 +2008,7 @@ class ServingEngine:
     def _expire(self, i: int, slot: _Slot) -> None:
         req = slot.req
         now = time.monotonic()
-        self.pool.free_blocks(slot.table)
+        self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
         self._expired += 1
         self._count("serve_expired")
@@ -1913,7 +2020,7 @@ class ServingEngine:
     def _quarantine(self, i: int, slot: _Slot) -> None:
         req = slot.req
         now = time.monotonic()
-        self.pool.free_blocks(slot.table)
+        self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
         self._quarantined += 1
         self._count("serve_quarantined")
@@ -2170,6 +2277,10 @@ class ServingEngine:
             decode_s=round(seg["decode_s"], 6),
             fetch_s=round(seg["fetch_s"], 6),
         )
+        if "window_blocks" in rec:
+            # a model with two kinds of cache: what its slots hold
+            counts.update((k, rec[k]) for k in (
+                "window_blocks", "summary_blocks", "windows_rolled"))
         if self._spec is not None:
             # the draft-vs-verify wall split: draft_s is the drafter's
             # proposal wall, decode_s+fetch_s the verify program's —

@@ -152,7 +152,12 @@ _NUM = (int, float)
 #      templates: spans / compute_spans / pipe), removed with the
 #      timeline it fed; + `spans` on `tick` records: the tick's parts at
 #      their measured starts (serving/engine.py tick_records)
-SCHEMA_VERSION = 16
+#  17: + window_blocks / summary_blocks / windows_rolled on `tick`
+#      records of an engine whose model keeps two kinds of cache (a
+#      window ring and chunk summaries, models/evabyte.py): what its
+#      slots hold that tick and how many started a new window; other
+#      engines' tick records are byte-compatible with v16 readers
+SCHEMA_VERSION = 17
 
 # step-record fields beyond the required step/ts; values are allowed types
 STEP_FIELDS: Dict[str, tuple] = {
@@ -397,6 +402,10 @@ META_FIELDS: Dict[str, tuple] = {
     "quarantined": int,
     "restarted": int,
     "produced": int,
+    # what the slots of a two-cache model hold (schema v17)
+    "window_blocks": int,
+    "summary_blocks": int,
+    "windows_rolled": int,
     # why this tick record exists: "event" (a count above is nonzero) or
     # "sample" (the tick_record_every cadence)
     "emit": str,

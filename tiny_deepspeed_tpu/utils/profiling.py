@@ -61,6 +61,7 @@ TABLE = {
     "tds.tick.prefill.fetch": ("span", _TICK, "tick_host_ms"),
     "tds.tick.draft": ("span", _TICK, "tick_host_ms"),
     "tds.tick.decode.operands": ("span", _TICK, "tick_host_ms"),
+    "tds.tick.roll": ("span", _TICK, "cache_blocks_per_slot"),
     "tds.tick.decode.dispatch": ("span", _TICK, "tick_host_ms"),
     "tds.tick.decode.fetch": ("span", _TICK, "tick_host_ms"),
     "tds.tick.commit": ("span", _TICK, "tick_host_ms"),
@@ -75,6 +76,8 @@ TABLE = {
     "tds.ln": ("scope", "kernels (train)", "fwd_ms"),
     "tds.attn.qkv": ("scope", "kernels (train)", "fwd_ms"),
     "tds.attn.kernel": ("scope", "kernels (train)", "attn_fwd_ms"),
+    "tds.attn.summary": ("scope", "kernels (serve)", "eva_summary_ms"),
+    "tds.attn.window": ("scope", "kernels (serve)", "decode_ms"),
     "tds.attn.proj": ("scope", "kernels (train)", "fwd_ms"),
     "tds.mlp": ("scope", "kernels (train)", "fwd_ms"),
     "tds.head": ("scope", "kernels (train)", "head_ms"),
@@ -103,6 +106,7 @@ TABLE = {
     "tds_ln_dx": ("kernel", "kernels (train)", "bwd_ms"),
     "tds_ln_dwdb": ("kernel", "kernels (train)", "bwd_ms"),
     "tds_paged_attn": ("kernel", "kernels (serve)", "decode_ms"),
+    "tds_eva_paged_attn": ("kernel", "kernels (serve)", "eva_attn_ms"),
     "tds_quant": ("kernel", "collectives", None),
     "tds_xent_fwd": ("kernel", "kernels (train)", "head_ms"),
     "tds_xent_dx": ("kernel", "kernels (train)", "head_ms"),
