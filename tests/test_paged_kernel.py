@@ -179,6 +179,142 @@ class TestPagedKernelParity:
             PAP.set_paged_kernel("sometimes")
 
 
+def _slots_at(poss, w, bt, dead):
+    """A table row a slot: its live entries (the first ceil(pos / bt))
+    name blocks of its own, numbered from 1; every entry past them
+    names a block of `dead`."""
+    tables = np.empty((len(poss), w), np.int32)
+    fresh = iter(range(1, 1 + len(poss) * w))
+    for s, pos in enumerate(poss):
+        live = -(-pos // bt)
+        tables[s] = [next(fresh) if e < live else dead[(s + e) % len(dead)]
+                     for e in range(w)]
+    return tables
+
+
+class TestOnlyLiveSteps:
+    """The kernel copies and folds the blocks below a slot's length and
+    no other (ISSUE 31).  The file's pools are tiny, so a chunk is cut
+    to 16 tokens (two 8-token blocks) to give a table several."""
+
+    BT, STEP = 8, 16
+
+    @pytest.fixture(autouse=True)
+    def small_steps(self, monkeypatch):
+        monkeypatch.setattr(PAP, "_STEP_TOKENS", self.STEP)
+
+    # slots at pos 0, 1, one whole step, one step + 1, the full table
+    @staticmethod
+    def _poss(w):
+        return [0, 1, 16, 17, w * 8]
+
+    @pytest.mark.parametrize("k1,w,quant,max_rows", [
+        (1, 4, None, 256),      # the decode step
+        (16, 4, None, 16),      # a span cut into two row tiles
+        (1, 4, "int8", 256),    # scales go through the same rule
+        (1, 5, None, 256),      # a table that is no whole number of steps
+    ], ids=["decode", "span-row-tiles", "int8", "ragged-table"])
+    def test_poison_past_a_slots_length_is_never_read(
+            self, model, monkeypatch, k1, w, quant, max_rows):
+        """Every block but those below a slot's length holds NaN, the
+        ones its table names past ceil(pos / bt) and the scratch block
+        among them: the output is finite, bit-equal to the clean
+        pool's, and the XLA reference's to float tolerance.  (The
+        parent multiplied V's NaN by p = 0.)"""
+        monkeypatch.setattr(PAP, "_MAX_ROWS", max_rows)
+        poss = self._poss(w)
+        named = _slots_at(poss, w, self.BT, dead=[0, 30, 31])  # 0: scratch
+        live = {int(b) for row, p in zip(named, poss)
+                for b in row[:-(-p // self.BT)]}
+        dead = sorted(set(range(32)) - live)
+        assert {0, 30, 31} <= set(dead) and len(live) == (10, 11)[w - 4]
+        tables = jnp.asarray(named)
+        pos = jnp.asarray(poss, jnp.int32)
+        page = page_ref(tables, jnp.minimum(pos, w * self.BT - 1),
+                        self.BT)._replace(pos=pos)
+        clean = _pool_view(quant, blocks=31)
+        nan = jnp.asarray(dead)
+        if quant:
+            bad = clean._replace(
+                k_scale=clean.k_scale.at[nan].set(jnp.nan),
+                v_scale=clean.v_scale.at[nan].set(jnp.nan))
+        else:
+            bad = clean._replace(k=clean.k.at[nan].set(jnp.nan),
+                                 v=clean.v.at[nan].set(jnp.nan))
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(ks[0], (len(poss), 2, k1, 16), jnp.float32)
+        sk = jax.random.normal(ks[1], (len(poss), 2, k1, 16), jnp.float32)
+        sv = jax.random.normal(ks[2], (len(poss), 2, k1, 16), jnp.float32)
+        nt = k1 * 2 // min(k1 * 2, max_rows)
+        assert PAP.pool_steps(w, self.BT) == (2, -(-w // 2))
+        assert nt == (2 if k1 > 1 else 1)
+        for layer in range(2):
+            want = PAP.paged_attention(q, clean, page, layer, (sk, sv),
+                                       kv_heads=2)
+            got = PAP.paged_attention(q, bad, page, layer, (sk, sv),
+                                      kv_heads=2)
+            assert np.isfinite(np.asarray(got)).all()
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+            ck, cv = paged_panel(clean, layer, page, 2, 16, jnp.float32)
+            ref = model._span_attention(q, ck, cv, sk, sv, pos)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                       rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("k1,w,max_rows", [
+        (1, 4, 256), (16, 5, 16)], ids=["decode", "span-row-tiles-ragged"])
+    def test_the_copies_started_are_the_live_blocks(
+            self, monkeypatch, k1, w, max_rows):
+        """A dead block costs nothing because nothing is done for it:
+        the K and V copies the kernel starts, counted as it runs, are
+        one each for every block below a slot's length, a row tile, and
+        as many are waited for; an empty slot starts none."""
+        monkeypatch.setattr(PAP, "_MAX_ROWS", max_rows)
+        done = {"start": 0, "wait": 0}
+        make = PAP.pltpu.make_async_copy
+
+        class Counted:
+            def __init__(self, *a):
+                self.copy = make(*a)
+
+            def start(self):
+                jax.debug.callback(
+                    lambda: done.__setitem__("start", done["start"] + 1))
+                self.copy.start()
+
+            def wait(self):
+                jax.debug.callback(
+                    lambda: done.__setitem__("wait", done["wait"] + 1))
+                self.copy.wait()
+
+        monkeypatch.setattr(PAP.pltpu, "make_async_copy", Counted)
+        poss = self._poss(w)
+        tables = jnp.asarray(_slots_at(poss, w, self.BT, dead=[0]))
+        pos = jnp.asarray(poss, jnp.int32)
+        page = page_ref(tables, jnp.minimum(pos, w * self.BT - 1),
+                        self.BT)._replace(pos=pos)
+        ks = jax.random.split(jax.random.PRNGKey(6), 3)
+        q, sk, sv = (jax.random.normal(k, (len(poss), 2, k1, 16))
+                     for k in ks)
+        out = PAP.paged_attention(q, _pool_view(None, blocks=31), page, 1,
+                                  (sk, sv), kv_heads=2)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+        nt = 2 if k1 > 1 else 1
+        blocks = sum(-(-p // self.BT) for p in poss)
+        assert blocks == (10 if w == 4 else 11)
+        assert done == {"start": 2 * nt * blocks, "wait": 2 * nt * blocks}
+
+    def test_pool_steps_are_what_the_engine_counts(self, monkeypatch):
+        """`pool_steps`: the entries a chunk holds and the chunks a
+        table row can fill, at the tests' sizes and the serve cell's."""
+        assert PAP.pool_steps(5, self.BT) == (2, 3)
+        monkeypatch.setattr(PAP, "_STEP_TOKENS", 256)
+        assert PAP.pool_steps(64, 16) == (16, 4)
+        assert PAP.pool_steps(21, 16) == (16, 2)
+        assert PAP.pool_steps(4, 8) == (4, 1)
+
+
 def _staggered_trace(model, params, kmode, spec=None, quant=None):
     """Three requests through a real ServingEngine, the third admitted
     mid-flight; returns each request's committed tokens."""

@@ -287,6 +287,68 @@ def test_tick_jsonl_record_carries_the_segments_at_their_starts(
         assert split == pytest.approx(rec["wall_s"], abs=5e-6)
 
 
+def test_a_decode_tick_counts_the_kernels_live_steps(
+        model_params, monkeypatch, tmp_path):
+    """`kv_steps_live` of `kv_steps`: the paged kernel's pool steps a
+    layer that begin below their slot's length, counted from the slots'
+    `pos` into `tick_records`, the `tick` JSONL record and the ids of
+    `tds.tick.decode.operands`.  A step is cut to one 16-token block so
+    that three short requests reach several."""
+    import tiny_deepspeed_tpu.ops.paged_attn_pallas as PAP
+    from tiny_deepspeed_tpu.telemetry import schema
+
+    class Sink:
+        def __init__(self):
+            self.records = []
+
+        def log_meta(self, **rec):
+            self.records.append(rec)
+
+    monkeypatch.setattr(PAP, "_STEP_TOKENS", 16)
+    model, params = model_params
+    sink = Sink()
+    eng = ServingEngine(model, params, ServeConfig(
+        max_active=4, num_blocks=32, block_tokens=16, temperature=0.0,
+        tick_record_every=1), logger=sink)
+    eng.submit(list(range(1, 20)), 2)
+    eng.drain()                                 # compile outside the trace
+    first = len(eng.tick_records)
+    asked = [(14, 6), (30, 4), (47, 6)]         # prompt tokens, new tokens
+
+    def body():
+        for n, new in asked:
+            eng.submit(list(range(1, n + 1)), new)
+        eng.drain()
+
+    found = _traced(tmp_path, body)
+    # all three are admitted in one tick, which decodes too: at decode
+    # step t a request of n prompt tokens holds n + t, and it leaves
+    # after new - 1 steps (the prefill gave its first token)
+    by_hand = [sum(-(-(n + t) // 16) for n, new in asked if t < new - 1)
+               for t in range(5)]
+    assert by_hand == [6, 6, 7, 6, 6]
+    counters = {n for n in _names("counter")
+                if TABLE[n][1] == "kernels (serve)"}
+    assert counters == {"kv_steps_live", "kv_steps"}
+    kept = list(eng.tick_records)[first:]
+    assert [r["kv_steps_live"] for r in kept] == by_hand
+    assert {r["kv_steps"] for r in kept} == {4 * eng.max_blocks_per_req}
+    ticks = [r for r in sink.records if r["kind"] == "tick"][first:]
+    ids = [dict(e.stats) for e in found
+           if e.name == "tds.tick.decode.operands"]
+    for rec, written, span_ids in zip(kept, ticks, ids, strict=True):
+        assert {k: written[k] for k in counters} == {
+            k: rec[k] for k in counters} == {
+            k: span_ids[k] for k in counters}
+        assert span_ids["tick"] == rec["tick"]
+        assert schema.validate_record(dict(written, ts=0.0)) == []
+    # a tick that decodes nothing counts nothing
+    idle = ServingEngine(model, params, ServeConfig(max_active=2,
+                                                    num_blocks=8))
+    idle.tick()
+    assert not counters & set(idle.tick_records[-1])
+
+
 # -- a span costs nothing with no session ------------------------------------
 
 def test_a_dead_span_costs_under_two_microseconds_and_keeps_nothing():
@@ -323,7 +385,8 @@ def test_startup_marks_order_imports_before_the_backend():
     assert out.returncode == 0, out.stderr[-2000:]
     import json
     m = json.loads(out.stdout.strip().splitlines()[-1])
-    assert set(m) - {"t", "now"} == _names("counter")
+    assert set(m) - {"t", "now"} == {
+        n for n in _names("counter") if TABLE[n][1] == "entry / start-up"}
     assert (m["t"] <= m["import_begin"] <= m["import_done"]
             <= m["select_platform"] <= m["backend_up"] <= m["now"])
     # the package import is seconds of work: the mark is not a constant

@@ -9,10 +9,10 @@ block-table gather writes an (S, KVH, W*bt, Dh) pair to HBM, attention
 reads it back, and on a quantized pool a third dequantized copy joins
 them — PROFILE.md "Decode under load" measures exactly this gather as
 the decode step's dominant non-matmul cost.  This kernel reads the pool
-blocks DIRECTLY: the block table rides the grid's scalar prefetch, each
-grid step DMAs one physical (block, layer) into VMEM, dequantizes
+blocks DIRECTLY: the block table rides the scalar prefetch, the kernel
+copies each live (block, layer) from HBM into VMEM itself, dequantizes
 int8/fp8 resting blocks in-register against their per-vector scales,
-and folds the block into a flash-style online softmax — the panel never
+and folds the blocks into a flash-style online softmax — the panel never
 exists in HBM.
 
 The pool rests as (blocks, bt, L * KVH * Dh) (serving/pool.py) and the
@@ -32,18 +32,39 @@ One entry point, `paged_attention(q, view, page, l, (sk, sv))`: q holds
 a SPAN of K1 positions per slot — one on the plain decode step, k+1 on
 a speculative verify, a bucket on a suffix prefill — the pool
 contributes the COMMITTED prefix (positions < page.pos) and the span's
-own K/V, not yet in the pool, enter as one extra grid step under the
-windowed causal mask.  The caller commits the span afterwards
-(serving/pool.paged_append, paged_append_span).
+own K/V, not yet in the pool, are folded last under the windowed causal
+mask.  The caller commits the span afterwards (serving/pool.paged_append,
+paged_append_span).
 
-Grid: (S, row tiles, ceil(W / nb) + 1) — slots and row tiles parallel,
-table entries sequential, nb of them a step (a step costs about a
-third of a microsecond whatever it brings, so it brings 256 tokens),
-with VMEM softmax stats (m, l, acc) carried across the steps and reset
-at j=0
-(the bundled TPU flash kernels' accumulation discipline).  Unused table
-entries point at the scratch block; their positions fall outside the
-mask, so the extra DMAs are dead weight but never dead wrong.
+Grid: (S, row tiles), both parallel.  A grid step owns one slot: it
+walks the slot's table in chunks of nb entries (`pool_steps`), copies a
+chunk's blocks into one half of a two-deep VMEM buffer while it folds
+the other half, keeps the softmax stats (m, l, acc) in VMEM, and ends
+with the span's own K/V.
+
+Only what is live is worked on.  The pool arrays stay in HBM
+(`memory_space=HBM`: no BlockSpec, no pipeline of Pallas's), and the
+walk ends at the slot's length `page.pos`: a block whose first token
+lies at or past it is neither looked up in the table nor copied nor
+folded, and an empty slot (pos 0, a table of scratch) goes straight to
+its span.  (A quantized pool's scales, a sixteenth of its bytes or
+less, are the exception: their minor dimension L * KVH is no whole
+number of lane tiles, which a copy out of HBM must be, so the slots'
+scales of the layer are gathered before the kernel, `_scale_panel`.)
+A slot costs its grid step (0.6 us) and 1.4-1.5 us for each
+chunk it fills.  Measured on a v5e at gpt2-124m's serving sizes, 64
+slots x 64 table entries x 12 layers a decode tick (PERF.md section 6,
+PR 31): 0.45 ms with every slot empty, 0.65 with 5 slots live at 80-896
+tokens, 1.96 with 51 live, 4.74 with all 64 full; the kernel this one
+replaced gave every slot a grid step for each chunk of its table,
+live or not, with a BlockSpec for each of the chunk's K and V blocks,
+and took 7.15 ms whatever the slots held.  (The same grid with a
+dead step's arithmetic skipped and its BlockSpecs naming the blocks of
+the step before, so that nothing was fetched for it, took 11.9 ms, 8.8
+with the index maps' two integer divisions made shifts: what a grid
+step costs is its BlockSpecs, 36 here, each 50-80 ns a step whether or
+not it fetches.)  `ops/eva_attn_pallas.py` reads the same pool and
+still walks it by BlockSpecs.
 
 Numerics: scores, softmax stats and accumulation are float32 (like the
 XLA reference); the output casts back to the query's dtype.  The online
@@ -150,47 +171,48 @@ def effective_paged_kernel() -> str:
 def _paged_attn_kernel(
     # scalar prefetch
     tables_ref, pos_ref, l_ref,
-    # inputs (the scales and their selector on a quantized pool only)
+    # inputs (the scales and their selector on a quantized pool only),
+    # output, scratch
     *refs,
-    bt: int, nb: int, tk: int, quant: bool, scale: float,
+    bt: int, nb: int, w: int, tk: int, quant: bool, scale: float,
 ):
-    """One (slot, row tile, table entries) grid step: fold `nb` pool
-    blocks — or, on the final step, the span's own K/V — into the
-    online softmax of the tile's R query rows.  Everything is
+    """One (slot, row tile) grid step: fold the slot's live pool blocks,
+    `nb` of them a chunk, and then the span's own K/V, into the online
+    softmax of the tile's R query rows.  The pool arrays stay in HBM;
+    the kernel copies the blocks it folds into a two-deep VMEM buffer
+    itself, the next chunk's while it folds this one's.  Everything is
     two-dimensional with the pool's merged minor dimension C = KVH * Dh
-    in the lanes: the queries come block-diagonal (row (h, g, t) holds its Dh numbers
-    in head h's columns, zeros elsewhere), so ONE q @ k^T over C gives
-    every head's scores and no head is ever sliced out of a block; the
-    accumulator keeps all C columns per row and the caller reads the
-    row's own head back out.  Scratch (acc, m, ll) persists across the
-    sequential j dimension and resets at j == 0."""
-    q_ref = refs[0]
-    k_refs, v_refs = refs[1:1 + nb], refs[1 + nb:1 + 2 * nb]
-    i = 1 + 2 * nb
+    in the lanes: the queries come block-diagonal (row (h, g, t) holds
+    its Dh numbers in head h's columns, zeros elsewhere), so ONE
+    q @ k^T over C gives every head's scores and no head is ever sliced
+    out of a block; the accumulator keeps all C columns per row and the
+    caller reads the row's own head back out."""
+    q_ref, pools = refs[0], refs[1:3]
+    i = 3
     if quant:
-        ks_refs, vs_refs = refs[i:i + nb], refs[i + nb:i + 2 * nb]
-        sel_ref = refs[i + 2 * nb]
-        i += 2 * nb + 1
-    sk_ref, sv_ref, o_ref, acc, m, ll = refs[i:i + 6]
+        ks_ref, vs_ref, sel_ref = refs[3:6]
+        i = 6
+    sk_ref, sv_ref, o_ref = refs[i:i + 3]
+    bufs = refs[i + 3:i + 5]
+    sem, acc, m, ll = refs[i + 5:]
 
     s = pl.program_id(0)
     t = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc[...] = jnp.zeros(acc.shape, jnp.float32)
-        m[...] = jnp.full(m.shape, _MASKED, jnp.float32)
-        ll[...] = jnp.zeros(ll.shape, jnp.float32)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # (R, C)
+    c_lanes = bufs[0].shape[-1]
+    col = pl.ds(pl.multiple_of(l_ref[0] * c_lanes, c_lanes), c_lanes)
+    step = nb * bt
     limit = pos_ref[s]
+    held = jnp.minimum(limit, w * bt)  # what the table's w entries hold
 
-    def dot_nt(a, b, **kw):  # a @ b^T over the lanes of both
+    acc[...] = jnp.zeros(acc.shape, jnp.float32)
+    m[...] = jnp.full(m.shape, _MASKED, jnp.float32)
+    ll[...] = jnp.zeros(ll.shape, jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32) * scale  # (R, C)
+
+    def dot_nt(a, b):  # a @ b^T over the lanes of both
         return jax.lax.dot_general(
             a, b, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, **kw)
+            preferred_element_type=jnp.float32)
 
     def fold(scores, vblk, vscale=None):
         """Online-softmax update: scores (R, T'), vblk (T', C), both
@@ -206,46 +228,101 @@ def _paged_attn_kernel(
             p, vblk, preferred_element_type=jnp.float32)
         m[...] = m_new
 
-    def rows(block_refs):  # the step's nb blocks, one under the other
-        return jnp.concatenate([r[0] for r in block_refs], axis=0)
+    def copies(c, half, act):
+        """Start, or wait for, the copies of chunk c's live blocks into
+        buffer `half`: table entry c * nb + i lands in plane i.  An
+        entry whose first token the slot has not reached is not read
+        from the table and not copied: its plane keeps what it held."""
+        for i in range(nb):
+            entry = c * nb + i
 
-    @pl.when(j < nj - 1)
-    def _pool_blocks():
-        scores = dot_nt(q, rows(k_refs).astype(jnp.float32))  # (R, nb * bt)
+            @pl.when(entry * bt < held)
+            def _(i=i, entry=entry):
+                blk = tables_ref[s, entry]
+                for n, (pool, buf) in enumerate(zip(pools, bufs)):
+                    act(pltpu.make_async_copy(
+                        pool.at[blk, :, col], buf.at[half, i],
+                        sem.at[half, n]))
+
+    def rows(buf, half):  # the chunk's nb planes, one under the other
+        return jnp.concatenate(
+            [buf[half, i].astype(jnp.float32) for i in range(nb)], axis=0)
+
+    @pl.when(held > 0)
+    def _first():
+        copies(0, 0, lambda cp: cp.start())
+
+    def chunk(c):
+        half = c % 2
+
+        @pl.when((c + 1) * step < held)
+        def _next():
+            copies(c + 1, 1 - half, lambda cp: cp.start())
+
+        copies(c, half, lambda cp: cp.wait())
+        scores = dot_nt(q, rows(bufs[0], half))  # (R, nb * bt)
+        tpos = c * step + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 1)
+        live = tpos < limit
         vscale = None
         if quant:
-            # a row's head picks its scale column: the one-hot `sel`
-            # (R, L * KVH) is made by the caller for this layer, and
-            # the exact (fp32) product with it is a gather
-            hi = dict(precision=jax.lax.Precision.HIGHEST)
-            scores = scores * dot_nt(sel_ref[...], rows(ks_refs), **hi)
-            vscale = dot_nt(sel_ref[...], rows(vs_refs), **hi)
-        # a table entry past the last (w no multiple of nb) repeats the
-        # last block at positions no slot reaches: masked like the rest
-        tpos = j * (nb * bt) + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        fold(jnp.where(tpos < limit, scores, _MASKED),
-             rows(v_refs).astype(jnp.float32), vscale)
+            # a row's head picks its scale row: the one-hot `sel`
+            # (R, KVH), and the exact (fp32) product with it is a gather
+            at = pl.ds(pl.multiple_of(c * step, step), step)
+            hi = dict(precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+            scores = scores * jnp.dot(sel_ref[...], ks_ref[0, :, at], **hi)
+            vscale = jnp.where(
+                live, jnp.dot(sel_ref[...], vs_ref[0, :, at], **hi), 0.0)
+        # a plane that was not copied, and the last block's rows past
+        # the slot's length, hold whatever they held: K's scores are
+        # masked, and V's rows zeroed, since p = 0 times a stray NaN is
+        # no 0
+        vblk = rows(bufs[1], half)
+        vrow = c * step + jax.lax.broadcasted_iota(jnp.int32, vblk.shape, 0)
+        fold(jnp.where(live, scores, _MASKED),
+             jnp.where(vrow < limit, vblk, 0.0), vscale)
+        return c + 1
 
-    @pl.when(j == nj - 1)
-    def _span_and_emit():
-        scores = dot_nt(q, sk_ref[0].astype(jnp.float32))  # (R, K1)
-        qoff = t * tk + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 0) % tk
-        koff = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        fold(jnp.where(koff <= qoff, scores, _MASKED),
-             sv_ref[0].astype(jnp.float32))
-        o_ref[0, 0] = (acc[...] / ll[...]).astype(o_ref.dtype)
+    jax.lax.while_loop(lambda c: c * step < held, chunk, jnp.int32(0))
+
+    scores = dot_nt(q, sk_ref[0].astype(jnp.float32))  # (R, K1)
+    qoff = t * tk + jax.lax.broadcasted_iota(
+        jnp.int32, scores.shape, 0) % tk
+    koff = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    fold(jnp.where(koff <= qoff, scores, _MASKED),
+         sv_ref[0].astype(jnp.float32))
+    o_ref[0, 0] = (acc[...] / ll[...]).astype(o_ref.dtype)
 
 
 # query rows a grid step folds at most: the accumulator is (rows, C) f32
 # in VMEM, and every row pays for all C columns on the MXU
 _MAX_ROWS = 256
-# pool tokens a grid step folds.  A step costs 0.36 us whatever it brings
-# and the arithmetic of a decode tick 4.9 ms (v5e, gpt2-124m, 64 slots x
-# 64 table entries x 12 layers, PERF.md section 6, PR 28): one 16-token
-# block a step is 22.4 ms of kernel a tick, 256 tokens a step 6.0
+# pool tokens a chunk holds: the VMEM buffer is two of them for K and two
+# for V, and a chunk is what one pass of the loop copies and folds.  On a
+# v5e at the serve cell's sizes, ms a decode tick of kernel at 128 / 256 /
+# 512: 0.70 / 0.65 / 0.66 with 5 of 64 slots live, 2.17 / 1.96 / 2.05
+# with 51 live, 5.98 / 4.74 / 4.68 with all 64 full (PERF.md section 6,
+# PR 31)
 _STEP_TOKENS = 256
+
+
+def pool_steps(w: int, bt: int) -> tuple[int, int]:
+    """(nb, npool): the table entries a chunk holds, and the chunks
+    that cover a table row of `w` entries of `bt` tokens each."""
+    nb = max(1, min(w, _STEP_TOKENS // bt))
+    return nb, -(-w // nb)
+
+
+def _scale_panel(scales, tables, l, kvh: int, entries: int):
+    """(S, KVH, entries * bt): layer l's scales of the blocks each
+    slot's table names (the last entry again up to `entries`), a head a
+    row and the tokens in the lanes."""
+    layer = jax.lax.dynamic_slice_in_dim(scales, l * kvh, kvh, axis=2)
+    tables = jnp.pad(tables, ((0, 0), (0, entries - tables.shape[1])),
+                     mode="edge")
+    panel = layer[tables]  # (S, entries, bt, KVH)
+    return panel.reshape(tables.shape[0], -1, kvh).swapaxes(1, 2)
 
 
 def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
@@ -272,8 +349,7 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
     bt = view.k.shape[1]
     w = page.tables.shape[1]
     quant = view.k_scale is not None
-    nb = max(1, min(w, _STEP_TOKENS // bt))  # table entries a step
-    npool = -(-w // nb)  # steps over the table; the span's is one more
+    nb, npool = pool_steps(w, bt)  # table entries a chunk, chunks a row
     # span offsets per row tile: all of them while the rows fit, else
     # halved (suffix-prefill buckets are powers of two)
     tk = k1
@@ -292,50 +368,42 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
     pos = page.pos.astype(jnp.int32)
     larr = jnp.reshape(jnp.asarray(l, jnp.int32), (1,))
 
-    def pool_specs(width, layer):
-        """The step's nb table entries of one pool array, each a
-        (block, layer): bt rows of the layer's `width` columns (all
-        columns where `layer` is False).  An entry past the table (w
-        no multiple of nb) is clamped to the last: its block is fetched
-        and masked.  The span step names the blocks of the step before
-        it, so nothing is fetched for it."""
-        def spec(i):
-            def index(si, ti, j, tr, pr, lr):
-                entry = jnp.minimum(
-                    jnp.minimum(j, npool - 1) * nb + i, w - 1)
-                return (tr[si, entry], 0, lr[0] if layer else 0)
-            return pl.BlockSpec((1, bt, width), index)
-        return [spec(i) for i in range(nb)]
-
+    # (slot, row tile) -> that tile's rows; the span's K/V a slot
     row_spec = pl.BlockSpec((1, 1, rows + rpad, c),
-                            lambda si, ti, j, tr, pr, lr: (si, ti, 0, 0))
-    in_specs = [row_spec] + 2 * pool_specs(c, True)
-    args = [qbd] + nb * [view.k] + nb * [view.v]
-    if quant:
-        # the scales' minor dimension L * KVH is no whole number of
-        # lane tiles a layer: a step takes its blocks' scales of every
-        # layer, and `sel` picks this layer's head for each row
-        nlk = view.k_scale.shape[2]
-        head = jnp.arange(rows + rpad) // (g * tk)
-        sel = jax.nn.one_hot(l * kvh + head, nlk, dtype=jnp.float32)
-        in_specs += 2 * pool_specs(nlk, False) + [
-            pl.BlockSpec(sel.shape, lambda si, ti, j, tr, pr, lr: (0, 0))]
-        args += nb * [view.k_scale] + nb * [view.v_scale] + [sel]
-    span_spec = pl.BlockSpec((1, k1, c), lambda si, ti, j, tr, pr, lr:
+                            lambda si, ti, tr, pr, lr: (si, ti, 0, 0))
+    span_spec = pl.BlockSpec((1, k1, c), lambda si, ti, tr, pr, lr:
                              (si, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [row_spec, in_hbm, in_hbm]
+    args = [qbd, view.k, view.v]
+    # the two-deep buffer: nb planes of one block's (bt, C) a half
+    scratch = 2 * [pltpu.VMEM((2, nb, bt, c), view.k.dtype)]
+    if quant:
+        # a scale a head vector is a sixteenth or less of the pool: the
+        # slots' scales of this layer are gathered whole, tokens in the
+        # lanes, and `sel` picks its head's row for each query row
+        sel = jax.nn.one_hot(jnp.arange(rows + rpad) // (g * tk), kvh,
+                             dtype=jnp.float32)
+        scale_spec = pl.BlockSpec((1, kvh, npool * nb * bt),
+                                  lambda si, ti, tr, pr, lr: (si, 0, 0))
+        in_specs += [scale_spec, scale_spec, pl.BlockSpec(
+            sel.shape, lambda si, ti, tr, pr, lr: (0, 0))]
+        args += [_scale_panel(a, tables, l, kvh, npool * nb)
+                 for a in (view.k_scale, view.v_scale)] + [sel]
     in_specs += [span_spec, span_spec]
     args += [a.swapaxes(1, 2).reshape(s, k1, c) for a in span_kv]
 
     kernel = functools.partial(
         _paged_attn_kernel,
-        bt=bt, nb=nb, tk=tk, quant=quant, scale=1.0 / math.sqrt(dh),
+        bt=bt, nb=nb, w=w, tk=tk, quant=quant, scale=1.0 / math.sqrt(dh),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s, nt, npool + 1),
+        grid=(s, nt),
         in_specs=in_specs,
         out_specs=row_spec,
-        scratch_shapes=[
+        scratch_shapes=scratch + [
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((rows + rpad, c), jnp.float32),
             pltpu.VMEM((rows + rpad, 1), jnp.float32),
             pltpu.VMEM((rows + rpad, 1), jnp.float32),
@@ -346,11 +414,11 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, nt, rows + rpad, c), q.dtype),
         interpret=INTERPRET,
-        # slots and row tiles are independent (scratch resets at
-        # j == 0), so they may split across Mosaic cores; j must stay
-        # ordered
+        # a grid step starts and waits for its own copies and resets
+        # its own softmax stats, so slots and row tiles may split
+        # across Mosaic cores
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
         ),
         name="tds_paged_attn",
     )(tables, pos, larr, *args)

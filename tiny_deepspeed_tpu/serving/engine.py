@@ -1395,7 +1395,7 @@ class ServingEngine:
         """One token for every active slot — the exact pre-speculation
         decode tick (spec off compiles and runs only this path)."""
         produced = 0
-        with self._span("decode.operands"):
+        with self._operands_span(active):
             tokens, pos, seeds, nprod, poison, tables = \
                 self._slot_arrays(active)
         if self._layout is not None:
@@ -1471,7 +1471,7 @@ class ServingEngine:
         produced = 0
         with self._span("draft") as draft:
             drafts = self._spec.propose(self._slots)  # (S, K+1) int32
-        with self._span("decode.operands"):
+        with self._operands_span(active):
             tokens, pos, seeds, nprod, poison, tables = \
                 self._slot_arrays(active)
             S = self.config.max_active
@@ -2222,6 +2222,29 @@ class ServingEngine:
         if self._FLIGHT_PRIORITY[reason] > cur:
             self._flight_reason = reason
 
+    def _operands_span(self, active) -> _TickSpan:
+        """`tds.tick.decode.operands`, carrying how much of their
+        tables the slots' lengths (`_slot_arrays`' `pos`) fill, in the
+        paged kernel's unit, a chunk of a table row
+        (ops/paged_attn_pallas.pool_steps): `kv_steps` the chunks the
+        slots' rows hold, `kv_steps_live` those that begin below their
+        slot's length, which are all the kernel copies and folds, a
+        layer.  Both go into the tick's record too.  A model with a
+        cache layout of its own reads the pool through its own kernel
+        and counts in `_note_cache`."""
+        ids = {"tick": self._tick["tick"]}
+        if self._layout is None:
+            from ..ops.paged_attn_pallas import pool_steps
+            bt = self.config.block_tokens
+            nb, npool = pool_steps(self.max_blocks_per_req, bt)
+            counts = dict(
+                kv_steps_live=sum(min(-(-s.pos // (nb * bt)), npool)
+                                  for _, s in active),
+                kv_steps=self.config.max_active * npool)
+            self._tick.update(counts)
+            ids.update(counts)
+        return _TickSpan(self._tick["segments"], "decode.operands", ids)
+
     def _record_tick(self, rec: dict) -> None:
         """End-of-tick bookkeeping, from the tick's own record (`rec`,
         the entry `tick_records` is about to take): append the tick
@@ -2277,10 +2300,11 @@ class ServingEngine:
             decode_s=round(seg["decode_s"], 6),
             fetch_s=round(seg["fetch_s"], 6),
         )
-        if "window_blocks" in rec:
-            # a model with two kinds of cache: what its slots hold
-            counts.update((k, rec[k]) for k in (
-                "window_blocks", "summary_blocks", "windows_rolled"))
+        # what the decode step's slots hold, by the kind of cache: the
+        # paged kernel's grid, or a two-cache model's blocks
+        counts.update((k, rec[k]) for k in (
+            "kv_steps_live", "kv_steps", "window_blocks",
+            "summary_blocks", "windows_rolled") if k in rec)
         if self._spec is not None:
             # the draft-vs-verify wall split: draft_s is the drafter's
             # proposal wall, decode_s+fetch_s the verify program's —

@@ -157,7 +157,13 @@ _NUM = (int, float)
 #      window ring and chunk summaries, models/evabyte.py): what its
 #      slots hold that tick and how many started a new window; other
 #      engines' tick records are byte-compatible with v16 readers
-SCHEMA_VERSION = 17
+#  18: + kv_steps_live / kv_steps on the `tick` records of every other
+#      engine, for a tick that ran a decode step: the chunks of table
+#      row the slots hold (slots x chunks a row, the paged kernel's
+#      unit, ops/paged_attn_pallas.pool_steps) and how many of them
+#      begin below their slot's length, which are all the kernel
+#      copies and folds, a layer
+SCHEMA_VERSION = 18
 
 # step-record fields beyond the required step/ts; values are allowed types
 STEP_FIELDS: Dict[str, tuple] = {
@@ -406,6 +412,10 @@ META_FIELDS: Dict[str, tuple] = {
     "window_blocks": int,
     "summary_blocks": int,
     "windows_rolled": int,
+    # the chunks of table row the slots hold, and those of them a slot's
+    # length reaches: what the paged kernel folds a layer (schema v18)
+    "kv_steps_live": int,
+    "kv_steps": int,
     # why this tick record exists: "event" (a count above is nonzero) or
     # "sample" (the tick_record_every cadence)
     "emit": str,

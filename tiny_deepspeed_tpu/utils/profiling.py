@@ -49,7 +49,10 @@ def trace(logdir: str):
 # it).  A `span` is a host TraceAnnotation (`span()` below), a `scope` a
 # jax.named_scope inside a compiled program, a `program` the name of a
 # jitted function (the trace's `XLA Modules` line reads `jit_<name>`), a
-# `kernel` the name= of a pallas_call, a `counter` a host clock reading.
+# `kernel` the name= of a pallas_call, a `counter` a number the host
+# takes: a clock reading at start-up (`utils/startup.marks`), or a count a
+# tick makes of its slots, kept in the tick's record and written as ids of
+# the span that covers the counting (`tds.tick.decode.operands`).
 # tests/test_spans.py holds the code to this table in both directions.
 _TICK, _STEP = "serving scheduler", "engine step"
 TABLE = {
@@ -115,6 +118,8 @@ TABLE = {
     "import_done": ("counter", "entry / start-up", "import_s"),
     "select_platform": ("counter", "entry / start-up", "backend_init_s"),
     "backend_up": ("counter", "entry / start-up", "backend_init_s"),
+    "kv_steps_live": ("counter", "kernels (serve)", None),
+    "kv_steps": ("counter", "kernels (serve)", None),
 }
 
 
