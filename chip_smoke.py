@@ -9,8 +9,8 @@ their entry points call (examples/common.run, scripts/serve_bench.serve), at
 the full width of gpt2-124m (random weights from a seed), and checks what
 comes out by the repo's own means:
 
-  train    SingleDevice, B=12 T=1024 bf16 params+moments, unrolled (bench.py
-           width), 6 steps at lr 1e-3: first loss near ln(vocab), finite,
+  train    SingleDevice, B=12 T=1024 bf16 params+moments, unrolled (the
+           benchmark's gpt2-124m.train-1chip), 6 steps at lr 1e-3: first loss near ln(vocab), finite,
            falling; the trainer's own --profile yields an .xplane.pb with a
            TPU plane that has events
   parity   the same first two steps with every kernel gate forced to XLA
@@ -204,8 +204,8 @@ class Smoke:
         return [r["loss"] for r in recs if "loss" in r and "step" in r]
 
     def bench_width(self):
-        """gpt2-124m as bench.py measures it (bench._bench_config):
-        B=12, bf16 params and moments, no remat, unrolled."""
+        """gpt2-124m as the benchmark's gpt2-124m.train-1chip measures
+        it: B=12, bf16 params and moments, no remat, unrolled."""
         import jax.numpy as jnp
         if self.rehearsal:
             return 2, dict(remat=False, scan_unroll=True), None

@@ -384,7 +384,7 @@ def run(engine_cls, args, single_device=False, cfg_overrides=None,
     non-zero before anything compiles).  `cfg_overrides` (model config
     fields) and `state_dtype` (AdamW moment dtype) are for programmatic
     callers that need a preset at another precision or attention path —
-    chip_smoke.py runs gpt2-124m at its bench.py width through them."""
+    chip_smoke.py runs gpt2-124m at the benchmark's width through them."""
     select_platform(getattr(args, "cpu_devices", 0),
                     cpu_flag="--cpu-devices N")
     init_distributed()

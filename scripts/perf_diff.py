@@ -30,8 +30,8 @@ nonzero for CI when something regressed:
     tokens/s can mask (tail latency traded for batch occupancy).
 
 Records are usable only when fresh: value > 0 and not marked as a
-replay (`extra.cached_result` / top-level `stale`; bench.py no longer
-writes either, older round files did).  With zero usable fingerprints
+replay (`extra.cached_result` / top-level `stale`: older round files
+carry them).  With zero usable fingerprints
 the verdict is OK (nothing to compare), exit 0.
 
 Pure python (no jax): runs anywhere, including tier-1 CI.

@@ -8,7 +8,7 @@ validate it against the telemetry schema.
     python scripts/report_run.py --check RUN.jsonl
 
 The JSONL comes from `utils.profiling.MetricsLogger` (examples/common.py
-`--telemetry --metrics RUN.jsonl`, or bench.py's telemetry sidecar); the
+`--telemetry --metrics RUN.jsonl`); the
 schema is `tiny_deepspeed_tpu/telemetry/schema.py`.  `--check` exits
 non-zero on any drift (unknown fields, wrong types, malformed lines) so CI
 catches schema breakage (tests/test_telemetry.py smoke-runs it in tier-1).

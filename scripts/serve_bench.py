@@ -21,12 +21,11 @@ per-token latency — the serving headline the ROADMAP asks for.
     python scripts/serve_bench.py --model tiny --cpu --requests 12 \
         --closed-loop --chaos "nan@6,nan@7,delay@10" --deadline 30
 
-Prints a human summary plus ONE machine-readable JSON line (the same
-shape bench.py's BENCH_SERVE record embeds in `extra`).
+Prints a human summary plus ONE machine-readable JSON line.
 
 Every run writes a telemetry JSONL SIDECAR (default
-artifacts/serve_run.jsonl; --jsonl PATH moves it, --jsonl none disables)
-the same way bench.py does: a run_meta record carrying the serve config,
+artifacts/serve_run.jsonl; --jsonl PATH moves it, --jsonl none disables):
+a run_meta record carrying the serve config,
 per-tick `tick` records, per-request `request` records with lifecycle
 events + latency components, flight records on faults, and the
 telemetry summary — so every bench run replays in the dashboard
@@ -488,9 +487,9 @@ def serve(argv=None, cfg_overrides=None) -> dict:
     if "tenants" in res:
         summary["tenants"] = res["tenants"]
     if "slo" in res:
-        # the budget snapshot rides the machine-readable line, so
-        # bench.py's BENCH_SERVE extra carries slo.attainment — the
-        # higher-is-better key perf_diff.py's sentinel watches
+        # the budget snapshot rides the machine-readable line:
+        # slo.attainment is the higher-is-better key perf_diff.py's
+        # sentinel watches
         summary["slo"] = res["slo"]
 
     if args.chaos:

@@ -7,7 +7,7 @@ Three layers of pins:
   * exact dot/fusion FLOP arithmetic and while-trip multiplication on
     tiny SYNTHETIC HLO text (no compile, no jax numerics);
   * the 124M GPT-2 train step's HLO-counted matmul FLOPs within 2% of
-    bench's analytic `flops_tok_matmul` — the "measured ground truth
+    the analytic matmul formula — the "measured ground truth
     agrees with the honest hand formula" acceptance — and the MoE
     dispatch/combine undercount first DEMONSTRATED (counted >> the old
     formula) then CORRECTED (counted ~= formula + the new
@@ -274,7 +274,7 @@ def _compiled_text(model_name: str, b=1, t=1024):
 
 class TestPinned124M:
     def test_hlo_counted_within_2pct_of_bench_formula(self):
-        """The acceptance pin: bench's analytic `flops_tok_matmul` for
+        """The acceptance pin: the analytic matmul formula for
         the 124M GPT-2 train step (b=1, t=1024, remat off) agrees with
         the FLOPs counted from the compiled program within 2%."""
         b, t = 1, 1024
@@ -316,13 +316,13 @@ class TestPinnedMoE:
             for n, s in model.param_shapes().items()
             if ".moe." in n and "router" not in n
         )
-        # the OLD bench accounting: expert params scaled k/E, einsum
+        # the OLD accounting: expert params scaled k/E, einsum
         # pair ignored entirely
         old_active = (n_params - expert
                       + expert * cfg.expert_top_k // cfg.n_expert)
         old_tok = (6 * (old_active - embed)
                    + 12 * cfg.n_layer * t * cfg.n_embd)
-        # the CORRECTED accounting (bench run_one, in lock-step):
+        # the CORRECTED accounting:
         # capacity-padded expert compute (E*C slot-rows, not k/E) + the
         # dispatch/combine einsum matmuls
         cap = max(1, int(cfg.capacity_factor * cfg.expert_top_k * b * t

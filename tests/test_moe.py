@@ -184,7 +184,7 @@ class TestSortDispatch:
         assert abs(lq - lp) < 0.05 * max(1.0, abs(lp)), (lq, lp)
 
     def test_effective_dispatch_predicate(self):
-        """The single fallback predicate bench.py records: sort survives
+        """The single fallback predicate: sort survives
         single-device and pure DP, falls back under ep/tp/sp/pipe."""
         import dataclasses
         from tiny_deepspeed_tpu import Zero1

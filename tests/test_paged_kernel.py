@@ -19,10 +19,8 @@ Acceptance pins:
     gating, and the 20-step training loss parity (<5%) the gather_quant
     precedent set (slow tier);
   * tune_e2e: coordinate-descent mechanics (bool-vs-int knob identity,
-    failure tolerance, objective direction), plan persistence through
-    the AOT cache's v2 envelope (legacy flat files still load), and
-    the spec_k round-trip — a tuned plan's spec_k reaches ServeConfig
-    through bench.resolve_spec_k and flips `_config_fingerprint`;
+    failure tolerance, objective direction) and plan persistence
+    through the AOT cache's v2 envelope (legacy flat files still load);
   * autotuner diagnostics land in the Telemetry registry / MetricsLogger
     (run_meta records, candidate-failure counter+gauge) instead of
     bare prints;
@@ -556,47 +554,6 @@ class TestTuneE2E:
         t2.save(p)
         t3 = RuntimeAutoTuner()
         assert t3.load(p) == 1
-
-    def test_spec_k_roundtrip_plan_to_serveconfig_to_kernel_stamp(
-            self, tmp_path, monkeypatch):
-        """The satellite fix: a tuned spec_k round-trips plan ->
-        resolve_spec_k -> ServeConfig, and the consumed plan's hash
-        lands in BENCH_TUNE_PLAN so the record's kernel stamp separates
-        runs under different plans.  The plan is read only from a cache
-        passed explicitly (BENCH_TUNE_CACHE)."""
-        import bench
-        from tiny_deepspeed_tpu.autotuner import (
-            RuntimeAutoTuner, plan_key,
-        )
-        from tiny_deepspeed_tpu.serving import ServeConfig
-
-        cache = str(tmp_path / "cache.json")
-        monkeypatch.setenv("BENCH_TUNE_CACHE", cache)
-        monkeypatch.delenv("BENCH_SPEC_K", raising=False)
-        monkeypatch.delenv("BENCH_TUNE_PLAN", raising=False)
-        mesh, backend = bench._mesh_desc()
-        t = RuntimeAutoTuner()
-        t.store_plan(plan_key("tiny", mesh, backend), {"spec_k": 6}, {})
-        t.save(cache)
-
-        assert bench._kernel_stamp()["tune_plan"] == ""
-        k, source = bench.resolve_spec_k("tiny")
-        assert (k, source) == (6, "plan")
-        assert os.environ["BENCH_TUNE_PLAN"]  # hash exported
-        assert (bench._kernel_stamp()["tune_plan"]
-                == os.environ["BENCH_TUNE_PLAN"])
-        cfg = ServeConfig(spec_draft="ngram", spec_k=k)
-        assert cfg.spec_k == 6
-        # explicit env outranks the plan
-        monkeypatch.setenv("BENCH_SPEC_K", "3")
-        assert bench.resolve_spec_k("tiny") == (3, "env")
-        # no cache passed explicitly, no env -> the hand-set default
-        # (a generated artifacts/autotune_cache.json is never consulted)
-        monkeypatch.delenv("BENCH_SPEC_K")
-        monkeypatch.delenv("BENCH_TUNE_CACHE")
-        monkeypatch.setattr(
-            bench, "_tune_cache_path", lambda: cache)
-        assert bench.resolve_spec_k("tiny") == (4, "default")
 
 
 class TestAutotunerDiagnostics:

@@ -3,8 +3,8 @@
 
 """Pallas kernel numerics, run in interpret mode on the CPU CI mesh.
 
-On real TPU the same kernels are exercised by bench.py and the examples; this
-guards the kernel *logic* (blocking, grid accumulation, stats layout) in CI.
+On real TPU the same kernels are exercised by the benchmark's cells and the
+examples; this guards the kernel *logic* (blocking, grid accumulation, stats layout) in CI.
 """
 
 import jax

@@ -140,7 +140,6 @@ def test_gauge_names_documented_in_schema():
         os.path.join(REPO, "tiny_deepspeed_tpu"),
         os.path.join(REPO, "scripts"),
         os.path.join(REPO, "examples"),
-        os.path.join(REPO, "bench.py"),
     ]
     for root in roots:
         files = [root] if root.endswith(".py") else [
@@ -249,8 +248,7 @@ def test_serving_spec_schema_v7_names():
     tick field and the per-request spec_proposed/spec_accepted fields
     must stay validatable, and the ServeConfig knobs the docs/bench
     name must still exist — `report_run.py --check` hard-fails any
-    spec sidecar otherwise, and BENCH_SPEC keys its fingerprint on the
-    knob names."""
+    spec sidecar otherwise."""
     from tiny_deepspeed_tpu.telemetry import schema
 
     assert schema.SCHEMA_VERSION >= 7
@@ -379,7 +377,7 @@ def test_prefix_tenancy_schema_v9_names():
             f"gauge {g} documented in schema but no longer registered "
             "by serving/engine.py"
         )
-    # the knobs serve_bench/BENCH_PREFIX and the docs name
+    # the knobs serve_bench and the docs name
     for knob in ("prefix_cache", "tenants"):
         assert knob in engine_src, f"ServeConfig.{knob} gone"
     for field in ("tenant", "prefix_blocks", "prefix_tokens"):
@@ -640,3 +638,59 @@ def test_live_slo_schema_v15_names():
         "counters": {}, "histograms": {},
     })
     assert not errs, errs
+
+
+def test_serving_engine_asks_the_layout_not_whether_there_is_one():
+    """One cache seam (PR 32): every servable model states a slot layout
+    (serving/pool.DenseLayout), so `serving/engine.py` holds no test of
+    whether it has one, and the paged kernel's arithmetic is the layout's:
+    of `ops/paged_attn_pallas` the engine imports the kernel mode only."""
+    import ast
+
+    with open(os.path.join(
+            REPO, "tiny_deepspeed_tpu", "serving", "engine.py")) as f:
+        src = f.read()
+    assert "_layout is" not in src
+    assert "self._layout." in src  # the seam is there, under this name
+    imported = set()
+    for node in ast.walk(ast.parse(src)):
+        if isinstance(node, ast.ImportFrom) and (
+                node.module or "").endswith("paged_attn_pallas"):
+            imported |= {a.name for a in node.names}
+        elif isinstance(node, ast.Import):
+            assert not any("paged_attn_pallas" in a.name
+                           for a in node.names)
+    assert imported == {"PAGED_KERNEL_MODES", "paged_kernel_forced"}
+
+
+def test_the_old_benchmark_is_named_by_no_code():
+    """One benchmark (PR 32): `BENCHMARK.json` + `benchmarks/`.  The
+    script it replaced, that script's configuration table and its
+    environment arms are named by no Python or shell file outside
+    `benchmarks/` (whose prose is the benchmark's own to mend), nor by
+    README.md.  The records (PERF.md, CHANGES.md, ROADMAP.md, PROFILE.md,
+    BASELINE.md) may name them: they say what was measured by what."""
+    import re
+
+    gone = re.compile("|".join((
+        r"\bben" r"ch\.py\b", r"\b(?:import|from) ben" r"ch\b",
+        r"_ben" r"ch_config", r"\bBEN" r"CH_[A-Z]")))
+    paths = [os.path.join(REPO, "README.md")]
+    for dp, dns, fs in os.walk(REPO):
+        # a checkout's own leavings and the chip tool's copies aside
+        dns[:] = [d for d in dns if not d.startswith((".", "chiprun_"))
+                  and d != "__pycache__"
+                  and os.path.join(dp, d) != os.path.join(REPO,
+                                                          "benchmarks")]
+        paths += [os.path.join(dp, f) for f in fs
+                  if f.endswith((".py", ".sh"))]
+    assert len(paths) > 100, "the walk found no tree to read"
+    hits = []
+    for path in paths:
+        with open(path) as f:
+            for n, line in enumerate(f, 1):
+                if gone.search(line):
+                    hits.append(f"{os.path.relpath(path, REPO)}:{n}: "
+                                f"{line.strip()[:80]}")
+    assert not hits, hits
+    assert not os.path.exists(os.path.join(REPO, "ben" "ch.py"))

@@ -396,7 +396,7 @@ class TestServingTelemetry:
     # plain-engine scheduling quick; this smoke keeps the poisson_trace
     # shape assertions in the slow tier
     def test_driver_closed_loop_smoke(self, model, params):
-        """poisson_trace + run_trace (the serve_bench/BENCH_SERVE code
+        """poisson_trace + run_trace (the serve_bench code
         path), closed-loop so the smoke never sleeps."""
         from tiny_deepspeed_tpu.serving import ServingEngine
         from tiny_deepspeed_tpu.serving.driver import (

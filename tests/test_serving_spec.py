@@ -446,7 +446,7 @@ class TestSpecComposition:
         means something: an UNTRAINED model's greedy output is
         aperiodic (measured — nothing accepts on it, repetitive prompt
         or not), so train a small-vocab model briefly on periodic
-        sequences the way BENCH_SPEC does.  The contrast is measured
+        sequences.  The contrast is measured
         over a SHORT horizon (6 new tokens, 5 prompts each way):
         prompt lookup has material from the first span on a repetitive
         prompt, while a random prompt offers nothing to mine until the

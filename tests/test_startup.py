@@ -57,7 +57,7 @@ class TestCompileCacheRule:
                         with open(os.path.join(dp, f)) as fh:
                             text = fh.read()
                         hits += [(f, o) for o in old if o in text]
-        for f in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
+        for f in ("__graft_entry__.py", "chip_smoke.py"):
             with open(os.path.join(REPO, f)) as fh:
                 text = fh.read()
             hits += [(f, o) for o in old if o in text]
@@ -129,23 +129,6 @@ class TestGeneratedFilesDoNotSteer:
         else:
             assert loader.native_build_error()
 
-    def test_bench_refuses_mfu_for_an_unknown_device(self):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "bench_startup_test", os.path.join(REPO, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-
-        class Dev:
-            device_kind = "cpu"
-
-        with pytest.raises(SystemExit, match="no peak"):
-            bench._peak_flops_per_chip(Dev())
-        Dev.device_kind = "TPU v5 lite"
-        assert bench._peak_flops_per_chip(Dev()) == 197e12
-        assert not hasattr(bench, "_retry_or_diagnose")
-        assert not hasattr(bench, "_load_last_good")
-
 
 class TestKernelNotes:
     def test_gates_note_what_they_traced(self):
@@ -193,10 +176,9 @@ class TestKernelNotes:
 TRAINERS = [os.path.join("examples", d, "train.py")
             for d in ("single_device", "ddp", "zero1", "zero2", "zero3",
                       "pipeline")]
-OTHERS = ["chip_smoke.py", "bench.py",
+OTHERS = ["chip_smoke.py",
           os.path.join("scripts", "serve_bench.py"),
-          os.path.join("examples", "generate.py"),
-          os.path.join("scripts", "profile_step.py")]
+          os.path.join("examples", "generate.py")]
 
 
 def _refused_without_chip(paths):
@@ -216,7 +198,7 @@ def _refused_without_chip(paths):
         assert out.strip() == "", (path, out[-500:])
 
 
-def test_chip_smoke_and_bench_refuse_the_cpu():
+def test_chip_smoke_and_serve_bench_refuse_the_cpu():
     _refused_without_chip(OTHERS[:2])
 
 
@@ -231,7 +213,7 @@ def test_every_entry_point_is_wired_to_the_refusal():
             assert "from common import parse_args, run" in f.read(), path
 
 
-@pytest.mark.slow  # eleven interpreters at once
+@pytest.mark.slow  # nine interpreters at once
 def test_every_entry_point_refuses_the_cpu():
     _refused_without_chip(TRAINERS + OTHERS)
 

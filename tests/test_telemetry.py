@@ -5,7 +5,7 @@
 recomputation across ZeRO stages, telemetry-off HLO identity (the knob is
 free when off), step-timer upgrades (p50/p95, segments, recompile
 attribution, exception safety), anomaly one-shot firing, the JSONL schema
-round-trip through scripts/report_run.py, and the bench telemetry sidecar.
+round-trip through scripts/report_run.py.
 """
 
 import importlib.util
@@ -459,54 +459,28 @@ class TestExampleEndToEnd:
         assert meta["schema_version"] == schema.SCHEMA_VERSION
 
 
-class TestBenchTelemetrySidecar:
-    def _bench(self):
-        spec = importlib.util.spec_from_file_location(
-            "bench_telemetry_test", os.path.join(REPO, "bench.py")
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+class TestReportPricesMfu:
+    """A run_meta that names the device's peak gets an MFU line, labeled
+    with the accounting it rests on: the HLO-counted FLOPs where the file
+    has them, else the matmul formula, else 6N."""
 
-    def test_fresh_cycle_vs_baseline(self, tmp_path, monkeypatch):
-        bench = self._bench()
-        d = tmp_path / "repo"
-        d.mkdir()
-        monkeypatch.setattr(bench.os.path, "dirname", lambda p: str(d))
-        assert bench._prev_round_value() is None       # trajectory []
-        assert bench._vs_prev_round(1000.0) == 1.0     # explicit neutral
-        (d / "BENCH_r01.json").write_text(json.dumps({"value": 500.0}))
-        assert bench._prev_round_value() == 500.0
-        assert bench._vs_prev_round(1000.0) == 2.0
+    STEPS = [{"step": i, "step_s": 0.1, "tokens_per_s": 2.56e6}
+             for i in range(3)]
+    RUN = {"kind": "run_meta", "schema_version": schema.SCHEMA_VERSION,
+           "model": "tiny", "devices": 1, "n_params": 1_000_000_000,
+           "tokens_per_step": 256, "peak_flops_per_chip": 197e12}
 
-    def test_rounds_order_numerically(self, tmp_path, monkeypatch):
-        """Round files must sort by round NUMBER: lexicographically r9 >
-        r10, which from round 10 on would compare the trajectory against
-        the wrong round."""
-        bench = self._bench()
-        d = tmp_path / "repo"
-        d.mkdir()
-        monkeypatch.setattr(bench.os.path, "dirname", lambda p: str(d))
-        (d / "BENCH_r9.json").write_text(json.dumps({"value": 900.0}))
-        (d / "BENCH_r10.json").write_text(json.dumps({"value": 1000.0}))
-        assert bench._prev_round_value() == 1000.0
-
-    def test_sidecar_writes_valid_jsonl(self, tmp_path, ddp_off):
-        bench = self._bench()
-        path = str(tmp_path / "bench_telemetry.jsonl")
-        state = ddp_off.init(jax.random.PRNGKey(0))
-        batch = make_batch(5)
-        compiled = ddp_off._step.lower(state, batch).compile()
-        bench._write_bench_telemetry(
-            path, ddp_off, state, batch, compiled.as_text(),
-            "tiny", ddp_off.n_dev, 8, 32, 197e12, steps=2,
-        )
-        counts, errs = schema.validate_file(path)
-        assert errs == []
-        # run_meta alone: the trace span-template record went (PR 26)
-        assert counts["step"] == 2 and counts["meta"] == 1
+    @pytest.mark.parametrize("extra,label,flops_per_token", [
+        ({"hlo_cost": {"total_flops": 256 * 7e9},
+          "flops_per_token_matmul": 5e9}, "HLO-counted", 7e9),
+        ({"flops_per_token_matmul": 5e9}, "matmul accounting", 5e9),
+        ({}, "6N naive", 6e9),
+    ])
+    def test_labels_the_accounting(self, extra, label, flops_per_token):
         rr = _load_report_run()
-        metas, steps, _ = rr.load_run(path)
-        report = rr.render_report(metas, steps, source=path)
-        assert "MFU" in report       # peak_flops_per_chip + n_params given
-        assert "HLO ledger" in report
+        report = rr.render_report([dict(self.RUN, **extra)], self.STEPS,
+                                  source="x.jsonl")
+        line = [ln for ln in report.splitlines() if "MFU" in ln]
+        assert len(line) == 1 and label in line[0], report
+        mfu = flops_per_token * 2.56e6 / 197e12
+        assert line[0].endswith(f"{mfu:.3f}")

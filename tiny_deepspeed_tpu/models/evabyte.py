@@ -92,13 +92,35 @@ class EvaLayout(NamedTuple):
     `block_tokens` rows: `window` blocks of exact K/V, written as a ring
     (position n rests in row n % W), then `summary` blocks, one row per
     closed chunk.  A slot's block-table row is the two tables side by
-    side: entries [0, window) and [window, window + summary)."""
+    side: entries [0, window) and [window, window + summary).  The
+    members are those of every slot layout (serving/pool.DenseLayout
+    says what the engine asks of each)."""
 
     window: int
     summary: int
     window_size: int
     chunk_size: int
     block_tokens: int
+
+    span = "roll"
+    # a slot can hold no more than its ring and its summary rows, and
+    # nothing but slots holds blocks: a block beyond max_active slots'
+    # worst case could never be allocated
+    bounds_pool = True
+    refuses = {
+        "prefix_cache": "be served with prefix_cache: the radix tree "
+                        "shares blocks of K/V by token prefix, and knows "
+                        "no window ring or summary rows",
+        "spec_draft": "be served with spec_draft: the verify program "
+                      "scores a span per slot, and the window ring and "
+                      "summary rows take one position",
+        "quant": "be served with quant: a quantized pool keeps "
+                 "per-vector scales the summary rows and the EVA decode "
+                 "kernel do not read",
+        **{verb + "_request": verb + " a request's blocks: export_blocks "
+           "/ import_blocks move one table of K/V blocks, not a window "
+           "ring and summary rows" for verb in ("export", "import")},
+    }
 
     @property
     def width(self) -> int:
@@ -118,6 +140,28 @@ class EvaLayout(NamedTuple):
         bt = self.block_tokens
         return (min(bucket, self.window_size) // bt,
                 -(-(bucket // self.chunk_size) // bt))
+
+    def fill_row(self, row, table, summary) -> None:
+        row[:len(table)] = table
+        row[self.window:self.window + len(summary)] = summary
+
+    def tick_counts(self, slots, max_active: int):
+        """What the slots hold this tick: blocks by kind, and how many
+        slots START A NEW WINDOW with this step -- their ring is written
+        from row 0 again, with no free and no alloc.  The span carries
+        besides the live slots and the rows the decode step will attend
+        (live window rows and visible summaries)."""
+        w = self.window_size
+        per = w // self.chunk_size
+        counts = dict(
+            window_blocks=sum(len(s.table) for s in slots),
+            summary_blocks=sum(len(s.summary) for s in slots),
+            windows_rolled=sum(s.pos > 0 and s.pos % w == 0
+                               for s in slots))
+        return counts, dict(
+            active=len(slots),
+            rows=sum(s.pos % w + s.pos // w * per for s in slots),
+            **counts)
 
 
 class EvaByteModel(LlamaModel):

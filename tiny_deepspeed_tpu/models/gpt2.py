@@ -205,9 +205,9 @@ def effective_xent_impl(cfg, multi_device: bool = False,
                         seq_sharded: bool = False,
                         tokens: Optional[int] = None) -> str:
     """The loss-head implementation a step with this config/mesh actually
-    runs — ONE predicate shared by `GPT2Model.head` and bench.py's A/B
-    record (mirroring moe.effective_dispatch), so a measurement can never
-    be labeled with a knob value that fell back.
+    runs — ONE predicate, the one `GPT2Model.head` gates on (mirroring
+    moe.effective_dispatch), so a measurement can never be labeled with a
+    knob value that fell back.
 
     Returns "unfused" (materialized logits), "chunked" (XLA
     fused_linear_xent ladder), or "pallas" (ops/xent_pallas.py — only on
@@ -958,7 +958,7 @@ class GPT2Model:
 
         if targets is not None:
             # ONE shared predicate (effective_xent_impl) decides the head
-            # implementation for both this gate and bench.py's A/B record
+            # implementation: this gate, and whoever labels a run with it
             impl = effective_xent_impl(
                 c,
                 multi_device=pctx is not None and pctx.is_multi_device,
@@ -1299,11 +1299,14 @@ class GPT2Model:
     # numbers, so a method put above them would re-key every program)
 
     def paged_layout(self, max_seq: int, block_tokens: int):
-        """How a slot's blocks are laid out where one block table of
-        ceil(length / block_tokens) entries does not say it: None here
-        (K and V of the whole context), `models/evabyte.EvaLayout` for a
-        family that keeps a window and chunk summaries."""
-        return None
+        """What a slot holds in the paged pool and how it fills its
+        block-table row (serving/pool.DenseLayout states what the
+        engine asks of a layout): K and V of the whole context here,
+        one table of ceil(max_seq / block_tokens) entries;
+        `models/evabyte.EvaLayout` for a family that keeps a window and
+        chunk summaries."""
+        from ..serving.pool import DenseLayout
+        return DenseLayout(-(-max_seq // block_tokens), block_tokens)
 
     def paged_page_ref(self, tables, pos, block_tokens: int):
         """The decode step's write coordinates (serving/pool.page_ref:

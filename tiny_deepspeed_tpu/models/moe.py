@@ -53,9 +53,9 @@ class MoEConfig(GPTConfig):
     # matmul FLOPs themselves — while the sort path moves the same rows
     # with O(S*k log) sort + gather.  Round 16: the einsum cost IS now
     # counted as model compute — `dispatch_combine_flops_per_token`
-    # below feeds bench's flops_tok_matmul when the effective dispatch
-    # is einsum, and tests/test_hlo_cost.py pins the analytic number
-    # against the HLO-counted FLOPs of the compiled step.
+    # below is that term when the effective dispatch is einsum, and
+    # tests/test_hlo_cost.py pins the analytic number against the
+    # HLO-counted FLOPs of the compiled step.
     # "sort" runs single-device and — round 5 — SHARD-LOCAL under pure
     # data parallelism (experts replicated: each device argsorts its own
     # token shard inside a shard_map, capacity prorated by shard, zero
@@ -63,7 +63,7 @@ class MoEConfig(GPTConfig):
     # ep/tp/sp/pipe: with EP the einsum contraction IS what GSPMD turns
     # into the all-to-all, and the other axes would put the gather/
     # scatter on partially-manual meshes (`effective_dispatch` is the
-    # single predicate; bench.py records its answer).  Slot
+    # single predicate).  Slot
     # assignment differs under capacity overflow: einsum fills all 1st
     # choices before 2nd choices, sort fills token-major — identical
     # outputs whenever nothing drops (pinned by test).
@@ -72,8 +72,8 @@ class MoEConfig(GPTConfig):
 
 def effective_dispatch(cfg, pctx) -> str:
     """The dispatch mechanism a step with this config/mesh actually runs —
-    ONE predicate shared by `_moe_mlp` and bench.py's A/B record, so a
-    measurement can never be labeled with a knob value that fell back."""
+    ONE predicate, the one `_moe_mlp` gates on, so a measurement can
+    never be labeled with a knob value that fell back."""
     if cfg.moe_dispatch != "sort":
         return cfg.moe_dispatch
     if pctx is None or not pctx.is_multi_device:

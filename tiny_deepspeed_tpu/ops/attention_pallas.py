@@ -114,17 +114,3 @@ FA2_MAX_T = 16384
 FLASH_VARIANTS = [_fa2_variant(512, 512), _fa2_variant(1024, 512),
                   _variant(1024, 512), _variant(512, 512),
                   _variant(1024, 1024)]
-
-
-def promote_flash_variant(name: str) -> bool:
-    """Reorder FLASH_VARIANTS in place so `name` dispatches as the
-    untuned default (candidates[0] — what `flash_attention` runs with
-    no tuner installed, and what a frozen tuner falls back to).  This
-    is the seam tune_e2e's kernel-block-size knob turns: the e2e search
-    measures whole steps per variant instead of standalone kernel
-    timings.  Returns False (list untouched) for an unknown name."""
-    for i, fn in enumerate(FLASH_VARIANTS):
-        if fn.__name__ == name:
-            FLASH_VARIANTS.insert(0, FLASH_VARIANTS.pop(i))
-            return True
-    return False

@@ -6,7 +6,7 @@
 Pallas kernels (flash attention, fused layernorm, fused AdamW) are chosen
 at TRACE time — tracers carry no device, so the gates historically read
 `jax.default_backend()`.  That breaks ahead-of-time compilation against a
-compile-only TPU topology (scripts/aot_topology.py, aot_memory.py,
+compile-only TPU topology (scripts/aot_topology.py,
 tests/test_aot_topology.py): the process backend is CPU while the program
 targets TPU, so every gate silently picked the XLA fallback and the
 "TPU-compiled" programs differed from what the chip actually runs —

@@ -49,7 +49,7 @@ def linear_forward(x, w, b=None, tuner=None):
 
     fp8 (ops/matmul_fp8.py): mode "candidate" adds the e4m3 forward
     matmul to the tuner list (it wins only if measured faster); "on"
-    forces it — the BENCH_FP8_MATMUL A/B arm.  "off" (default) takes
+    forces it.  "off" (default) takes
     the exact pre-fp8 path: same candidates, same trace, byte-identical
     HLO (pinned)."""
     from .matmul_fp8 import _fwd_fp8, fp8_matmul_mode

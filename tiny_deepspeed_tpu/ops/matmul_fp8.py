@@ -39,8 +39,7 @@ Mode switch (`set_fp8_matmul`): "off" (default — the trace, and its
 HLO, is byte-identical to the pre-fp8 path, pinned in
 tests/test_paged_kernel.py), "candidate" (fp8 joins the autotuner
 candidate list and wins only if measured faster), "on" (every
-`linear_forward` and the fused-xent head's chunk matmuls run fp8 —
-the A/B arm `BENCH_FP8_MATMUL=on` measures).
+`linear_forward` and the fused-xent head's chunk matmuls run fp8).
 
 On non-TPU kernel targets the quantized values upcast to float32 for
 the dot (XLA-CPU has no fp8 MXU; the NUMBERS are identical because

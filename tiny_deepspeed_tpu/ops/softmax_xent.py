@@ -70,8 +70,8 @@ def _pick_chunk(t: int, want: int) -> int:
 
 def _head_logits(xc, w):
     """One chunk's lm_head matmul in f32 — or the e4m3 fp8 matmul when
-    ops/matmul_fp8 is forced "on" (the BENCH_FP8_MATMUL arm covers the
-    fused head too; trace-time gate, so "off" stays byte-identical)."""
+    ops/matmul_fp8 is forced "on" (the mode covers the fused head
+    too; trace-time gate, so "off" stays byte-identical)."""
     from .matmul_fp8 import fp8_matmul, fp8_matmul_mode
     if fp8_matmul_mode() == "on":
         return fp8_matmul(xc, w)

@@ -1015,17 +1015,6 @@ class ZeroEngine:
             self._build_step()
         return n
 
-    def revert_tune(self) -> None:
-        """Undo autotuning: uninstall the process-default tuner and rebuild
-        the step with every dispatch site's candidate[0] default — the
-        guardrail counterpart to retune() for when the standalone-timed
-        winners lose end-to-end (the hazard optim/adamw_pallas.py measured;
-        bench.py's BENCH_AUTOTUNE pass uses this when the tuned step is
-        slower than the default one)."""
-        from ..autotuner import set_default_tuner
-        set_default_tuner(None)
-        self._build_step()
-
     # -- state creation ----------------------------------------------------
 
     def init(self, key) -> "TrainState":

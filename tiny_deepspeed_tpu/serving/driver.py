@@ -3,8 +3,8 @@
 
 """Synthetic serving load: Poisson arrivals through the engine, and the
 serial `generate()` baseline the continuous-batching numbers are judged
-against.  Shared by `scripts/serve_bench.py`, `bench.py` (BENCH_SERVE)
-and tests/test_serving.py so the three never measure different things.
+against.  Shared by `scripts/serve_bench.py` and tests/test_serving.py
+so the two never measure different things.
 """
 
 from __future__ import annotations

@@ -120,6 +120,7 @@ the decode/prefill HLO is byte-identical with observability on or off
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import os
@@ -463,27 +464,16 @@ class ServingEngine:
                 "is not wired through the suffix path — run one or "
                 "the other"
             )
-        # a model that keeps a window and chunk summaries (two block
-        # tables a slot) says so; what cannot follow it yet is refused
-        # here, by the mechanism's name
+        # what a slot holds in the pool and how it fills its table row
+        # is the model's to say (serving/pool.DenseLayout); what cannot
+        # follow its cache yet is refused here, in the layout's words
         self._layout = model.paged_layout(
             config.max_seq_tokens or c.block_size, config.block_tokens)
-        if self._layout is not None:
-            for on, what in (
-                    (config.prefix_cache, "prefix_cache: the radix tree "
-                     "shares blocks of K/V by token prefix, and knows no "
-                     "window ring or summary rows"),
-                    (config.spec_draft is not None, "spec_draft: the "
-                     "verify program scores a span per slot, and the "
-                     "window ring and summary rows take one position"),
-                    (config.quant is not None, "quant: a quantized pool "
-                     "keeps per-vector scales the summary rows and the "
-                     "EVA decode kernel do not read")):
-                if on:
-                    raise ValueError(
-                        f"{type(model).__name__} cannot be served with "
-                        + what)
         self.model = model
+        self._refuse(*(feature for feature, on in (
+            ("prefix_cache", config.prefix_cache),
+            ("spec_draft", config.spec_draft is not None),
+            ("quant", config.quant is not None)) if on))
         self.params = params
         self.config = config
         self.telemetry = telemetry
@@ -516,18 +506,16 @@ class ServingEngine:
         # one block table row per slot, wide enough for a max_seq
         # request (both tables side by side where there are two);
         # unused entries point at scratch
-        self.max_blocks_per_req = (
-            -(-self.max_seq // config.block_tokens)
-            if self._layout is None else self._layout.width)
-        # a model that states what a slot CAN hold bounds the pool,
+        self.max_blocks_per_req = self._layout.width
+        # a layout that states what a slot CAN hold bounds the pool,
         # whatever num_blocks says: a block beyond max_active slots'
         # worst case could never be allocated (a caller that sizes the
         # pool as slots x context / block_tokens would ask for 8 times
         # what a window and its summaries take)
         num_blocks = config.num_blocks
-        if self._layout is not None:
+        if self._layout.bounds_pool:
             num_blocks = min(num_blocks, config.max_active * sum(
-                self._need(self.max_seq - 1)))
+                self._layout.need(self.max_seq - 1)))
         self._pool_args = dict(
             n_layer=c.n_layer, kv_heads=kv_heads, head_dim=c.head_dim,
             num_blocks=num_blocks,
@@ -840,7 +828,7 @@ class ServingEngine:
                    if self.max_seq < c.block_size
                    else f"block_size {c.block_size}")
             )
-        worst = sum(self._need(total - 1))
+        worst = sum(self._layout.need(total - 1))
         if worst > self.pool.num_usable:
             raise ValueError(
                 f"request needs up to {worst} blocks but the pool has "
@@ -949,6 +937,14 @@ class ServingEngine:
         if not ids:
             ids = {"tick": self._tick["tick"]}
         return _TickSpan(self._tick["segments"], name, ids)
+
+    def _refuse(self, *features: str) -> None:
+        """Raise for the first of `features` that the layout's cache
+        cannot follow, with the layout's own sentence."""
+        for feature in features:
+            if feature in self._layout.refuses:
+                raise ValueError(f"{type(self.model).__name__} cannot "
+                                 + self._layout.refuses[feature])
 
     def drain(self, max_ticks: Optional[int] = None) -> int:
         """Tick until every submitted request is done; returns total
@@ -1112,11 +1108,7 @@ class ServingEngine:
             raise ValueError(f"slot {i} is empty — nothing to export")
         req = slot.req
         now = time.monotonic()
-        if self._layout is not None:
-            raise ValueError(
-                f"{type(self.model).__name__} cannot export a request's "
-                "blocks: export_blocks / import_blocks move one table of "
-                "K/V blocks, not a window ring and summary rows")
+        self._refuse("export_request")
         payload = export_blocks(self.pool.view, slot.table)
         self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
@@ -1149,11 +1141,7 @@ class ServingEngine:
         False (nothing consumed) when no slot or blocks are free;
         geometry/dtype mismatches between the pools raise with both
         sides named (serving/pool.import_blocks)."""
-        if self._layout is not None:
-            raise ValueError(
-                f"{type(self.model).__name__} cannot import a request's "
-                "blocks: export_blocks / import_blocks move one table of "
-                "K/V blocks, not a window ring and summary rows")
+        self._refuse("import_request")
         if self._spec is not None:
             raise ValueError(
                 "import_request on a speculative engine is unsupported "
@@ -1376,15 +1364,13 @@ class ServingEngine:
         poison = np.zeros((S,), np.float32)
         tables = np.full((S, self.max_blocks_per_req), SCRATCH_BLOCK,
                          np.int32)
+        fill_row = self._layout.fill_row
         for i, s in active:
             tokens[i] = s.last
             pos[i] = s.pos
             seeds[i] = s.req.seed
             nprod[i] = len(s.req.tokens)
-            tables[i, :len(s.table)] = s.table
-            if s.summary:
-                at = self._layout.window
-                tables[i, at:at + len(s.summary)] = s.summary
+            fill_row(tables[i], s.table, s.summary)
         if self._poison_pending:
             for i in self._poison_pending:
                 poison[i] = np.nan
@@ -1398,8 +1384,6 @@ class ServingEngine:
         with self._operands_span(active):
             tokens, pos, seeds, nprod, poison, tables = \
                 self._slot_arrays(active)
-        if self._layout is not None:
-            self._note_cache(active)
         # dispatch returns before the device finishes (async); the
         # np.asarray token fetch is the sync — the tick record splits
         # the two (decode.dispatch vs decode.fetch)
@@ -1439,25 +1423,6 @@ class ServingEngine:
                     "poisoned decode ticks"
                 )
         return produced
-
-    def _note_cache(self, active) -> None:
-        """What the slots of a model with two kinds of cache hold this
-        tick, counted into the tick's record and written as the ids of
-        `tds.tick.roll`: blocks by kind, the rows the decode step will
-        attend (live window rows and visible summaries), and how many
-        slots START A NEW WINDOW with this step -- their ring is written
-        from row 0 again, with no free and no alloc."""
-        w = self._layout.window_size
-        per = w // self._layout.chunk_size
-        counts = dict(
-            window_blocks=sum(len(s.table) for _, s in active),
-            summary_blocks=sum(len(s.summary) for _, s in active),
-            windows_rolled=sum(s.pos > 0 and s.pos % w == 0
-                               for _, s in active))
-        with self._span("roll", tick=self._tick["tick"], active=len(active),
-                        rows=sum(s.pos % w + s.pos // w * per
-                                 for _, s in active), **counts):
-            self._tick.update(counts)
 
     def _decode_spec(self, active) -> int:
         """Speculative tick: drafter proposes up to K tokens per slot,
@@ -1610,27 +1575,17 @@ class ServingEngine:
             b *= 2
         return min(b, self.model.config.block_size)
 
-    def _need(self, horizon: int):
-        """(blocks of the table, summary blocks) a slot owns before it
-        writes position `horizon`: one block per block_tokens positions
-        and no second table, unless the model's layout says otherwise."""
-        if self._layout is None:
-            return horizon // self.config.block_tokens + 1, 0
-        return self._layout.need(horizon)
-
     def _prefill_operands(self, prompt_now: List[int], ids: List[int],
                           summary: Sequence[int] = ()):
         """The full-prompt prefill program's (padded prompt, block-id
         panel) operands — shared by the plain and spec admission
-        paths.  With a layout the panel is two, side by side: the blocks
-        of one window, then a summary row per chunk of the bucket."""
+        paths.  The panel is the layout's: the table's entries, then
+        the second table's where there is one (`prefill_panel`)."""
         p = len(prompt_now)
-        bt = self.config.block_tokens
         bucket = self._bucket(p)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt_now
-        nw, ns = ((bucket // bt, 0) if self._layout is None
-                  else self._layout.prefill_panel(bucket))
+        nw, ns = self._layout.prefill_panel(bucket)
         block_ids = np.full((nw + ns,), SCRATCH_BLOCK, np.int32)
         # the prefill panel only spans the bucket; the +1 decode
         # block can lie past it (boundary p == bucket) — it is
@@ -1730,7 +1685,7 @@ class ServingEngine:
                     # to the request's final position — replaces p: same
                     # worst-case block count as the plain path, claimed
                     # up front instead of across the first few grows
-                    n_table, n_summary = self._need(
+                    n_table, n_summary = self._layout.need(
                         self._write_horizon(req, p))
                     ids_new = self._alloc(
                         n_table + n_summary - len(alias))
@@ -1778,7 +1733,7 @@ class ServingEngine:
                         span[0, :len(suffix)] = suffix
                         tables = np.full((1, self.max_blocks_per_req),
                                          SCRATCH_BLOCK, np.int32)
-                        tables[0, :len(ids)] = ids
+                        self._layout.fill_row(tables[0], ids, summary)
                         fn, args = self._prefill_suffix_fn, (
                             self.params, self._stacked, span, tables,
                             np.asarray([p0], np.int32),
@@ -1880,7 +1835,7 @@ class ServingEngine:
         def short(slot):
             """The table that lacks a block for the next write, if one
             does (a window ring that is whole never grows again)."""
-            n_table, n_summary = self._need(
+            n_table, n_summary = self._layout.need(
                 self._write_horizon(slot.req, slot.pos))
             if len(slot.table) < n_table:
                 return slot.table
@@ -2222,28 +2177,25 @@ class ServingEngine:
         if self._FLIGHT_PRIORITY[reason] > cur:
             self._flight_reason = reason
 
-    def _operands_span(self, active) -> _TickSpan:
-        """`tds.tick.decode.operands`, carrying how much of their
-        tables the slots' lengths (`_slot_arrays`' `pos`) fill, in the
-        paged kernel's unit, a chunk of a table row
-        (ops/paged_attn_pallas.pool_steps): `kv_steps` the chunks the
-        slots' rows hold, `kv_steps_live` those that begin below their
-        slot's length, which are all the kernel copies and folds, a
-        layer.  Both go into the tick's record too.  A model with a
-        cache layout of its own reads the pool through its own kernel
-        and counts in `_note_cache`."""
-        ids = {"tick": self._tick["tick"]}
-        if self._layout is None:
-            from ..ops.paged_attn_pallas import pool_steps
-            bt = self.config.block_tokens
-            nb, npool = pool_steps(self.max_blocks_per_req, bt)
-            counts = dict(
-                kv_steps_live=sum(min(-(-s.pos // (nb * bt)), npool)
-                                  for _, s in active),
-                kv_steps=self.config.max_active * npool)
-            self._tick.update(counts)
-            ids.update(counts)
-        return _TickSpan(self._tick["segments"], "decode.operands", ids)
+    @contextlib.contextmanager
+    def _operands_span(self, active):
+        """`tds.tick.decode.operands`, around the building of the decode
+        (or verify) step's operands, and what the layout counts of the
+        tick's slots (`tick_counts`): into the tick's record, and as the
+        ids of the span the layout names, which is this one or one of
+        its own, opened and closed right after this one."""
+        lay = self._layout
+        counts, ids = lay.tick_counts([s for _, s in active],
+                                      self.config.max_active)
+        self._tick.update(counts)
+        tick = self._tick["tick"]
+        own = lay.span != "decode.operands"
+        with self._span("decode.operands", tick=tick,
+                        **({} if own else ids)):
+            yield
+        if own:
+            with self._span(lay.span, tick=tick, **ids):
+                pass
 
     def _record_tick(self, rec: dict) -> None:
         """End-of-tick bookkeeping, from the tick's own record (`rec`,

@@ -59,6 +59,8 @@ from typing import Dict, List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..ops.paged_attn_pallas import pool_steps
+
 # the never-allocated block absorbing invalid-slot / padding writes
 SCRATCH_BLOCK = 0
 
@@ -117,6 +119,65 @@ def pool_shape(num_blocks: int, block_tokens: int, n_layer: int,
     the sizes alone.  The scales of a quantized pool follow it with
     head_dim = 1."""
     return (num_blocks, block_tokens, n_layer * kv_heads * head_dim)
+
+
+class DenseLayout(NamedTuple):
+    """What one slot holds in the pool, in blocks of `block_tokens`
+    rows, and how that reaches its block-table row: K and V of the whole
+    context, one block per `block_tokens` positions, one table.
+
+    A slot layout is the one thing `serving/engine.py` asks about a
+    model's cache, and every servable model states one
+    (`GPT2Model.paged_layout`; `models/evabyte.EvaLayout` is the other).
+    Its members, all of them host arithmetic:
+
+    width             entries of a slot's block-table row
+    need(pos)         (table blocks, summary blocks) a slot owns before
+                      it writes position `pos`: never fewer for a later
+                      position, never more than the row holds
+    prefill_panel(b)  (table, summary) entries of the block-id panel a
+                      prefill of bucket `b` scatters through
+    fill_row(..)      a slot's two block lists into its table row
+    span, tick_counts(..)
+                      what a decode tick counts of its slots: `counts`
+                      go into the tick's record, `ids` onto the span
+                      `tds.tick.<span>` (utils/profiling.TABLE)
+    bounds_pool       whether max_active x need(last position) is all
+                      the pool can ever hold.  Not here: a prefix tree
+                      keeps blocks that no slot owns
+    refuses           {engine feature: why}, each refused by the engine
+                      as "<Model> cannot <why>"; nothing here"""
+
+    width: int
+    block_tokens: int
+
+    span = "decode.operands"
+    bounds_pool = False
+    refuses = {}
+
+    def need(self, pos: int):
+        return pos // self.block_tokens + 1, 0
+
+    def prefill_panel(self, bucket: int):
+        return bucket // self.block_tokens, 0
+
+    def fill_row(self, row, table, summary) -> None:
+        row[:len(table)] = table
+
+    def tick_counts(self, slots, max_active: int):
+        """How much of their table rows the slots' lengths fill, in the
+        paged kernel's unit, a chunk of a row
+        (ops/paged_attn_pallas.pool_steps): `kv_steps` the chunks
+        `max_active` rows hold, `kv_steps_live` those that begin below
+        their slot's length, which are all the kernel copies and folds,
+        a layer."""
+        nb, npool = pool_steps(self.width, self.block_tokens)
+        chunk = nb * self.block_tokens
+        counts = dict(
+            kv_steps_live=sum(min(-(-s.pos // chunk), npool)
+                              for s in slots),
+            kv_steps=max_active * npool)
+        return counts, counts
 
 
 def _quant_vectors(x, mode: str):

@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Tuple
 _NUM = (int, float)
 
 # Version of this schema, stamped into every `run_meta` record
-# (Telemetry.run_meta / bench.py's sidecar).  Bump it when record kinds or
+# (Telemetry.run_meta).  Bump it when record kinds or
 # fields change so `report_run.py --check` can WARN when a file was
 # written by a different schema vintage (a mismatch is advisory — the
 # field-level validation below is what hard-fails).

@@ -5,9 +5,9 @@
 
 `hlo_comm.collective_ledger` prices the WIRE axis of a compiled step from
 the post-SPMD HLO text.  Compute, until now, was a hand formula
-(bench.py `flops_tok_matmul`) and HBM traffic was not measured at all —
-so "MFU" compared a measured time against an analytic numerator, and
-nothing could say whether a program is compute-, HBM-, or wire-bound.
+(6 x the matmul parameters a token) and HBM traffic was not measured at
+all — so "MFU" compared a measured time against an analytic numerator,
+and nothing could say whether a program is compute-, HBM-, or wire-bound.
 
 This module closes the loop with the same machinery: split the HLO into
 computations, multiply while bodies by their static trip counts, and walk
@@ -81,8 +81,8 @@ from .hlo_comm import (
 # Per-device roofline tables (public spec-sheet numbers), keyed by a
 # substring of `jax.devices()[0].device_kind` ("TPU v5 lite" on a v5e).
 #
-# Peak dense bf16 FLOP/s per chip (bench._peak_flops_per_chip delegates
-# here so the MFU denominator and the roofline verdict cannot drift).
+# Peak dense bf16 FLOP/s per chip: one table, so the MFU denominator and
+# the roofline verdict cannot drift.
 # HBM and interchip (ICI) bandwidths are per chip:
 #   HBM    v4 1228 GB/s · v5e 819 GB/s · v5p 2765 GB/s · v6e 1640 GB/s
 #   ICI    v4 300 GB/s  · v5e 200 GB/s · v5p 600 GB/s  · v6e 448 GB/s
