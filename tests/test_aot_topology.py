@@ -630,9 +630,12 @@ def test_eva_decode_step_compiles_for_the_chip_at_published_widths(
         topo_mesh):
     """EvaByte's decode program at the benchmark cell's sizes (6 layers,
     d 4096, 32 heads of 128, 16 slots, 16-row blocks, the pool of 16 x 256
-    blocks): Mosaic takes `tds_eva_paged_attn` (16 K and 16 V blocks of
-    (16, 4096) bf16 a grid step), the pool is aliased from argument to
-    result, and no temporary of the pool's size is made."""
+    blocks): Mosaic takes `tds_eva_paged_attn` (the pool's K and V as two
+    operands left in HBM, a grid step a slot, and a two-deep VMEM buffer
+    of 2 x 4 MiB: two chunks of 16 blocks of (16, 4096) bf16 for K and
+    two for V, which the kernel fills by its own copies), the pool is
+    aliased from argument to result, and no temporary of the pool's size
+    is made."""
     import dataclasses
 
     import jax

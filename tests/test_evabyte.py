@@ -311,7 +311,7 @@ def test_planted_faults_fail_on_the_normal_path_too(model_params,
 def test_the_decode_kernel_agrees_with_the_gathered_panels(
         model_params, monkeypatch, bt):
     """`tds_eva_paged_attn`, interpreted, against the XLA form of the same
-    tick: two valid ranges a slot, dead blocks named but not read."""
+    tick: two valid ranges a slot, dead blocks neither looked up nor read."""
     monkeypatch.setattr(paged_attn_pallas, "INTERPRET", True)
     model, params = model_params
     prompts = [_tokens(30 + n, n).tolist() for n in (3, W, 2 * W + 5)]
@@ -320,6 +320,88 @@ def test_the_decode_kernel_agrees_with_the_gathered_panels(
         gaps[mode] = _serve_gap(model, params, prompts, W + 3,
                                 block_tokens=bt, paged_kernel=mode)[0]
     assert gaps["off"] < TOL and gaps["on"] < TOL, gaps
+
+
+# a hand-built pool for the kernel alone: windows of 64 rows, a summary per
+# 16, blocks of 8 rows; a table row of 8 window entries and 6 summary
+# entries (48 rows: twelve windows), chunks cut to 32 rows = 4 blocks, so
+# that the window is two chunks and the summaries one and a half
+_KW, _KC, _KBT, _KSUM, _KSTEP = 64, 16, 8, 6, 32
+_EDGES = {
+    # position -> (live window rows, visible summaries)
+    "position 0: both ranges empty": [0],
+    "a window just begun, summaries behind it": [4 * _KW],          # 0, 16
+    "a window one row short of full": [_KW - 1, 5 * _KW + _KW - 1],  # 63, 20
+    "both ranges end on a chunk boundary": [8 * _KW + 32],          # 32, 32
+    "both ranges end on a block boundary": [2 * _KW + 40],          # 40, 8
+    "both ranges end inside a block": [3 * _KW + 13],               # 13, 12
+    "every summary row the table holds": [12 * _KW + 5],            # 5, 48
+}
+_EDGES["slots of all these kinds in one call"] = sum(_EDGES.values(), [])
+
+
+@pytest.mark.parametrize("case", list(_EDGES))
+def test_the_decode_kernel_reads_only_what_is_live(monkeypatch, case):
+    """`tds_eva_paged_attn`, interpreted and called directly, beside the
+    XLA form over the same live rows.  The kernel's pool holds NaN in
+    every row past a range's bound, in every block no live entry names
+    (the scratch block too, which every dead entry names) and in the other
+    layer's columns: what is dead is neither folded nor able to poison a
+    sum.  The XLA form multiplies dead rows by a weight of 0, so its pool
+    holds zeros there."""
+    from tiny_deepspeed_tpu.ops import eva_attn_pallas
+    from tiny_deepspeed_tpu.serving.pool import KVPoolView, page_ref
+
+    monkeypatch.setattr(paged_attn_pallas, "INTERPRET", True)
+    monkeypatch.setattr(eva_attn_pallas, "_STEP_TOKENS", _KSTEP)
+    h, dh, layers, l = 4, 16, 2, 1
+    c = h * dh
+    lay = evabyte_mod.EvaLayout(window=_KW // _KBT, summary=_KSUM,
+                                window_size=_KW, chunk_size=_KC,
+                                block_tokens=_KBT)
+    assert eva_attn_pallas.eva_steps(lay.window, lay.summary, _KBT) == (
+        4, 2, 2)
+    pos = np.asarray(_EDGES[case], np.int32)
+    s = len(pos)
+    rng = np.random.default_rng(len(case))
+    blocks = 1 + s * lay.width
+    pools = rng.standard_normal((2, blocks, _KBT, layers * c)).astype(
+        np.float32)
+    live = np.zeros((blocks, _KBT), bool)
+    tables = np.zeros((s, lay.width), np.int32)  # dead entries: scratch
+    ids = iter(rng.permutation(np.arange(1, blocks)))
+    n_win, n_sum = eva_ops.eva_bounds(pos, _KW, _KC)
+    for i in range(s):
+        for first, rows in ((0, n_win[i]), (lay.window, n_sum[i])):
+            for e in range(-(-rows // _KBT)):
+                blk = tables[i, first + e] = next(ids)
+                live[blk, :rows - e * _KBT] = True
+    cols = np.zeros(layers * c, bool)
+    cols[l * c:(l + 1) * c] = True
+    keep = live[..., None] & cols
+    clean, planted = (np.where(keep, pools, fill) for fill in (0.0, np.nan))
+    q, sk, sv = (jnp.asarray(rng.standard_normal((s, h, 1, dh)), jnp.float32)
+                 for _ in range(3))
+
+    def view(a):
+        return KVPoolView(jnp.asarray(a[0]), jnp.asarray(a[1]), None, None)
+
+    page = page_ref(jnp.asarray(tables), jnp.asarray(pos), _KBT)
+    with paged_attn_pallas.paged_kernel_forced("off"):
+        want = eva_ops.eva_paged_attention(q, view(clean), page, l, (sk, sv),
+                                           lay)
+    got = eva_attn_pallas.eva_paged_attention_kernel(
+        q, view(planted), page.tables, jnp.asarray(n_win),
+        jnp.asarray(n_sum), l, (sk, sv), window_blocks=lay.window)
+    assert got.shape == want.shape == (s, h, 1, dh)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < TOL
+    # and the XLA form over the planted pool IS poisoned wherever a slot
+    # holds anything: the test's NaN lies where a careless read finds it
+    with paged_attn_pallas.paged_kernel_forced("off"):
+        bad = eva_ops.eva_paged_attention(q, view(planted), page, l,
+                                          (sk, sv), lay)
+    assert bool(jnp.isnan(bad).any())
 
 
 # -- the ring, the bound, the refusals ----------------------------------------
@@ -452,7 +534,8 @@ def test_gpt2_serve_programs_lower_to_the_same_text():
 
 def test_the_tick_record_carries_what_the_slots_hold(model_params):
     """`tick_records` and the `tick` JSONL record gain window_blocks,
-    summary_blocks and windows_rolled (schema v17); a GPT-2 engine's
+    summary_blocks and windows_rolled (schema v17), and kv_steps_live of
+    kv_steps in the EVA kernel's chunks (v18's names); a GPT-2 engine's
     records stay as they were."""
     from tiny_deepspeed_tpu.telemetry import schema
 
@@ -476,6 +559,12 @@ def test_the_tick_record_carries_what_the_slots_hold(model_params):
     assert [t["windows_rolled"] for t in ticks] == [0, 0, 0, 1, 0, 0, 0]
     assert [t["summary_blocks"] for t in ticks] == [1, 1, 1, 2, 2, 2, 2]
     assert all(t["window_blocks"] == 4 for t in ticks)
+    # a chunk is 256 rows of a range: the window's alone until position
+    # 32, where the window is empty and 8 summaries are visible, then both
+    assert [t["kv_steps_live"] for t in ticks] == [1, 1, 1, 1, 2, 2, 2]
+    assert all(t["kv_steps"] == 2 * (1 + 1) for t in ticks)
+    kept = [r for r in eng.tick_records if "window_blocks" in r]
+    assert [r["kv_steps_live"] for r in kept] == [1, 1, 1, 1, 2, 2, 2]
     for t in ticks:
         assert schema.validate_record(dict(t, ts=0.0)) == []
     plain = build_model(ALL_PRESETS["tiny"])
@@ -484,6 +573,9 @@ def test_the_tick_record_carries_what_the_slots_hold(model_params):
     other.submit([1, 2, 3], 2)
     other.drain(max_ticks=10)
     assert not any("window_blocks" in r for r in other.tick_records)
+    # its chunks are the dense layout's: one table row of 8 x 16 tokens
+    assert [(r["kv_steps_live"], r["kv_steps"]) for r in other.tick_records
+            if "kv_steps" in r] == [(1, 2)]
 
 
 def test_the_reference_in_bfloat16_is_refused_by_the_same_tolerance(

@@ -96,13 +96,19 @@ _COUNTS = {
     "tiny": ("decode.operands",
              dict(kv_steps_live=3, kv_steps=4),
              dict(kv_steps_live=3, kv_steps=4)),
-    # rows attended: 19 of the window and 4 summaries; 0 of a window just
-    # begun and 2 x 8 summaries; 1 and none.  Slot 1 starts a new window
+    # rows attended: 19 of the window and none of the summaries (the first
+    # window is not past); 0 of a window just begun and 2 x 8 summaries; 1
+    # and none.  Slot 1 starts a new window.  In the EVA kernel's chunks
+    # of 256 rows a range: the window's 32 rows are one chunk and the
+    # summaries' 128 one, so 2 a table row, 8 for four slots; live are
+    # slot 0's window, slot 1's summaries, slot 3's window
     "evabyte-tiny": ("roll",
                      dict(window_blocks=8, summary_blocks=5,
-                          windows_rolled=1),
+                          windows_rolled=1, kv_steps_live=1 + 1 + 1,
+                          kv_steps=4 * (1 + 1)),
                      dict(active=3, rows=19 + 16 + 1, window_blocks=8,
-                          summary_blocks=5, windows_rolled=1)),
+                          summary_blocks=5, windows_rolled=1,
+                          kv_steps_live=3, kv_steps=8)),
 }
 
 
@@ -141,6 +147,32 @@ def test_seeded_slots_fill_fixed_operands_and_counts(served):
     assert {k: eng._tick[k] for k in counts} == counts
     names = [s[0] for s in eng._tick["segments"]]
     assert names == ["decode.operands"] + ([] if dense else ["roll"])
+
+
+@pytest.mark.parametrize("step, live, per_row", [
+    # rows a chunk -> the seeded slots' live chunks (19 window rows and no
+    # summary; none and 16; 1 and none), and the chunks of a table row of
+    # 32 window rows and 128 summary rows
+    (8, 3 + 2 + 1, 4 + 16),
+    (16, 2 + 1 + 1, 2 + 8),
+    (256, 1 + 1 + 1, 1 + 1),
+])
+def test_the_eva_layout_counts_chunks_as_its_kernel_cuts_them(
+        monkeypatch, step, live, per_row):
+    """One constant cuts both: `kv_steps_live` is the trip count of the
+    kernel's loop summed over the slots, whatever a chunk holds."""
+    from tiny_deepspeed_tpu.ops import eva_attn_pallas
+    monkeypatch.setattr(eva_attn_pallas, "_STEP_TOKENS", step)
+    cfg = ALL_PRESETS["evabyte-tiny"]
+    lay = build_model(cfg).paged_layout(cfg.block_size, BT)
+    nb, npw, nps = eva_attn_pallas.eva_steps(lay.window, lay.summary, BT)
+    assert nb * BT == step and npw + nps == per_row
+    slots = [_slot(**kw) for kw in _SLOTS.values()]
+    counts, ids = lay.tick_counts(slots, 4)
+    assert counts["kv_steps_live"] == ids["kv_steps_live"] == live
+    assert counts["kv_steps"] == ids["kv_steps"] == 4 * per_row
+    # an empty slot list, a tick that decodes nothing
+    assert lay.tick_counts([], 4)[0]["kv_steps_live"] == 0
 
 
 _MECHANISM = {"prefix_cache": "radix tree", "spec_draft": "verify program",

@@ -165,6 +165,11 @@ def test_every_span_of_the_table_is_written_and_none_besides(
     assert all(r["active"] == 1 and r["window_blocks"] == 4 for r in rolls)
     assert [r["rows"] for r in rolls] == [
         n % 32 + n // 32 * 8 for n in range(29, 36)]
+    # and in the EVA kernel's chunks, 256 rows of a range: one of the
+    # window at 29-31, one of the 8 summaries at 32 (a window just
+    # begun), both from 33; a table row holds one of each, two slots four
+    assert [r["kv_steps_live"] for r in rolls] == [1, 1, 1, 1, 2, 2, 2]
+    assert {r["kv_steps"] for r in rolls} == {2 * (1 + 1)}
 
 
 def test_tick_spans_nest_as_the_table_says_and_carry_the_tick_number(

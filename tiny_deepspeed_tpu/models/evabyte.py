@@ -43,8 +43,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.eva_attention import (
-    eva_attention, eva_pad_len, eva_paged_attention, eva_summaries,
+    eva_attention, eva_bounds, eva_pad_len, eva_paged_attention,
+    eva_summaries,
 )
+from ..ops.eva_attn_pallas import eva_steps
 from ..ops.rmsnorm import rmsnorm
 from .llama import LlamaConfig, LlamaModel, rope, rope_at
 
@@ -150,17 +152,28 @@ class EvaLayout(NamedTuple):
         slots START A NEW WINDOW with this step -- their ring is written
         from row 0 again, with no free and no alloc.  The span carries
         besides the live slots and the rows the decode step will attend
-        (live window rows and visible summaries)."""
+        (live window rows and visible summaries).  And how much of their
+        table rows that is in the decode kernel's unit, a chunk of a
+        range (ops/eva_attn_pallas.eva_steps): `kv_steps` the chunks
+        `max_active` rows hold, `kv_steps_live` those that begin below
+        their range's bound, which are all the kernel copies and folds,
+        a layer."""
         w = self.window_size
-        per = w // self.chunk_size
+        bounds = [eva_bounds(s.pos, w, self.chunk_size) for s in slots]
+        nb, npw, nps = eva_steps(self.window, self.summary,
+                                 self.block_tokens)
+        chunk = nb * self.block_tokens
         counts = dict(
             window_blocks=sum(len(s.table) for s in slots),
             summary_blocks=sum(len(s.summary) for s in slots),
             windows_rolled=sum(s.pos > 0 and s.pos % w == 0
-                               for s in slots))
+                               for s in slots),
+            kv_steps_live=sum(-(-n_win // chunk) + -(-n_sum // chunk)
+                              for n_win, n_sum in bounds),
+            kv_steps=max_active * (npw + nps))
         return counts, dict(
             active=len(slots),
-            rows=sum(s.pos % w + s.pos // w * per for s in slots),
+            rows=sum(n_win + n_sum for n_win, n_sum in bounds),
             **counts)
 
 

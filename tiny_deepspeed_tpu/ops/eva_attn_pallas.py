@@ -8,19 +8,25 @@ The pool and the way it is read are `ops/paged_attn_pallas.py`'s: the
 arrays as they rest, (blocks, bt, L * KVH * Dh), a (block, layer) one
 (bt, KVH * Dh) window, the queries block-diagonal over the merged
 dimension so that one product gives every head's scores, an online
-softmax in float32 carried across the grid's sequential dimension.  What
-differs is what a slot's table row holds (models/evabyte.EvaLayout): the
-window's blocks first, of which rows [0, n_win) are live, then the
-summary blocks, of which rows [0, n_sum) are visible.  Both bounds ride
-the scalar prefetch, and the grid visits the window's steps, then the
-summaries', then one last step for the position's own key and value.
+softmax in float32.  What differs is what a slot's table row holds
+(models/evabyte.EvaLayout): the window's blocks first, of which rows
+[0, n_win) are live, then the summary blocks, of which rows [0, n_sum)
+are visible.  Both bounds ride the scalar prefetch beside the table.
 
-Only live blocks cross HBM.  A table entry past a range's bound names
-the range's last live block again: Pallas fetches a block only when its
-index changes from one step to the next, so a dead step brings nothing,
-and its arithmetic is skipped.  At 32k of context a slot holds 4096 rows
-of table and attends 1-3 thousand of them; the dead ones cost a grid
-step (a third of a microsecond), not their bytes.
+Grid: (S,), one grid step a slot, parallel.  Only what is live is worked
+on.  The pool arrays stay in HBM (`memory_space=HBM`: no BlockSpec over
+the table, no index map, no pipeline of Pallas's), and the step walks
+the slot's live chunks itself: the window range's, then the summary
+range's, as ONE loop of ceil(n_win / 256) + ceil(n_sum / 256) passes.  A
+chunk is 256 rows of one range, `nb` blocks (`eva_steps`); the step
+copies a chunk's live blocks into one half of a two-deep VMEM buffer
+while it folds the other half, across the boundary of the two ranges as
+well.  A block whose first row lies at or past its range's bound is
+neither looked up in the table nor copied, and what its plane of the
+buffer still holds is masked out of the scores and zeroed out of V.
+Last comes the position's own key and value, not yet in the pool, then
+the result.  A slot with both ranges empty (position 0, or an empty slot
+whose table is scratch) copies nothing and goes straight there.
 
 The products take the pool's bf16 as it is, with float32 accumulation:
 a product of two bf16 numbers is exact in float32, so q . k is what
@@ -29,6 +35,18 @@ the float32 scores.  The softmax weights are rounded to the pool's dtype
 before they multiply V (a relative 2^-9 on each of hundreds of weights,
 which averages out an order under the rounding of the result itself);
 the running sums and the rescaling stay float32.
+
+Measured on a v5e at evabyte-6.5b's serving sizes (16 slots, 256 table
+entries of 16 rows x 4096 columns, one block 128 KiB, the kernel once a
+layer for 6 layers: a decode tick; PERF.md section 6, PR 33), ms a tick
+with every slot empty / 7 slots live at 2.5k-18k positions (10,287 rows)
+/ all 16 at 18k (30,864) / all 16 full (63,472): 0.23 / 1.62 / 4.56 /
+8.63, at 623, 666 and 723 GB/s of the live rows' K and V (819 is the
+chip's).  The kernel this one replaced gave every slot a grid step for
+each chunk of its table row, live or not, with a BlockSpec for each of
+the chunk's 16 K and 16 V blocks, and took 6.27 / 6.74 / 7.82 / 9.07.
+At 256 rows a chunk the two give the same bits; 128 read 0.25 / 1.61 /
+4.45 / 8.57 and 512 0.23 / 1.71 / 4.59 / 8.78.
 """
 
 from __future__ import annotations
@@ -44,30 +62,46 @@ from jax.experimental.pallas import tpu as pltpu
 from . import paged_attn_pallas as _paged
 
 _MASKED = -1e30
-# pool rows a grid step folds (paged_attn_pallas._STEP_TOKENS: a step
-# costs a third of a microsecond whatever it brings)
+# pool rows a chunk holds, of either range: the VMEM buffer is two of
+# them for K and two for V, and a chunk is what one pass of the loop
+# copies and folds (paged_attn_pallas._STEP_TOKENS).  The slot layout
+# counts a tick's chunks by the same rule (`eva_steps`)
 _STEP_TOKENS = 256
+
+
+def eva_steps(window_blocks: int, summary_blocks: int,
+              bt: int) -> tuple[int, int, int]:
+    """(nb, npw, nps): the table entries a chunk holds, and the chunks
+    that cover a table row's `window_blocks` entries and its
+    `summary_blocks` entries, of `bt` rows each."""
+    nb = max(1, _STEP_TOKENS // bt)
+    return nb, -(-window_blocks // nb), -(-summary_blocks // nb)
 
 
 def _eva_kernel(
     # scalar prefetch
     tables_ref, nwin_ref, nsum_ref, l_ref,
-    # inputs, outputs, scratch
-    *refs,
-    bt: int, nb: int, npw: int, nps: int, scale: float,
+    # inputs, output, scratch
+    q_ref, k_pool, v_pool, sk_ref, sv_ref, o_ref, kbuf, vbuf, sem, acc, m, ll,
+    *, bt: int, nb: int, nw_e: int, scale: float,
 ):
-    q_ref = refs[0]
-    k_refs, v_refs = refs[1:1 + nb], refs[1 + nb:1 + 2 * nb]
-    sk_ref, sv_ref, o_ref, acc, m, ll = refs[1 + 2 * nb:]
+    """One slot a grid step: fold the live chunks of the slot's window
+    range, then of its summary range, `nb` blocks a chunk, then the
+    position's own key and value, into the online softmax of its R query
+    rows.  The pool arrays stay in HBM; the kernel copies the blocks it
+    folds into a two-deep VMEM buffer itself, the next chunk's while it
+    folds this one's, across the boundary of the two ranges as well."""
     s = pl.program_id(0)
-    j = pl.program_id(1)
+    c_lanes = kbuf.shape[-1]
+    col = pl.ds(pl.multiple_of(l_ref[0] * c_lanes, c_lanes), c_lanes)
+    step = nb * bt
+    n_win, n_sum = nwin_ref[s], nsum_ref[s]
+    cw = (n_win + step - 1) // step   # the window's live chunks
+    total = cw + (n_sum + step - 1) // step
 
-    @pl.when(j == 0)
-    def _init():
-        acc[...] = jnp.zeros(acc.shape, jnp.float32)
-        m[...] = jnp.full(m.shape, _MASKED, jnp.float32)
-        ll[...] = jnp.zeros(ll.shape, jnp.float32)
-
+    acc[...] = jnp.zeros(acc.shape, jnp.float32)
+    m[...] = jnp.full(m.shape, _MASKED, jnp.float32)
+    ll[...] = jnp.zeros(ll.shape, jnp.float32)
     q = q_ref[0]  # (R, C), the pool's dtype
 
     def dot_nt(a, b):  # a @ b^T over the lanes of both
@@ -85,37 +119,68 @@ def _eva_kernel(
         acc[...] = acc[...] * alpha + values(p)
         m[...] = m_new
 
-    def rows(block_refs):  # the step's nb blocks, one under the other
-        return jnp.concatenate([r[0] for r in block_refs], axis=0)
+    def place(c):
+        """Chunk c of the walk -> (the table entry of its first block,
+        its first row in its range, the range's bound)."""
+        own = c < cw
+        j = jnp.where(own, c, c - cw)
+        return (jnp.where(own, 0, nw_e) + j * nb, j * step,
+                jnp.where(own, n_win, n_sum))
 
-    def pool_step(first_row, bound):
-        scores = dot_nt(q, rows(k_refs)) * scale          # (R, nb * bt)
-        at = first_row + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
+    def copies(at, half, act):
+        """Start, or wait for, the copies of the live blocks of the chunk
+        at `place(c)` into buffer `half`, a block a plane.  A block whose
+        first row lies at or past its range's bound is not read from the
+        table and not copied: its plane keeps what it held."""
+        entry, row0, bound = at
+        for i in range(nb):
+
+            @pl.when(row0 + i * bt < bound)
+            def _(i=i):
+                blk = tables_ref[s, entry + i]
+                for n, (pool, buf) in enumerate(
+                        ((k_pool, kbuf), (v_pool, vbuf))):
+                    act(pltpu.make_async_copy(
+                        pool.at[blk, :, col], buf.at[half, i],
+                        sem.at[half, n]))
+
+    def rows(buf, half):  # the chunk's nb planes, one under the other
+        return jnp.concatenate([buf[half, i] for i in range(nb)], axis=0)
+
+    @pl.when(total > 0)
+    def _first():
+        copies(place(0), 0, lambda cp: cp.start())
+
+    def chunk(c):
+        half = c % 2
+        here = _, row0, bound = place(c)
+
+        @pl.when(c + 1 < total)
+        def _next():
+            copies(place(c + 1), 1 - half, lambda cp: cp.start())
+
+        copies(here, half, lambda cp: cp.wait())
+        scores = dot_nt(q, rows(kbuf, half)) * scale       # (R, nb * bt)
+        at = row0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        # a plane that was not copied, and the last block's rows past
+        # the bound, hold whatever they held: K's scores are masked, and
+        # V's rows zeroed, since p = 0 times a stray NaN is no 0
+        vblk = rows(vbuf, half)
+        vrow = row0 + jax.lax.broadcasted_iota(jnp.int32, vblk.shape, 0)
+        vblk = jnp.where(vrow < bound, vblk, jnp.zeros_like(vblk))
         fold(jnp.where(at < bound, scores, _MASKED),
-             lambda p: jnp.dot(p.astype(q.dtype), rows(v_refs),
+             lambda p: jnp.dot(p.astype(q.dtype), vblk,
                                preferred_element_type=jnp.float32))
+        return c + 1
 
-    n_win, n_sum = nwin_ref[s], nsum_ref[s]
-    step = nb * bt
+    jax.lax.while_loop(lambda c: c < total, chunk, jnp.int32(0))
 
-    @pl.when(jnp.logical_and(j < npw, j * step < n_win))
-    def _window():
-        pool_step(j * step, n_win)
-
-    @pl.when(jnp.logical_and(
-        jnp.logical_and(j >= npw, j < npw + nps), (j - npw) * step < n_sum))
-    def _summaries():
-        pool_step((j - npw) * step, n_sum)
-
-    @pl.when(j == npw + nps)
-    def _self_and_emit():
-        # one key, one value: a row sum and a scaled row, no product
-        sk = sk_ref[0].astype(jnp.float32)                 # (1, C)
-        sv = sv_ref[0].astype(jnp.float32)
-        own = jnp.sum(q.astype(jnp.float32) * sk, axis=-1, keepdims=True)
-        fold(own * scale, lambda p: p * sv)
-        o_ref[0] = (acc[...] / ll[...]).astype(o_ref.dtype)
+    # one key, one value: a row sum and a scaled row, no product
+    sk = sk_ref[0].astype(jnp.float32)                     # (1, C)
+    sv = sv_ref[0].astype(jnp.float32)
+    own = jnp.sum(q.astype(jnp.float32) * sk, axis=-1, keepdims=True)
+    fold(own * scale, lambda p: p * sv)
+    o_ref[0] = (acc[...] / ll[...]).astype(o_ref.dtype)
 
 
 def eva_paged_attention_kernel(q, view, tables, n_win, n_sum, l, self_kv,
@@ -131,10 +196,7 @@ def eva_paged_attention_kernel(q, view, tables, n_win, n_sum, l, self_kv,
     c = h * dh
     bt = view.k.shape[1]
     pdt = view.k.dtype
-    nw_e = window_blocks
-    ns_e = tables.shape[1] - nw_e
-    nb = max(1, min(nw_e, ns_e, _STEP_TOKENS // bt))
-    npw, nps = -(-nw_e // nb), -(-ns_e // nb)
+    nb, _, _ = eva_steps(window_blocks, tables.shape[1] - window_blocks, bt)
     rpad = -h % 8
 
     # block-diagonal queries: row h carries its Dh numbers in head h's
@@ -144,38 +206,27 @@ def eva_paged_attention_kernel(q, view, tables, n_win, n_sum, l, self_kv,
            * eye[None, :, :, None]).reshape(s, h, c)
     qbd = jnp.pad(qbd, ((0, 0), (0, rpad), (0, 0)))
 
-    def entry_spec(i):
-        """Block i of a step.  Window steps [0, npw) take table entry
-        j * nb + i, summary steps the same past the window's entries;
-        an entry beyond a range's live rows names the range's last live
-        block (entry 0 of it where none is), and the last step those of
-        the step before it: nothing new is fetched."""
-        def index(si, j, tr, wr, sr, lr):
-            last_w = jnp.maximum(-(-wr[si] // bt), 1) - 1
-            last_s = jnp.maximum(-(-sr[si] // bt), 1) - 1
-            e_w = jnp.minimum(j * nb + i, jnp.minimum(last_w, nw_e - 1))
-            js = jnp.minimum(j, npw + nps - 1) - npw
-            e_s = nw_e + jnp.minimum(js * nb + i,
-                                     jnp.minimum(last_s, ns_e - 1))
-            return (tr[si, jnp.where(j < npw, e_w, e_s)], 0, lr[0])
-        return pl.BlockSpec((1, bt, c), index)
-
-    pool_specs = [entry_spec(i) for i in range(nb)]
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     row_spec = pl.BlockSpec((1, h + rpad, c),
-                            lambda si, j, tr, wr, sr, lr: (si, 0, 0))
+                            lambda si, tr, wr, sr, lr: (si, 0, 0))
     self_spec = pl.BlockSpec((1, 1, c),
-                             lambda si, j, tr, wr, sr, lr: (si, 0, 0))
+                             lambda si, tr, wr, sr, lr: (si, 0, 0))
     sk, sv = (a.swapaxes(1, 2).reshape(s, 1, c).astype(pdt)
               for a in self_kv)
     out = pl.pallas_call(
-        functools.partial(_eva_kernel, bt=bt, nb=nb, npw=npw, nps=nps,
+        functools.partial(_eva_kernel, bt=bt, nb=nb, nw_e=window_blocks,
                           scale=1.0 / math.sqrt(dh)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(s, npw + nps + 1),
-            in_specs=[row_spec] + 2 * pool_specs + [self_spec, self_spec],
+            grid=(s,),
+            in_specs=[row_spec, in_hbm, in_hbm, self_spec, self_spec],
             out_specs=row_spec,
             scratch_shapes=[
+                # the two-deep buffer: nb planes of one block's (bt, C)
+                # a half, for K and for V
+                pltpu.VMEM((2, nb, bt, c), pdt),
+                pltpu.VMEM((2, nb, bt, c), pdt),
+                pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((h + rpad, c), jnp.float32),
                 pltpu.VMEM((h + rpad, 1), jnp.float32),
                 pltpu.VMEM((h + rpad, 1), jnp.float32),
@@ -183,14 +234,18 @@ def eva_paged_attention_kernel(q, view, tables, n_win, n_sum, l, self_kv,
         ),
         out_shape=jax.ShapeDtypeStruct((s, h + rpad, c), q.dtype),
         interpret=_paged.INTERPRET,
+        # a grid step starts and waits for its own copies and resets its
+        # own softmax state, so slots may split across Mosaic cores
+        # the buffer is 2 x 4 MiB at 16 blocks of (16, 4096) bf16 a
+        # chunk, and a chunk's K and V rows pass through as values too
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         name="tds_eva_paged_attn",
     )(tables.astype(jnp.int32), n_win.astype(jnp.int32),
       n_sum.astype(jnp.int32), jnp.reshape(jnp.asarray(l, jnp.int32), (1,)),
-      qbd, *(nb * [view.k]), *(nb * [view.v]), sk, sv)
+      qbd, view.k, view.v, sk, sv)
     # a row's own head out of its C columns
     hsel = jnp.arange(h)
     out = out[:, :h].reshape(s, h, h, dh)[:, hsel, hsel]   # (S, H, Dh)

@@ -63,8 +63,8 @@ dead step's arithmetic skipped and its BlockSpecs naming the blocks of
 the step before, so that nothing was fetched for it, took 11.9 ms, 8.8
 with the index maps' two integer divisions made shifts: what a grid
 step costs is its BlockSpecs, 36 here, each 50-80 ns a step whether or
-not it fetches.)  `ops/eva_attn_pallas.py` reads the same pool and
-still walks it by BlockSpecs.
+not it fetches.)  `ops/eva_attn_pallas.py` reads the same pool the
+same way since PR 33: two ranges of a slot's table row in one loop.
 
 Numerics: scores, softmax stats and accumulation are float32 (like the
 XLA reference); the output casts back to the query's dtype.  The online

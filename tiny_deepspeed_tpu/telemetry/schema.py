@@ -162,7 +162,9 @@ _NUM = (int, float)
 #      row the slots hold (slots x chunks a row, the paged kernel's
 #      unit, ops/paged_attn_pallas.pool_steps) and how many of them
 #      begin below their slot's length, which are all the kernel
-#      copies and folds, a layer
+#      copies and folds, a layer.  A two-cache engine's records carry
+#      the same two names for its own kernel's chunks, of a window
+#      range and a summary range (ops/eva_attn_pallas.eva_steps)
 SCHEMA_VERSION = 18
 
 # step-record fields beyond the required step/ts; values are allowed types

@@ -52,7 +52,8 @@ def trace(logdir: str):
 # `kernel` the name= of a pallas_call, a `counter` a number the host
 # takes: a clock reading at start-up (`utils/startup.marks`), or a count a
 # tick makes of its slots, kept in the tick's record and written as ids of
-# the span that covers the counting (`tds.tick.decode.operands`).
+# the span that covers the counting (`tds.tick.decode.operands`, or the
+# span the model's slot layout names: `tds.tick.roll`).
 # tests/test_spans.py holds the code to this table in both directions.
 _TICK, _STEP = "serving scheduler", "engine step"
 TABLE = {
