@@ -682,3 +682,69 @@ def test_eva_decode_step_compiles_for_the_chip_at_published_widths(
     pool_bytes = 2 * int(np.prod(shape)) * 2
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_mimo_decode_step_compiles_for_the_chip_at_published_widths(
+        topo_mesh):
+    """MiMo-V2-Flash's decode program at the benchmark cell's sizes (7
+    layers at hidden 4096, 64 heads, q/k 192 and v 128, 16 held experts,
+    64 slots, 16-row blocks): Mosaic takes `tds_paged_attn` with K wider
+    than V for both kinds of layer (4 KV heads over a table, 8 over a
+    ring with the sink), both kinds of pool are aliased from argument to
+    result, the arguments are the 11.6 GiB the cell rests at, and no
+    temporary of a weight's size is made: the split of q and k into a
+    rotary and a plain part stays off the weights (the barrier in
+    `MiMoModel._qkv`; without it the step re-lays 100 MB of q weights a
+    layer and holds 324 MiB of temporaries)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tiny_deepspeed_tpu.models import ALL_PRESETS, build_model
+    from tiny_deepspeed_tpu.serving.pool import KVPoolView, pool_shape
+
+    one = SingleDeviceSharding(topo_mesh.devices.reshape(-1)[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = dataclasses.replace(ALL_PRESETS["mimo-v2-flash-7l"],
+                              param_dtype=jnp.bfloat16)
+    model = build_model(cfg)
+    params = {k: sds(v.shape, v.dtype) for k, v in jax.eval_shape(
+        model.init, jax.random.PRNGKey(0)).items()}
+    stacked = {k: v for k, v in params.items()
+               if k.split(".")[0] in ("g", "w", "dense", "moe")}
+    slots, bt = 64, 16
+    lay = model.paged_layout(cfg.block_size, bt)
+    assert (lay.table, lay.ring) == (1024, 8)
+    view, pool_bytes = [], 0
+    for kind in lay.kinds:
+        shapes = [pool_shape(slots * kind.blocks + 1, bt, kind.layers,
+                             kind.kv_heads, width)
+                  for width in (kind.k_dim, kind.v_dim)]
+        view.append(KVPoolView(*(sds(s, jnp.bfloat16) for s in shapes),
+                               None, None))
+        pool_bytes += sum(2 * int(np.prod(s)) for s in shapes)
+    assert [v.k.shape[2] for v in view] == [2 * 4 * 192, 5 * 8 * 192]
+    assert [v.v.shape[2] for v in view] == [2 * 4 * 128, 5 * 8 * 128]
+
+    def decode(params, stacked, view, tokens, pos, tables):
+        x = model._embed_decode(params, tokens, pos)
+        page = model.paged_page_ref(tables, pos, bt)
+        x, view, counts = model.paged_decode(stacked, x, view, page)
+        return model.head(params, x)[:, 0], view, counts
+
+    ints = sds((slots,), jnp.int32)
+    with kernel_target_forced("tpu"):
+        compiled = jax.jit(decode, donate_argnums=(2,)).lower(
+            params, stacked, tuple(view), ints, ints,
+            sds((slots, lay.width), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tds_paged_attn" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert 11.4 < mem.argument_size_in_bytes / 2 ** 30 < 11.8
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20

@@ -300,8 +300,8 @@ class TestDisaggregation:
         )
         kw = dict(n_layer=2, kv_heads=2, head_dim=16, num_blocks=8,
                   block_tokens=8, dtype=jnp.float32)
-        pf = PagedKVPool(**kw)
-        pq = PagedKVPool(**kw, quant="int8")
+        pf = PagedKVPool.dense(**kw)
+        pq = PagedKVPool.dense(**kw, quant="int8")
         bf = payload_bytes(export_blocks(pf.view, [1, 2]))
         bq = payload_bytes(export_blocks(pq.view, [1, 2]))
         # f32 block = 4 B/elem; int8 block = 1 B/elem + f32 scale per
@@ -310,7 +310,7 @@ class TestDisaggregation:
         assert bf / bq == pytest.approx(3.2)
         with pytest.raises(ValueError, match="dtype mismatch"):
             import_blocks(pq.view, [1, 2], export_blocks(pf.view, [1, 2]))
-        small = PagedKVPool(**{**kw, "block_tokens": 4})
+        small = PagedKVPool.dense(**{**kw, "block_tokens": 4})
         with pytest.raises(ValueError, match="geometry mismatch"):
             import_blocks(small.view, [1, 2],
                           export_blocks(pf.view, [1, 2]))

@@ -69,9 +69,9 @@ def params(model):
 def _pool_view(quant, kvh=2, dh=16, L=2, bt=8, blocks=16):
     """A pool whose blocks hold random content (quantized through the
     real codec when quant is set)."""
-    pool = PagedKVPool(n_layer=L, kv_heads=kvh, head_dim=dh,
-                      num_blocks=blocks, block_tokens=bt,
-                      dtype=jnp.float32, quant=quant)
+    pool = PagedKVPool.dense(n_layer=L, kv_heads=kvh, head_dim=dh,
+                            num_blocks=blocks, block_tokens=bt,
+                            dtype=jnp.float32, quant=quant)
     view = pool.view
     k1, k2 = jax.random.split(jax.random.PRNGKey(7))
     # drawn (and quantized) per head vector, then merged into the

@@ -258,11 +258,11 @@ class TestQuantizedCache:
         from tiny_deepspeed_tpu.serving.pool import PagedKVPool
         kw = dict(n_layer=2, kv_heads=2, head_dim=16, num_blocks=8,
                   block_tokens=8)
-        base = PagedKVPool(dtype=jnp.float32, **kw).kv_bytes()
-        half = PagedKVPool(dtype=jnp.bfloat16, **kw).kv_bytes()
+        base = PagedKVPool.dense(dtype=jnp.float32, **kw).kv_bytes()
+        half = PagedKVPool.dense(dtype=jnp.bfloat16, **kw).kv_bytes()
         assert half["kv_block_bytes"] * 2 == base["kv_block_bytes"]
         for quant, dt in (("int8", jnp.int8), ("fp8", jnp.float8_e4m3fn)):
-            q = PagedKVPool(dtype=jnp.float32, quant=quant, **kw)
+            q = PagedKVPool.dense(dtype=jnp.float32, quant=quant, **kw)
             b = q.kv_bytes()
             assert jnp.dtype(q.view.k.dtype) == jnp.dtype(dt)
             assert b["itemsize"] == 1
@@ -277,9 +277,9 @@ class TestQuantizedCache:
             PagedKVPool, page_ref, paged_append, paged_panel,
         )
         dh, kvh, s = 16, 2, 3
-        pool = PagedKVPool(n_layer=1, kv_heads=kvh, head_dim=dh,
-                           num_blocks=4, block_tokens=4,
-                           dtype=jnp.float32, quant="int8")
+        pool = PagedKVPool.dense(n_layer=1, kv_heads=kvh, head_dim=dh,
+                                 num_blocks=4, block_tokens=4,
+                                 dtype=jnp.float32, quant="int8")
         rng = np.random.default_rng(0)
         k = rng.normal(size=(s, kvh, dh)).astype(np.float32)
         v = rng.normal(size=(s, kvh, dh)).astype(np.float32)
@@ -534,7 +534,7 @@ class TestDecodeHealthGuard:
         _assert_accounting(eng)
         assert [r.status for r in storm] == ["failed"] * 3
         assert all(r.finish_reason == "nonfinite_logits" for r in storm)
-        free = eng.pool._free
+        free = [b for kind in eng.pool._free for b in kind]
         assert len(free) == len(set(free)) == eng.pool.num_usable, (
             "quarantine leaked or double-freed pool blocks"
         )
@@ -798,7 +798,7 @@ class TestRunTraceGuards:
         eng = ServingEngine(model, params, _serve_config())
         # simulate the post-incident pool shrink: every block vanishes
         # after the admission check, so the queued prompt never admits
-        eng.pool._free = []
+        eng.pool._free = [[]]
         with pytest.raises(RuntimeError,
                            match=r"no progress .* queue_depth=1"):
             run_trace(eng, [Arrival(0.0, _prompt(1, 7), 4)],

@@ -93,9 +93,9 @@ class TestRefcountedPool:
 
     def _pool(self, n=6):
         from tiny_deepspeed_tpu.serving import PagedKVPool
-        return PagedKVPool(n_layer=1, kv_heads=1, head_dim=4,
-                           num_blocks=n, block_tokens=4,
-                           dtype=jnp.float32)
+        return PagedKVPool.dense(n_layer=1, kv_heads=1, head_dim=4,
+                                 num_blocks=n, block_tokens=4,
+                                 dtype=jnp.float32)
 
     def test_share_free_and_exact_counts(self):
         pool = self._pool()
@@ -139,9 +139,9 @@ class TestPrefixTree:
 
     def _pool(self, n=8):
         from tiny_deepspeed_tpu.serving import PagedKVPool
-        return PagedKVPool(n_layer=1, kv_heads=1, head_dim=4,
-                           num_blocks=n, block_tokens=4,
-                           dtype=jnp.float32)
+        return PagedKVPool.dense(n_layer=1, kv_heads=1, head_dim=4,
+                                 num_blocks=n, block_tokens=4,
+                                 dtype=jnp.float32)
 
     def test_match_insert_and_weak_ownership(self):
         from tiny_deepspeed_tpu.serving import PrefixCache

@@ -19,7 +19,7 @@ from tiny_deepspeed_tpu.serving import ServeConfig, ServingEngine
 from tiny_deepspeed_tpu.serving.engine import Request, _Slot
 
 BT = 8
-FAMILIES = ["tiny", "evabyte-tiny"]
+FAMILIES = ["tiny", "evabyte-tiny", "mimo-tiny"]
 
 
 @pytest.fixture(scope="module", params=FAMILIES)
@@ -90,6 +90,11 @@ _ROWS = {
     "evabyte-tiny": {0: [7, 3, 11, _S, 21] + [_S] * 15,
                      1: [5, 6, 8, 9, 30, 31, 32] + [_S] * 13,
                      3: [2, _S, _S, _S, 40] + [_S] * 15},
+    # the global table's 32 entries, then a ring of 16 rows = 2 entries
+    # (a slot's second list is as long as the ring at most)
+    "mimo-tiny": {0: [7, 3, 11] + [_S] * 29 + [21, _S],
+                  1: [5, 6, 8, 9] + [_S] * 28 + [30, 31],
+                  3: [2] + [_S] * 31 + [40, _S]},
 }
 _COUNTS = {
     # a chunk of the row is 256 tokens = the whole row: one chunk a slot
@@ -109,6 +114,14 @@ _COUNTS = {
                      dict(active=3, rows=19 + 16 + 1, window_blocks=8,
                           summary_blocks=5, windows_rolled=1,
                           kv_steps_live=3, kv_steps=8)),
+    # blocks by kind; on the span the rows a global layer attends (every
+    # position before the slot's own) and a window layer (at most the
+    # window's other 15)
+    "mimo-tiny": ("route",
+                  dict(global_blocks=8, window_blocks=4),
+                  dict(active=3, rows_global=19 + 64 + 1,
+                       rows_window=15 + 15 + 1, global_blocks=8,
+                       window_blocks=4)),
 }
 
 
@@ -117,8 +130,10 @@ def test_seeded_slots_fill_fixed_operands_and_counts(served):
     eng = ServingEngine(model, params, ServeConfig(
         max_active=4, num_blocks=200, block_tokens=BT))
     dense = name == "tiny"
+    # a ring holds no more blocks than it has entries
+    second = getattr(eng._layout, "ring", None)
     for i, kw in _SLOTS.items():
-        kw = dict(kw, summary=() if dense else kw["summary"])
+        kw = dict(kw, summary=() if dense else kw["summary"][:second])
         eng._slots[i] = _slot(**kw)
     active = [(i, s) for i, s in enumerate(eng._slots) if s is not None]
     eng._poison_pending.add(3)
@@ -146,7 +161,10 @@ def test_seeded_slots_fill_fixed_operands_and_counts(served):
         pass
     assert {k: eng._tick[k] for k in counts} == counts
     names = [s[0] for s in eng._tick["segments"]]
-    assert names == ["decode.operands"] + ([] if dense else ["roll"])
+    # a layout whose decode program hands counts back opens its span
+    # after the fetch, with them (tests/test_spans.py)
+    assert names == ["decode.operands"] + (
+        [] if dense or lay.fetched else [span])
 
 
 @pytest.mark.parametrize("step, live, per_row", [
@@ -199,8 +217,8 @@ def test_each_refusal_names_its_mechanism(served):
     assert eng.pool.num_usable == 2 * lay.width
     for feature, mechanism in _MECHANISM.items():
         assert mechanism in lay.refuses[feature]
-        with pytest.raises(ValueError, match="EvaByteModel cannot .*"
-                           + mechanism):
+        with pytest.raises(ValueError, match=type(model).__name__
+                           + " cannot .*" + mechanism):
             eng._refuse(feature)
     # the first stated one is raised, none for what the layout can follow
     eng._refuse("tenants")

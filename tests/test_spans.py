@@ -117,9 +117,23 @@ def tick_spans(model_params, tmp_path_factory):
         eva.submit(list(range(1, 30)), 8)
         eva.drain()
 
+    # a model whose decode program counts its own step (the only writer
+    # of tds.tick.route)
+    mimo_model = build_model(ALL_PRESETS["mimo-tiny"])
+    mimo = ServingEngine(mimo_model, mimo_model.init(jax.random.PRNGKey(0)),
+                         ServeConfig(max_active=2, num_blocks=64,
+                                     block_tokens=8, temperature=0.0))
+    mimo.submit(list(range(1, 30)), 3)
+    mimo.drain()
+
+    def mimo_body():
+        mimo.submit(list(range(1, 30)), 5)
+        mimo.drain()
+
     found = _traced(tmp_path_factory.mktemp("tick"), body)
     drafted = _traced(tmp_path_factory.mktemp("spec"), spec_body)
     drafted += _traced(tmp_path_factory.mktemp("eva"), eva_body)
+    drafted += _traced(tmp_path_factory.mktemp("mimo"), mimo_body)
     assert warm.done and all(r.done for r in reqs)
     return eng, reqs, found, drafted
 
@@ -170,6 +184,17 @@ def test_every_span_of_the_table_is_written_and_none_besides(
     # begun), both from 33; a table row holds one of each, two slots four
     assert [r["kv_steps_live"] for r in rolls] == [1, 1, 1, 1, 2, 2, 2]
     assert {r["kv_steps"] for r in rolls} == {2 * (1 + 1)}
+    # what a decode program counted of its own step rides the span that
+    # follows its fetch: one slot's 4 choices in each of 6 expert layers,
+    # every expert held (mimo-tiny), beside what the layout counted
+    routes = [dict(e.stats) for e in drafted if e.name == "tds.tick.route"]
+    assert len(routes) == 4
+    assert all(r["pairs"] == 6 * 4 and 6 <= r["experts_touched"] <= 24
+               for r in routes)
+    assert [r["rows_global"] for r in routes] == [29, 30, 31, 32]
+    assert all(r["active"] == 1 and r["rows_window"] == 15
+               and r["window_blocks"] == 2 for r in routes)
+    assert [r["global_blocks"] for r in routes] == [4, 4, 4, 5]
 
 
 def test_tick_spans_nest_as_the_table_says_and_carry_the_tick_number(
@@ -332,9 +357,11 @@ def test_a_decode_tick_counts_the_kernels_live_steps(
     by_hand = [sum(-(-(n + t) // 16) for n, new in asked if t < new - 1)
                for t in range(5)]
     assert by_hand == [6, 6, 7, 6, 6]
-    counters = {n for n in _names("counter")
-                if TABLE[n][1] == "kernels (serve)"}
-    assert counters == {"kv_steps_live", "kv_steps"}
+    # the dense layout's two, and the two a decode program that counts
+    # its own step hands back (tds.tick.route, above)
+    counters = {"kv_steps_live", "kv_steps"}
+    assert counters | {"pairs", "experts_touched"} == {
+        n for n in _names("counter") if TABLE[n][1] == "kernels (serve)"}
     kept = list(eng.tick_records)[first:]
     assert [r["kv_steps_live"] for r in kept] == by_hand
     assert {r["kv_steps"] for r in kept} == {4 * eng.max_blocks_per_req}

@@ -103,8 +103,12 @@ class EvaLayout(NamedTuple):
     window_size: int
     chunk_size: int
     block_tokens: int
+    # both kinds of row have one shape: ONE kind of block
+    kinds: tuple = ()
 
+    tables = (0, 0)
     span = "roll"
+    fetched = ()
     # a slot can hold no more than its ring and its summary rows, and
     # nothing but slots holds blocks: a block beyond max_active slots'
     # worst case could never be allocated
@@ -369,9 +373,12 @@ class EvaByteModel(LlamaModel):
             raise ValueError(
                 f"block_tokens={bt} must divide window_size={w}: the "
                 "window's blocks are reused as a ring")
-        return EvaLayout(
-            window=w // bt, summary=-(-(-(-max_seq // w) * (w // ch)) // bt),
-            window_size=w, chunk_size=ch, block_tokens=bt)
+        from ..serving.pool import BlockKind
+        nw, ns = w // bt, -(-(-(-max_seq // w) * (w // ch)) // bt)
+        kind = BlockKind(c.n_layer, c.kv_heads, c.head_dim, c.head_dim,
+                         nw + ns)
+        return EvaLayout(window=nw, summary=ns, window_size=w,
+                         chunk_size=ch, block_tokens=bt, kinds=(kind,))
 
     def paged_page_ref(self, tables, pos, block_tokens: int):
         """The decode step's write coordinates: position n rests in ring

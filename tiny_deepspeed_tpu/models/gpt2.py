@@ -1305,8 +1305,12 @@ class GPT2Model:
         one table of ceil(max_seq / block_tokens) entries;
         `models/evabyte.EvaLayout` for a family that keeps a window and
         chunk summaries."""
-        from ..serving.pool import DenseLayout
-        return DenseLayout(-(-max_seq // block_tokens), block_tokens)
+        from ..serving.pool import BlockKind, DenseLayout
+        c = self.config
+        width = -(-max_seq // block_tokens)
+        kind = BlockKind(c.n_layer, getattr(c, "kv_heads", c.n_head),
+                         c.head_dim, c.head_dim, width)
+        return DenseLayout(width, block_tokens, (kind,))
 
     def paged_page_ref(self, tables, pos, block_tokens: int):
         """The decode step's write coordinates (serving/pool.page_ref:

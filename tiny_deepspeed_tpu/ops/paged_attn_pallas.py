@@ -175,6 +175,7 @@ def _paged_attn_kernel(
     # output, scratch
     *refs,
     bt: int, nb: int, w: int, tk: int, quant: bool, scale: float,
+    ring: int = 0, sink: bool = False,
 ):
     """One (slot, row tile) grid step: fold the slot's live pool blocks,
     `nb` of them a chunk, and then the span's own K/V, into the online
@@ -186,12 +187,25 @@ def _paged_attn_kernel(
     its Dh numbers in head h's columns, zeros elsewhere), so ONE
     q @ k^T over C gives every head's scores and no head is ever sliced
     out of a block; the accumulator keeps all C columns per row and the
-    caller reads the row's own head back out."""
+    caller reads the row's own head back out.  K and V may differ in
+    width (C is K's, the accumulator has V's columns).
+
+    `ring` > 0: the table is a ring of `ring` rows, position n resting
+    in row n % ring, and a query sees the `ring` - 1 positions before
+    its own: rows below min(pos, ring) are live but for row pos % ring,
+    which holds the position that just left the window (the caller
+    overwrites it with this step's).  The order of rows in a ring does
+    not matter to a softmax over keys rotated before they were written.
+    `sink`: one more input, a number a query row, which joins the
+    softmax's denominator as exp(number) and no value."""
     q_ref, pools = refs[0], refs[1:3]
     i = 3
     if quant:
         ks_ref, vs_ref, sel_ref = refs[3:6]
         i = 6
+    if sink:
+        sink_ref = refs[i]
+        i += 1
     sk_ref, sv_ref, o_ref = refs[i:i + 3]
     bufs = refs[i + 3:i + 5]
     sem, acc, m, ll = refs[i + 5:]
@@ -200,8 +214,16 @@ def _paged_attn_kernel(
     t = pl.program_id(1)
     c_lanes = bufs[0].shape[-1]
     col = pl.ds(pl.multiple_of(l_ref[0] * c_lanes, c_lanes), c_lanes)
+    cols = [col, col]
+    if bufs[1].shape[-1] != c_lanes:  # V narrower or wider than K
+        v_lanes = bufs[1].shape[-1]
+        cols[1] = pl.ds(pl.multiple_of(l_ref[0] * v_lanes, v_lanes),
+                        v_lanes)
     step = nb * bt
     limit = pos_ref[s]
+    if ring:
+        left = limit % ring  # the row of the position that left
+        limit = jnp.minimum(limit, ring)
     held = jnp.minimum(limit, w * bt)  # what the table's w entries hold
 
     acc[...] = jnp.zeros(acc.shape, jnp.float32)
@@ -241,7 +263,7 @@ def _paged_attn_kernel(
                 blk = tables_ref[s, entry]
                 for n, (pool, buf) in enumerate(zip(pools, bufs)):
                     act(pltpu.make_async_copy(
-                        pool.at[blk, :, col], buf.at[half, i],
+                        pool.at[blk, :, cols[n]], buf.at[half, i],
                         sem.at[half, n]))
 
     def rows(buf, half):  # the chunk's nb planes, one under the other
@@ -264,6 +286,8 @@ def _paged_attn_kernel(
         tpos = c * step + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
         live = tpos < limit
+        if ring:
+            live = live & (tpos != left)
         vscale = None
         if quant:
             # a row's head picks its scale row: the one-hot `sel`
@@ -280,8 +304,11 @@ def _paged_attn_kernel(
         # no 0
         vblk = rows(bufs[1], half)
         vrow = c * step + jax.lax.broadcasted_iota(jnp.int32, vblk.shape, 0)
+        vlive = vrow < limit
+        if ring:
+            vlive = vlive & (vrow != left)
         fold(jnp.where(live, scores, _MASKED),
-             jnp.where(vrow < limit, vblk, 0.0), vscale)
+             jnp.where(vlive, vblk, 0.0), vscale)
         return c + 1
 
     jax.lax.while_loop(lambda c: c * step < held, chunk, jnp.int32(0))
@@ -292,7 +319,11 @@ def _paged_attn_kernel(
     koff = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     fold(jnp.where(koff <= qoff, scores, _MASKED),
          sv_ref[0].astype(jnp.float32))
-    o_ref[0, 0] = (acc[...] / ll[...]).astype(o_ref.dtype)
+    if sink:
+        o_ref[0, 0] = (acc[...] / (ll[...] + jnp.exp(
+            sink_ref[...] - m[...]))).astype(o_ref.dtype)
+    else:
+        o_ref[0, 0] = (acc[...] / ll[...]).astype(o_ref.dtype)
 
 
 # query rows a grid step folds at most: the accumulator is (rows, C) f32
@@ -325,7 +356,8 @@ def _scale_panel(scales, tables, l, kvh: int, entries: int):
     return panel.reshape(tables.shape[0], -1, kvh).swapaxes(1, 2)
 
 
-def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
+def paged_attention(q, view, page, l, span_kv, *, kv_heads: int,
+                    ring: int = 0, sink=None):
     """Fused block-table-gather attention over the paged pool.
 
     q: (S, Hq, K1, Dh) span queries (K1 == 1 on the plain decode step);
@@ -339,6 +371,13 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
     itself under the windowed causal mask (the exact mask of models'
     `_span_attention`).  Returns (S, Hq, K1, Dh) in q's dtype.
 
+    K and V may differ in width: Dh is then K's and the queries', the
+    result has V's (span_kv's sv and view.v say which).  `ring` > 0
+    reads the table as a ring of that many rows with one position a
+    slot in the span (models/mimo.py's window layers): a query sees the
+    ring's live rows but the one its own position will overwrite.
+    `sink` (Hq,) float32 adds exp(sink[h]) to head h's denominator.
+
     The pool arrays are handed to the kernel as they rest; what is
     reshaped to meet them is small: the queries (block-diagonal over
     the heads, see the kernel), the span's K/V and the result."""
@@ -346,6 +385,10 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
     kvh = kv_heads
     g = hq // kvh
     c = kvh * dh
+    dv = span_kv[1].shape[-1]
+    cv = kvh * dv
+    if ring and k1 != 1:
+        raise ValueError("a ring holds one new position a slot")
     bt = view.k.shape[1]
     w = page.tables.shape[1]
     quant = view.k_scale is not None
@@ -373,11 +416,18 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
                             lambda si, ti, tr, pr, lr: (si, ti, 0, 0))
     span_spec = pl.BlockSpec((1, k1, c), lambda si, ti, tr, pr, lr:
                              (si, 0, 0))
+    out_spec, vspan_spec = row_spec, span_spec
+    if cv != c:
+        out_spec = pl.BlockSpec((1, 1, rows + rpad, cv),
+                                lambda si, ti, tr, pr, lr: (si, ti, 0, 0))
+        vspan_spec = pl.BlockSpec((1, k1, cv), lambda si, ti, tr, pr, lr:
+                                  (si, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [row_spec, in_hbm, in_hbm]
     args = [qbd, view.k, view.v]
     # the two-deep buffer: nb planes of one block's (bt, C) a half
-    scratch = 2 * [pltpu.VMEM((2, nb, bt, c), view.k.dtype)]
+    scratch = [pltpu.VMEM((2, nb, bt, c), view.k.dtype),
+               pltpu.VMEM((2, nb, bt, cv), view.v.dtype)]
     if quant:
         # a scale a head vector is a sixteenth or less of the pool: the
         # slots' scales of this layer are gathered whole, tokens in the
@@ -390,21 +440,28 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
             sel.shape, lambda si, ti, tr, pr, lr: (0, 0))]
         args += [_scale_panel(a, tables, l, kvh, npool * nb)
                  for a in (view.k_scale, view.v_scale)] + [sel]
-    in_specs += [span_spec, span_spec]
-    args += [a.swapaxes(1, 2).reshape(s, k1, c) for a in span_kv]
+    if sink is not None:
+        # query row (h, g, t) is head h * G + g: its sink, a column
+        per_row = jnp.repeat(sink.astype(jnp.float32), tk)
+        in_specs.append(pl.BlockSpec(
+            (rows + rpad, 1), lambda si, ti, tr, pr, lr: (0, 0)))
+        args.append(jnp.pad(per_row, (0, rpad))[:, None])
+    in_specs += [span_spec, vspan_spec]
+    args += [a.swapaxes(1, 2).reshape(s, k1, -1) for a in span_kv]
 
     kernel = functools.partial(
         _paged_attn_kernel,
         bt=bt, nb=nb, w=w, tk=tk, quant=quant, scale=1.0 / math.sqrt(dh),
+        ring=ring, sink=sink is not None,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(s, nt),
         in_specs=in_specs,
-        out_specs=row_spec,
+        out_specs=out_spec,
         scratch_shapes=scratch + [
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((rows + rpad, c), jnp.float32),
+            pltpu.VMEM((rows + rpad, cv), jnp.float32),
             pltpu.VMEM((rows + rpad, 1), jnp.float32),
             pltpu.VMEM((rows + rpad, 1), jnp.float32),
         ],
@@ -412,7 +469,7 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, nt, rows + rpad, c), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, nt, rows + rpad, cv), q.dtype),
         interpret=INTERPRET,
         # a grid step starts and waits for its own copies and resets
         # its own softmax stats, so slots and row tiles may split
@@ -424,8 +481,8 @@ def paged_attention(q, view, page, l, span_kv, *, kv_heads: int):
     )(tables, pos, larr, *args)
     # a row's own head out of its C columns (a gather: the other heads'
     # columns are never computed with)
-    out = out[:, :, :rows].reshape(s, nt, kvh, g * tk, kvh, dh)
+    out = out[:, :, :rows].reshape(s, nt, kvh, g * tk, kvh, dv)
     hsel = jnp.arange(kvh)
     out = out[:, :, hsel, :, hsel]  # (KVH, S, NT, G * tk, Dh)
-    out = out.reshape(kvh, s, nt, g, tk, dh).transpose(1, 0, 3, 2, 4, 5)
-    return out.reshape(s, hq, k1, dh)
+    out = out.reshape(kvh, s, nt, g, tk, dv).transpose(1, 0, 3, 2, 4, 5)
+    return out.reshape(s, hq, k1, dv)

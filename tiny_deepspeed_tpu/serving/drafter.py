@@ -170,7 +170,7 @@ class ModelDrafter:
         self.max_seq = min(int(max_seq), c.block_size)
         self._W = -(-self.max_seq // self._bt)
         kv_heads = getattr(c, "kv_heads", c.n_head)
-        self.pool = PagedKVPool(
+        self.pool = PagedKVPool.dense(
             n_layer=c.n_layer, kv_heads=kv_heads, head_dim=c.head_dim,
             num_blocks=max_active * self._W, block_tokens=self._bt,
             dtype=resolved_cache_dtype(c),

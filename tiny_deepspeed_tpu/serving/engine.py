@@ -444,8 +444,10 @@ class ServingEngine:
         if not getattr(model, "paged_decode_capable", False):
             raise ValueError(
                 f"{type(model).__name__} does not support the paged "
-                "decode step (paged_decode_capable=False) — MoE capacity "
-                "routing cannot batch slots at mixed positions"
+                "decode step (paged_decode_capable=False) — a model "
+                "that cannot batch slots at mixed positions (MoEGPT's "
+                "static expert capacity is sized for one sequence "
+                "length; dropless experts are served, models/mimo.py)"
             )
         c = model.config
         if c.block_size % config.block_tokens:
@@ -502,23 +504,21 @@ class ServingEngine:
         # journal attach (property: stamps the serving geometry into the
         # file) — after max_seq so the stamp reflects the real geometry
         self.journal = journal
-        kv_heads = getattr(c, "kv_heads", c.n_head)
         # one block table row per slot, wide enough for a max_seq
         # request (both tables side by side where there are two);
         # unused entries point at scratch
         self.max_blocks_per_req = self._layout.width
-        # a layout that states what a slot CAN hold bounds the pool,
+        # the pool is built from the kinds of block the layout states.
+        # A layout that states what a slot CAN hold bounds each kind,
         # whatever num_blocks says: a block beyond max_active slots'
         # worst case could never be allocated (a caller that sizes the
         # pool as slots x context / block_tokens would ask for 8 times
         # what a window and its summaries take)
-        num_blocks = config.num_blocks
-        if self._layout.bounds_pool:
-            num_blocks = min(num_blocks, config.max_active * sum(
-                self._layout.need(self.max_seq - 1)))
         self._pool_args = dict(
-            n_layer=c.n_layer, kv_heads=kv_heads, head_dim=c.head_dim,
-            num_blocks=num_blocks,
+            kinds=tuple(kind._replace(blocks=min(
+                config.num_blocks, config.max_active * kind.blocks)
+                if self._layout.bounds_pool else config.num_blocks)
+                for kind in self._layout.kinds),
             block_tokens=config.block_tokens,
             dtype=resolved_cache_dtype(c), quant=config.quant,
         )
@@ -559,6 +559,8 @@ class ServingEngine:
         # and the flight ring read the newest; so can anyone else.
         self.tick_records: Deque[dict] = deque(maxlen=_TICK_RECORDS)
         self._tick: dict = {"tick": -1, "segments": [], "buckets": []}
+        # the ids of the layout's span, kept from the operands to the fetch
+        self._span_ids: dict = {}
         self._tick_counts = dict.fromkeys(
             ("admitted", "evicted", "preempted", "expired",
              "quarantined", "restarted"), 0)
@@ -627,7 +629,11 @@ class ServingEngine:
             with jax.named_scope("tds.decode"):
                 x = model._embed_decode(params, tokens, pos)
                 page = model.paged_page_ref(tables, pos, bt)
-                x, view = model.paged_decode(stacked, x, view, page)
+                # a model may count what its step did (the layout's
+                # `fetched` names the counts): they ride behind the
+                # tokens, in the one fetch the tick makes anyway
+                x, view, *counted = model.paged_decode(stacked, x, view,
+                                                       page)
                 logits = model.head(params, x)[:, 0]
                 # chaos operand: 0.0 off-path (tokens bit-identical —
                 # x+0.0 never changes an argmax or a categorical draw),
@@ -640,6 +646,9 @@ class ServingEngine:
                     nxt = sample_logits_per_slot(
                         model.sampling_logits(logits), base_key, seeds,
                         nprod, temp, top_k)
+                if counted:
+                    nxt = jnp.concatenate(
+                        [nxt, *(c.astype(nxt.dtype) for c in counted)])
             return nxt, logits, bad, view
 
         def tds_prefill(params, stacked, prompt, last_pos, block_ids,
@@ -1309,7 +1318,7 @@ class ServingEngine:
         return tq is not None and self._queue.depth(tenant) >= tq
 
     def describe(self) -> str:
-        q = self.config.quant or str(jnp.dtype(self.pool.view.k.dtype))
+        q = self.config.quant or str(jnp.dtype(self.pool.dtype))
         spec = (f", {self._spec.describe()}"
                 if self._spec is not None else "")
         extras = ""
@@ -1400,6 +1409,16 @@ class ServingEngine:
             bad = np.asarray(bad)
             tnow = time.monotonic()
         self._gap_hist.append(tnow - disp.t0)
+        if self._layout.fetched:
+            # what the program counted of its own step came behind the
+            # tokens: into the tick's record and, with what the layout
+            # counted of the slots, onto the layout's span
+            fetched = dict(zip(self._layout.fetched, map(
+                int, nxt[self.config.max_active:])))
+            self._tick.update(fetched)
+            with self._span(self._layout.span, **self._span_ids,
+                            **fetched):
+                pass
         with self._span("commit"):
             poisoned = (set(self._guard.observe(bad, [i for i, _ in
                                                       active]))
@@ -1610,17 +1629,17 @@ class ServingEngine:
         else:
             self._queue.popleft()
 
-    def _alloc(self, n: int) -> Optional[List[int]]:
+    def _alloc(self, n: int, kind: int = 0) -> Optional[List[int]]:
         """pool.alloc with prefix-tree reclaim: under pressure the
         radix tree yields its LRU unreferenced leaves (warm cache, no
         live holder) BEFORE the scheduler resorts to preemption —
         cached blocks are an optimization, never a reason to evict a
         running request."""
-        ids = self.pool.alloc(n)
+        ids = self.pool.alloc(n, kind)
         if ids is None and self._prefix is not None:
             if self._prefix.evict(self.pool,
                                   need=n - self.pool.blocks_free):
-                ids = self.pool.alloc(n)
+                ids = self.pool.alloc(n, kind)
         return ids
 
     def _effective_pool_util(self) -> float:
@@ -1687,14 +1706,17 @@ class ServingEngine:
                     # up front instead of across the first few grows
                     n_table, n_summary = self._layout.need(
                         self._write_horizon(req, p))
-                    ids_new = self._alloc(
-                        n_table + n_summary - len(alias))
-                    if ids_new is None:
-                        if alias:
-                            self.pool.free_blocks(alias)  # roll the pin back
+                    # each list from the kind of block the layout says
+                    # it is of, both or neither
+                    k_table, k_summary = self._layout.tables
+                    ids_new = self._alloc(n_table - len(alias), k_table)
+                    summary = (None if ids_new is None
+                               else self._alloc(n_summary, k_summary))
+                    if summary is None:
+                        # roll back the table's blocks and the pin
+                        self.pool.free_blocks(alias + (ids_new or []))
                         break
                     ids = alias + ids_new
-                    ids, summary = ids[:n_table], ids[n_table:]
                     self._pop_queued(req)
                     if self._prefill_exc is not None:
                         # chaos: the prefill "fails"; put everything back
@@ -1838,17 +1860,21 @@ class ServingEngine:
             n_table, n_summary = self._layout.need(
                 self._write_horizon(slot.req, slot.pos))
             if len(slot.table) < n_table:
-                return slot.table
-            return slot.summary if len(slot.summary) < n_summary else None
+                return slot.table, self._layout.tables[0]
+            if len(slot.summary) < n_summary:
+                return slot.summary, self._layout.tables[1]
+            return None
 
         for i, slot in enumerate(self._slots):
             if slot is None or self._slots[i] is not slot:
                 continue
             while self._slots[i] is slot:
-                table = short(slot)
-                if table is None:
+                lacks = short(slot)
+                if lacks is None:
                     break
-                ids = self._alloc(1)  # prefix tree yields before preemption
+                table, kind = lacks
+                # the prefix tree yields before preemption
+                ids = self._alloc(1, kind)
                 if ids is not None:
                     table.extend(ids)
                     continue
@@ -2183,7 +2209,9 @@ class ServingEngine:
         (or verify) step's operands, and what the layout counts of the
         tick's slots (`tick_counts`): into the tick's record, and as the
         ids of the span the layout names, which is this one or one of
-        its own, opened and closed right after this one."""
+        its own, opened and closed right after this one (or, where
+        the decode program hands counts back too, `fetched`, after its
+        fetch: `_decode_plain`)."""
         lay = self._layout
         counts, ids = lay.tick_counts([s for _, s in active],
                                       self.config.max_active)
@@ -2193,8 +2221,9 @@ class ServingEngine:
         with self._span("decode.operands", tick=tick,
                         **({} if own else ids)):
             yield
-        if own:
-            with self._span(lay.span, tick=tick, **ids):
+        self._span_ids = dict(tick=tick, **ids)
+        if own and not lay.fetched:
+            with self._span(lay.span, **self._span_ids):
                 pass
 
     def _record_tick(self, rec: dict) -> None:
@@ -2256,7 +2285,8 @@ class ServingEngine:
         # paged kernel's grid, or a two-cache model's blocks
         counts.update((k, rec[k]) for k in (
             "kv_steps_live", "kv_steps", "window_blocks",
-            "summary_blocks", "windows_rolled") if k in rec)
+            "summary_blocks", "windows_rolled", "global_blocks",
+            "pairs", "experts_touched") if k in rec)
         if self._spec is not None:
             # the draft-vs-verify wall split: draft_s is the drafter's
             # proposal wall, decode_s+fetch_s the verify program's —

@@ -54,7 +54,8 @@ device arrays + free list + exact accounting.
 
 from __future__ import annotations
 
-from typing import Dict, List, NamedTuple, Optional
+import bisect
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -121,6 +122,20 @@ def pool_shape(num_blocks: int, block_tokens: int, n_layer: int,
     return (num_blocks, block_tokens, n_layer * kv_heads * head_dim)
 
 
+class BlockKind(NamedTuple):
+    """One kind of block: `block_tokens` rows, each the K vectors (width
+    `k_dim`) and the V vectors (width `v_dim`) of `kv_heads` heads of
+    `layers` layers, side by side as `pool_shape` says, a side an array.
+    `blocks` is how many: in a slot layout the most ONE slot can hold,
+    handed to `PagedKVPool` the usable blocks of the pool."""
+
+    layers: int
+    kv_heads: int
+    k_dim: int
+    v_dim: int
+    blocks: int
+
+
 class DenseLayout(NamedTuple):
     """What one slot holds in the pool, in blocks of `block_tokens`
     rows, and how that reaches its block-table row: K and V of the whole
@@ -128,10 +143,18 @@ class DenseLayout(NamedTuple):
 
     A slot layout is the one thing `serving/engine.py` asks about a
     model's cache, and every servable model states one
-    (`GPT2Model.paged_layout`; `models/evabyte.EvaLayout` is the other).
-    Its members, all of them host arithmetic:
+    (`GPT2Model.paged_layout`; `models/evabyte.EvaLayout` and
+    `models/mimo.MiMoLayout` are the others).  Its members, all of them
+    host arithmetic:
 
     width             entries of a slot's block-table row
+    kinds             the pool's kinds of block (`BlockKind`): which
+                      layers rest in each, their KV heads, K and V
+                      widths, and the most blocks one slot can hold.
+                      The engine builds the pool from them; one kind
+                      here, the model's L x KVH x Dh on both sides
+    tables            the kind a slot's table and its second block list
+                      draw from, by their place in `kinds`
     need(pos)         (table blocks, summary blocks) a slot owns before
                       it writes position `pos`: never fewer for a later
                       position, never more than the row holds
@@ -142,16 +165,23 @@ class DenseLayout(NamedTuple):
                       what a decode tick counts of its slots: `counts`
                       go into the tick's record, `ids` onto the span
                       `tds.tick.<span>` (utils/profiling.TABLE)
-    bounds_pool       whether max_active x need(last position) is all
-                      the pool can ever hold.  Not here: a prefix tree
+    fetched           names of the counts the model's decode program
+                      hands back behind its tokens (`paged_decode`'s
+                      third result); with any, the span is opened after
+                      the fetch and carries them too.  None here
+    bounds_pool       whether max_active x a kind's `blocks` is all the
+                      pool can ever hold of it.  Not here: a prefix tree
                       keeps blocks that no slot owns
     refuses           {engine feature: why}, each refused by the engine
                       as "<Model> cannot <why>"; nothing here"""
 
     width: int
     block_tokens: int
+    kinds: tuple = ()
 
+    tables = (0, 0)
     span = "decode.operands"
+    fetched = ()
     bounds_pool = False
     refuses = {}
 
@@ -395,8 +425,8 @@ def paged_scatter(view: KVPoolView, ks, vs, block_ids,
 
 class PagedKVPool:
     """Host-side pool owner: the device arrays plus exact block
-    accounting.  `num_blocks` is the USABLE count — one extra scratch
-    block is allocated on top and never handed out.
+    accounting.  A kind's `blocks` is its USABLE count — one extra
+    scratch block is allocated on top and never handed out.
 
     Blocks are REFCOUNTED (the prefix-cache extension of the original
     LIFO free list): `alloc` hands a block out at refcount 1, `share`
@@ -412,40 +442,50 @@ class PagedKVPool:
     occurrences + one for a prefix-tree node) — what
     tests/test_serving_prefix.py asserts per tick."""
 
-    def __init__(self, *, n_layer: int, kv_heads: int, head_dim: int,
-                 num_blocks: int, block_tokens: int, dtype,
-                 quant: Optional[str] = None):
+    def __init__(self, *, kinds: Sequence[BlockKind], block_tokens: int,
+                 dtype, quant: Optional[str] = None):
         if quant not in KV_QUANT_MODES:
             raise ValueError(
                 f"KV-cache quant must be one of {KV_QUANT_MODES}, "
                 f"got {quant!r}"
             )
-        if num_blocks < 1 or block_tokens < 1:
-            raise ValueError("num_blocks and block_tokens must be >= 1")
-        self.num_usable = int(num_blocks)
+        if block_tokens < 1 or any(k.blocks < 1 for k in kinds):
+            raise ValueError("blocks and block_tokens must be >= 1")
+        self.kinds = tuple(kinds)
         self.block_tokens = int(block_tokens)
         self.quant = quant
-        total = self.num_usable + 1  # + scratch
-        shape = pool_shape(total, block_tokens, n_layer, kv_heads, head_dim)
+        # ONE id space: kind j's blocks are ids bases[j] + 1 ..
+        # bases[j] + blocks, and rest in its arrays at id - bases[j];
+        # each kind's row 0 is its scratch, and id 0 names them all
+        self.bases = [0]
+        for k in self.kinds[:-1]:
+            self.bases.append(self.bases[-1] + int(k.blocks))
+        self.num_usable = self.bases[-1] + int(self.kinds[-1].blocks)
         rest = _QDTYPE.get(quant, dtype)
 
-        def scale():
-            # distinct arrays per side: the view is DONATED through the
-            # compiled steps, and two fields aliasing one zeros buffer
-            # would be a double donation
-            if not quant:
-                return None
-            return jnp.zeros(
-                pool_shape(total, block_tokens, n_layer, kv_heads, 1),
-                jnp.float32)
+        def arrays(kind: BlockKind) -> KVPoolView:
+            total = int(kind.blocks) + 1  # + scratch
 
-        self.view = KVPoolView(
-            k=jnp.zeros(shape, rest), v=jnp.zeros(shape, rest),
-            k_scale=scale(), v_scale=scale(),
-        )
-        # pop() hands out ascending ids from 1; frees push back LIFO —
-        # both deterministic, which the realloc-determinism test pins
-        self._free: List[int] = list(range(total - 1, 0, -1))
+            def side(width, dt):
+                # distinct arrays per side: the view is DONATED through
+                # the compiled steps, and two fields aliasing one zeros
+                # buffer would be a double donation
+                return jnp.zeros(pool_shape(total, block_tokens,
+                                            kind.layers, kind.kv_heads,
+                                            width), dt)
+
+            return KVPoolView(
+                k=side(kind.k_dim, rest), v=side(kind.v_dim, rest),
+                k_scale=side(1, jnp.float32) if quant else None,
+                v_scale=side(1, jnp.float32) if quant else None)
+
+        self.views = tuple(arrays(k) for k in self.kinds)
+        # pop() hands out ascending ids from a kind's first; frees push
+        # back LIFO -- both deterministic, which the
+        # realloc-determinism test pins
+        self._free: List[List[int]] = [
+            list(range(base + int(k.blocks), base, -1))
+            for base, k in zip(self.bases, self.kinds)]
         # block id -> holder count, for every allocated block (ids in
         # the free list never appear here)
         self._ref: Dict[int, int] = {}
@@ -454,13 +494,44 @@ class PagedKVPool:
 
     @property
     def blocks_free(self) -> int:
-        return len(self._free)
+        return sum(len(f) for f in self._free)
 
     @property
     def blocks_in_use(self) -> int:
         """DISTINCT allocated blocks — a block aliased by three holders
         still occupies one physical block."""
-        return self.num_usable - len(self._free)
+        return self.num_usable - self.blocks_free
+
+    def kind_of(self, b: int) -> int:
+        """Which kind block id `b` is of."""
+        return bisect.bisect_left(self.bases, int(b)) - 1
+
+    def free_of(self, kind: int) -> int:
+        """Free blocks of one kind."""
+        return len(self._free[kind])
+
+    @classmethod
+    def dense(cls, *, n_layer: int, kv_heads: int, head_dim: int,
+              num_blocks: int, **kw) -> "PagedKVPool":
+        """A pool of one kind, K and V of one width."""
+        return cls(kinds=(BlockKind(n_layer, kv_heads, head_dim, head_dim,
+                                    num_blocks),), **kw)
+
+    @property
+    def view(self):
+        """What the model's programs take and hand back: the one kind's
+        four arrays, or a tuple of such views where the layout states
+        several kinds.  Everything else here reads `views`."""
+        return self.views[0] if len(self.views) == 1 else self.views
+
+    @view.setter
+    def view(self, new) -> None:
+        self.views = (new,) if len(self.views) == 1 else tuple(new)
+
+    @property
+    def dtype(self):
+        """The resting dtype, of every kind and side."""
+        return self.views[0].k.dtype
 
     def refcount(self, b: int) -> int:
         """Holder count of block `b` (0 = free)."""
@@ -472,13 +543,14 @@ class PagedKVPool:
         holders it can enumerate (active tables + prefix-tree nodes)."""
         return dict(self._ref)
 
-    def alloc(self, n: int) -> Optional[List[int]]:
-        """n physical block ids at refcount 1, or None WITHOUT
+    def alloc(self, n: int, kind: int = 0) -> Optional[List[int]]:
+        """n physical block ids of `kind` at refcount 1, or None WITHOUT
         allocating when fewer than n are free (admission is
         all-or-nothing)."""
-        if n > len(self._free):
+        free = self._free[kind]
+        if n > len(free):
             return None
-        ids = [self._free.pop() for _ in range(n)]
+        ids = [free.pop() for _ in range(n)]
         for b in ids:
             self._ref[b] = 1
         return ids
@@ -517,24 +589,23 @@ class PagedKVPool:
             self._ref[b] -= 1
             if self._ref[b] == 0:
                 del self._ref[b]
-                self._free.append(b)
+                self._free[self.kind_of(b)].append(b)
 
     def kv_bytes(self) -> dict:
         """The pool's resting HBM footprint, FROM the device arrays'
         dtypes/shapes (what the quantization acceptance asserts against,
         not a model): K+V block bytes, scale bytes, and the per-element
         width."""
-        k = self.view.k
-        blocks = 2 * k.size * jnp.dtype(k.dtype).itemsize
-        scales = (
-            2 * self.view.k_scale.size
-            * jnp.dtype(self.view.k_scale.dtype).itemsize
-            if self.view.k_scale is not None else 0
-        )
+        def nbytes(*arrays):
+            return sum(a.size * jnp.dtype(a.dtype).itemsize
+                       for a in arrays if a is not None)
+
+        blocks = sum(nbytes(v.k, v.v) for v in self.views)
+        scales = sum(nbytes(v.k_scale, v.v_scale) for v in self.views)
         return {
             "kv_block_bytes": int(blocks),
             "scale_bytes": int(scales),
             "total_bytes": int(blocks + scales),
-            "dtype": str(jnp.dtype(k.dtype)),
-            "itemsize": int(jnp.dtype(k.dtype).itemsize),
+            "dtype": str(jnp.dtype(self.dtype)),
+            "itemsize": int(jnp.dtype(self.dtype).itemsize),
         }

@@ -165,7 +165,15 @@ _NUM = (int, float)
 #      copies and folds, a layer.  A two-cache engine's records carry
 #      the same two names for its own kernel's chunks, of a window
 #      range and a summary range (ops/eva_attn_pallas.eva_steps)
-SCHEMA_VERSION = 18
+#  19: + global_blocks / pairs / experts_touched on the `tick` records
+#      of an engine whose model keeps a table of global blocks beside a
+#      window ring and routes to held experts (models/mimo.py): the
+#      blocks its slots hold of the first kind (the ring's are
+#      `window_blocks`), and what the decode program counted of its own
+#      step and handed back behind its tokens: the (token, expert) pairs
+#      computed here and the held experts that got a token, summed over
+#      the expert layers; other engines' records are unchanged
+SCHEMA_VERSION = 19
 
 # step-record fields beyond the required step/ts; values are allowed types
 STEP_FIELDS: Dict[str, tuple] = {
@@ -418,6 +426,11 @@ META_FIELDS: Dict[str, tuple] = {
     # length reaches: what the paged kernel folds a layer (schema v18)
     "kv_steps_live": int,
     "kv_steps": int,
+    # a table of global blocks beside a ring, and what the decode program
+    # routed to the held experts (schema v19)
+    "global_blocks": int,
+    "pairs": int,
+    "experts_touched": int,
     # why this tick record exists: "event" (a count above is nonzero) or
     # "sample" (the tick_record_every cadence)
     "emit": str,

@@ -53,7 +53,9 @@ def trace(logdir: str):
 # takes: a clock reading at start-up (`utils/startup.marks`), or a count a
 # tick makes of its slots, kept in the tick's record and written as ids of
 # the span that covers the counting (`tds.tick.decode.operands`, or the
-# span the model's slot layout names: `tds.tick.roll`).
+# span the model's slot layout names: `tds.tick.roll`, `tds.tick.route`),
+# or a count the decode program makes of its own step and hands back
+# behind its tokens (`pairs`, `experts_touched`: the layout's `fetched`).
 # tests/test_spans.py holds the code to this table in both directions.
 _TICK, _STEP = "serving scheduler", "engine step"
 TABLE = {
@@ -66,6 +68,7 @@ TABLE = {
     "tds.tick.draft": ("span", _TICK, "tick_host_ms"),
     "tds.tick.decode.operands": ("span", _TICK, "tick_host_ms"),
     "tds.tick.roll": ("span", _TICK, "cache_blocks_per_slot"),
+    "tds.tick.route": ("span", _TICK, "cache_mib_per_slot"),
     "tds.tick.decode.dispatch": ("span", _TICK, "tick_host_ms"),
     "tds.tick.decode.fetch": ("span", _TICK, "tick_host_ms"),
     "tds.tick.commit": ("span", _TICK, "tick_host_ms"),
@@ -84,6 +87,11 @@ TABLE = {
     "tds.attn.window": ("scope", "kernels (serve)", "decode_ms"),
     "tds.attn.proj": ("scope", "kernels (train)", "fwd_ms"),
     "tds.mlp": ("scope", "kernels (train)", "fwd_ms"),
+    "tds.moe": ("scope", "kernels (serve)", "moe_ms"),
+    "tds.moe.router": ("scope", "kernels (serve)", "moe_ms"),
+    "tds.moe.dispatch": ("scope", "kernels (serve)", "moe_ms"),
+    "tds.moe.experts": ("scope", "kernels (serve)", "moe_roofline"),
+    "tds.moe.combine": ("scope", "kernels (serve)", "moe_ms"),
     "tds.head": ("scope", "kernels (train)", "head_ms"),
     "tds.cast": ("scope", _STEP, "fwd_ms"),
     "tds.optim": ("scope", _STEP, "optim_ms"),
@@ -121,6 +129,8 @@ TABLE = {
     "backend_up": ("counter", "entry / start-up", "backend_init_s"),
     "kv_steps_live": ("counter", "kernels (serve)", None),
     "kv_steps": ("counter", "kernels (serve)", None),
+    "pairs": ("counter", "kernels (serve)", "moe_tokens_per_expert"),
+    "experts_touched": ("counter", "kernels (serve)", "moe_roofline"),
 }
 
 
