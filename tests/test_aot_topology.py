@@ -522,8 +522,10 @@ class TestTpuTopologyHLO:
         p, st = jax.tree.map(like, params), jax.tree.map(like, eng._stacked)
         programs = {
             "tds_decode": (eng._decode_fn, (
-                p, st, view, ints(slots), ints(slots),
-                ints(slots, cfg.block_size // bt), ints(slots), ints(slots),
+                p, st, view, ints(slots), ints(2 * slots),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=sh),
+                ints(slots), ints(slots, cfg.block_size // bt), ints(slots),
+                ints(slots),
                 jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=sh))),
             "tds_prefill": (eng._prefill_fn, (
                 p, st, ints(1, bucket), ints(), ints(bucket // bt), view,

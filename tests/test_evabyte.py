@@ -431,12 +431,15 @@ def test_a_slot_ten_windows_long_holds_no_more_than_its_stated_blocks(
             # the ring: the same blocks, never freed, never reallocated
             window_ids = window_ids or list(slot.table)
             assert slot.table == window_ids
-        # grown at the start of the tick that wrote position pos - 1
-        assert len(slot.summary) == (slot.pos - 1) // C // bt + 1
+        # grown for the write of the step in flight, at pos, or with none
+        # in flight at the start of the tick that wrote position pos - 1
+        at = slot.pos - (eng._ahead is None)
+        assert len(slot.summary) == at // C // bt + 1
     assert len(r.prompt) + len(r.tokens) == 10 * W + 2
-    # (the last tick's growth is not seen: the slot is gone after it)
+    # (the last step's growth is seen: the tick before it grows for it,
+    # as it dispatches it ahead)
     assert max(held) <= stated and max(held) == lay.window + (
-        10 * W - 1) // C // bt + 1
+        10 * W) // C // bt + 1
     # a slot of full K/V would hold a block per bt positions
     assert max(held) < (10 * W) // bt
     assert eng.pool.blocks_in_use == 0 and eng.pool.blocks_free == 2 * stated
@@ -503,9 +506,12 @@ def test_block_export_import_and_the_other_caches_are_refused_by_name(
 # sha256 of the StableHLO text `jit(...).lower(...).as_text()` gives for
 # the tiny GPT-2 preset's decode and prefill programs (4 slots, 32 blocks of
 # 16, greedy), recorded on commit 27981b1, the parent of the PR that added
-# this family.  A PR that means to change GPT-2's serve programs records
-# them anew; one that adds a family or a hook must leave them as they are.
-_GPT2_DECODE = "90524dca8352103ca7e98bcc15dcbf172349df24242cde80e76f4e6c2000933b"
+# this family; the decode program's recorded anew when it began to take a
+# slot's token from the step before it (`prev`, `take`) and to hand its
+# health flags back behind its tokens.  A change meant to alter GPT-2's
+# serve programs records them anew; one that adds a family or a hook must
+# leave them as they are.
+_GPT2_DECODE = "8b1234b4a99237082c93ca9cdb5c078362dc2e7cdf951ac566d86a91e3a44261"
 _GPT2_PREFILL = "d338aaa3b60cdcd5210d2de1abca7b923b9e7d32bfb37ecea1b792b6fb0b28d0"
 
 
@@ -517,7 +523,8 @@ def test_gpt2_serve_programs_lower_to_the_same_text():
     ints = jax.ShapeDtypeStruct((4,), jnp.int32)
     view = eng.pool.view
     decode = eng._decode_fn.lower(
-        params, eng._stacked, view, ints, ints,
+        params, eng._stacked, view, ints, eng._no_prev,
+        jax.ShapeDtypeStruct((4,), jnp.bool_), ints,
         jax.ShapeDtypeStruct((4, eng.max_blocks_per_req), jnp.int32),
         ints, ints, jax.ShapeDtypeStruct((4,), jnp.float32)).as_text()
     prefill = eng._prefill_fn.lower(

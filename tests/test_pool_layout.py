@@ -149,7 +149,8 @@ def test_programs_never_reshape_the_pool(families, quant, kernel):
     ints = jnp.zeros((slots,), jnp.int32)
     programs = {
         "tds_decode": (eng._decode_fn, (
-            params, eng._stacked, view, ints, ints,
+            params, eng._stacked, view, ints, eng._no_prev,
+            jnp.zeros((slots,), bool), ints,
             jnp.zeros((slots, width), jnp.int32), ints, ints,
             jnp.zeros((slots,), jnp.float32))),
         "tds_prefill": (eng._prefill_fn, (

@@ -640,6 +640,36 @@ def test_live_slo_schema_v15_names():
     assert not errs, errs
 
 
+def test_decode_ahead_schema_v20_names():
+    """Schema-v20 drift guard: a `tick` record carries `ahead`, whether
+    the tick dispatched the next tick's decode step before it fetched its
+    own; the engine still writes it into the record and onto the
+    dispatch span, and the profiler's table still names it."""
+    from tiny_deepspeed_tpu.telemetry import schema
+    from tiny_deepspeed_tpu.utils.profiling import TABLE
+
+    assert schema.SCHEMA_VERSION >= 20
+    assert schema.META_FIELDS["ahead"] is int
+    assert TABLE["ahead"][0] == "counter"
+    with open(os.path.join(
+            REPO, "tiny_deepspeed_tpu", "serving", "engine.py")) as f:
+        engine_src = f.read()
+    for name in ('self._tick["ahead"]', '"decode.dispatch"', "ahead=int(",
+                 '"ahead") if k in rec'):
+        assert name in engine_src, (
+            f"{name} gone from serving/engine.py: the tick record and the "
+            "dispatch span carry `ahead` from there")
+    errs = schema.validate_record({
+        "kind": "tick", "ts": 0.0, "tick": 3, "t_s": 1.0, "wall_s": 0.002,
+        "sched_s": 0.001, "prefill_s": 0.0, "decode_s": 0.0005,
+        "fetch_s": 0.0005, "occupancy": 0.5, "pool_util": 0.1,
+        "queue_depth": 0, "admitted": 0, "evicted": 0, "preempted": 0,
+        "shed": 0, "expired": 0, "quarantined": 0, "restarted": 0,
+        "produced": 2, "kv_steps_live": 2, "kv_steps": 8, "ahead": 1,
+        "emit": "sample"})
+    assert not errs, errs
+
+
 def test_serving_engine_asks_the_layout_not_whether_there_is_one():
     """One cache seam (PR 32): every servable model states a slot layout
     (serving/pool.DenseLayout), so `serving/engine.py` holds no test of

@@ -155,16 +155,25 @@ def test_seeded_slots_fill_fixed_operands_and_counts(served):
     got = lay.tick_counts([s for _, s in active], 4)
     assert got == (counts, ids)
     assert list(got[1]) == list(ids)  # the span's ids keep their order
-    # and the tick writes them where their readers look: the record's
-    # keys, the span's name
-    with eng._operands_span(active):
+    # the operands span hands them on for the step: its commit writes
+    # them into the record and opens the layout's own span, where it has
+    # one, after the fetch (tests/test_spans.py)
+    with eng._operands_span(active) as made:
         pass
-    assert {k: eng._tick[k] for k in counts} == counts
-    names = [s[0] for s in eng._tick["segments"]]
-    # a layout whose decode program hands counts back opens its span
-    # after the fetch, with them (tests/test_spans.py)
-    assert names == ["decode.operands"] + (
-        [] if dense or lay.fetched else [span])
+    assert made == (counts, ids)
+    assert [s[0] for s in eng._tick["segments"]] == ["decode.operands"]
+    # a slot the step in flight carries on is one position further on
+    # (its token comes from that step's output on the chip)
+    tokens, pos, seeds, nprod, poison, _ = eng._slot_arrays(active, {1})
+    assert pos.tolist() == [19, 65, 0, 1]
+    assert nprod.tolist() == [2, 5, 0, 2]
+    assert not np.isnan(poison).any()
+    with eng._operands_span(active, {1}) as (_, on):
+        pass
+    row = {"tiny": None, "evabyte-tiny": "rows",
+           "mimo-tiny": "rows_global"}[name]
+    if row is not None:
+        assert on[row] == ids[row] + 1
 
 
 @pytest.mark.parametrize("step, live, per_row", [

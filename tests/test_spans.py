@@ -240,6 +240,35 @@ def test_an_admission_is_admit_dispatch_fetch_commit_with_its_request_id(
                      "tds.tick.commit", "tds.tick.observe"]
 
 
+def test_a_step_dispatched_a_tick_ahead_says_so_where_it_goes_and_comes(
+        tick_spans):
+    """`ahead` on `tds.tick.decode.dispatch`: 1 where the tick dispatches
+    the next tick's step; on `tds.tick.decode.fetch`: 1 where the step it
+    fetches went out a tick early.  The tick's record carries the
+    dispatch's (engine/schema's `ahead`)."""
+    eng, _, found, _ = tick_spans
+    assert {n for n in _names("counter")
+            if TABLE[n][1] == "serving scheduler"} == {"ahead"}
+    ticks = {dict(t.stats)["tick"]: t for t in found if t.name == "tds.tick"}
+    went = [dict(e.stats) for e in found
+            if e.name == "tds.tick.decode.dispatch"]
+    came = [dict(e.stats) for e in found if e.name == "tds.tick.decode.fetch"]
+    # nothing was dropped: every step dispatched was fetched, those that
+    # went ahead a tick later
+    assert len(went) == len(came) and sum(d["ahead"] for d in went) == sum(
+        f["ahead"] for f in came) >= len(came) - 2
+    records = {r["tick"]: r for r in eng.tick_records}
+    for n in ticks:
+        mine = [d["ahead"] for d in went if d["tick"] == n]
+        if mine:
+            assert records[n]["ahead"] == max(mine)
+    # a tick whose step went out ahead fetches it in the next tick
+    for f in came:
+        if f["ahead"]:
+            assert any(d["tick"] == f["tick"] - 1 and d["ahead"]
+                       for d in went)
+
+
 def test_step_spans_follow_the_loop(step_spans):
     assert [e.name for e in step_spans] == [
         "tds.load", "tds.h2d", "tds.step", "tds.sync"]
@@ -368,11 +397,16 @@ def test_a_decode_tick_counts_the_kernels_live_steps(
     ticks = [r for r in sink.records if r["kind"] == "tick"][first:]
     ids = [dict(e.stats) for e in found
            if e.name == "tds.tick.decode.operands"]
-    for rec, written, span_ids in zip(kept, ticks, ids, strict=True):
+    # a step's operands are counted where it is dispatched: from the
+    # second step on, the tick before the one that commits it
+    assert [r["ahead"] for r in kept] == [1, 1, 1, 1, 0]
+    dispatched = [kept[0]["tick"]] + [r["tick"] - 1 for r in kept[1:]]
+    for rec, written, span_ids, at in zip(kept, ticks, ids, dispatched,
+                                          strict=True):
         assert {k: written[k] for k in counters} == {
             k: rec[k] for k in counters} == {
             k: span_ids[k] for k in counters}
-        assert span_ids["tick"] == rec["tick"]
+        assert span_ids["tick"] == at
         assert schema.validate_record(dict(written, ts=0.0)) == []
     # a tick that decodes nothing counts nothing
     idle = ServingEngine(model, params, ServeConfig(max_active=2,
@@ -508,7 +542,8 @@ def test_serving_programs_are_named_and_scoped(model_params):
     view = eng.pool.view
     ints = jax.ShapeDtypeStruct((S,), jnp.int32)
     text = eng._decode_fn.lower(
-        params, eng._stacked, view, ints, ints,
+        params, eng._stacked, view, ints, eng._no_prev,
+        jax.ShapeDtypeStruct((S,), jnp.bool_), ints,
         jax.ShapeDtypeStruct((S, eng.max_blocks_per_req), jnp.int32),
         ints, ints, jax.ShapeDtypeStruct((S,), jnp.float32),
     ).compile().as_text()

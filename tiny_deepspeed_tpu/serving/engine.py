@@ -25,6 +25,12 @@ Phase split, two compiled programs:
     loop the verify and suffix-prefill programs use — and its K/V rows
     of all layers written once, after the loop.  Invalid slots carry
     scratch coordinates; no branch, no recompile as occupancy changes.
+    A tick dispatches the NEXT tick's step before it fetches its own
+    (`_next_step`): a slot that goes on takes its token from the step
+    still on the chip, so the chip decodes while the host commits,
+    schedules and comes back.  Every token a tick commits is on the
+    request when it returns; the step in flight is dropped (and
+    re-run, the same) where anything changes what it must do.
 
 Block exhaustion preempts the YOUNGEST active request (its blocks free
 immediately; it re-queues at the FRONT and later re-prefills from
@@ -126,7 +132,7 @@ import itertools
 import os
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional, Sequence, Union
+from typing import Deque, Dict, List, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -414,6 +420,33 @@ class _Slot:
 
 
 @dataclasses.dataclass
+class _Step:
+    """A decode step in flight: the slots it decodes as they were when it
+    went out, its outputs on the device (`out`: tokens, the layout's
+    `fetched` counts, health flags), when it was dispatched, what the
+    layout counted of it (`counts` for the record of the tick that
+    commits it, `ids` for the layout's span) and whether it went out a
+    tick before that one."""
+
+    slots: List[tuple]
+    out: jax.Array
+    logits: jax.Array
+    t0: float
+    counts: dict
+    ids: dict
+    ahead: bool
+
+
+class _At(NamedTuple):
+    """A slot as the step it is counted for sees it: one position on
+    where the step in flight commits its next token first."""
+
+    pos: int
+    table: List[int]
+    summary: List[int]
+
+
+@dataclasses.dataclass
 class KVHandoff:
     """One request in transit between two engines — the disaggregated
     prefill->decode migration unit (fleet/disagg.py).  `payload` holds
@@ -559,8 +592,15 @@ class ServingEngine:
         # and the flight ring read the newest; so can anyone else.
         self.tick_records: Deque[dict] = deque(maxlen=_TICK_RECORDS)
         self._tick: dict = {"tick": -1, "segments": [], "buckets": []}
-        # the ids of the layout's span, kept from the operands to the fetch
-        self._span_ids: dict = {}
+        # the decode step dispatched a tick ahead (`_next_step`), which
+        # the next tick commits.  Whatever changes what that step must do
+        # between two ticks drops it (poison, export, recover, restart,
+        # preemption), and the next tick dispatches its own: the dropped
+        # step wrote only the K/V of each slot's committed last token at
+        # its position, which the new one writes again, the same
+        self._ahead: Optional[_Step] = None
+        # when the last decode step's tokens reached the host
+        self._t_fetch = 0.0
         self._tick_counts = dict.fromkeys(
             ("admitted", "evicted", "preempted", "expired",
              "quarantined", "restarted"), 0)
@@ -624,9 +664,13 @@ class ServingEngine:
         # the programs' names are what a device trace's `XLA Modules` line
         # reads (`jit_tds_decode`, `jit_tds_prefill`), the named scopes
         # what its operations carry: utils/profiling.TABLE
-        def tds_decode(params, stacked, view, tokens, pos, tables,
-                       seeds, nprod, poison):
+        def tds_decode(params, stacked, view, tokens, prev, take, pos,
+                       tables, seeds, nprod, poison):
             with jax.named_scope("tds.decode"):
+                # a slot whose token the step before has not yet handed
+                # to the host takes it from that step's output, `prev`,
+                # still on the chip (`_next_step`); the others the host's
+                tokens = jnp.where(take, prev[:tokens.shape[0]], tokens)
                 x = model._embed_decode(params, tokens, pos)
                 page = model.paged_page_ref(tables, pos, bt)
                 # a model may count what its step did (the layout's
@@ -638,18 +682,17 @@ class ServingEngine:
                 # chaos operand: 0.0 off-path (tokens bit-identical —
                 # x+0.0 never changes an argmax or a categorical draw),
                 # NaN on a poisoned slot.  The per-slot health flag rides
-                # the same computation the token fetch already
-                # synchronizes on.
+                # behind the tokens, in the one fetch of the step.
                 logits = logits + poison[:, None]
                 bad = ~jnp.all(jnp.isfinite(logits), axis=-1)
                 with jax.named_scope("tds.sample"):
                     nxt = sample_logits_per_slot(
                         model.sampling_logits(logits), base_key, seeds,
                         nprod, temp, top_k)
-                if counted:
-                    nxt = jnp.concatenate(
-                        [nxt, *(c.astype(nxt.dtype) for c in counted)])
-            return nxt, logits, bad, view
+                out = jnp.concatenate(
+                    [nxt, *(c.astype(nxt.dtype) for c in counted),
+                     bad.astype(nxt.dtype)])
+            return out, logits, view
 
         def tds_prefill(params, stacked, prompt, last_pos, block_ids,
                         view, seed, nprod):
@@ -670,6 +713,11 @@ class ServingEngine:
         # one both programs index (serving/pool.py), so neither converts
         # it on entry or exit: donation alone could not spare that copy
         self._decode_fn = _kwrap(jax.jit(tds_decode, donate_argnums=(2,)))
+        # `prev` of a step that takes every token from the host: the
+        # shape of the program's first output, tokens, the layout's
+        # counts and the health flags
+        self._no_prev = jnp.zeros(
+            (2 * config.max_active + len(self._layout.fetched),), jnp.int32)
         self._prefill_fn = _kwrap(
             jax.jit(tds_prefill, donate_argnums=(5,)))
         # "h.*" compute-dtype cast once — params are frozen while serving.
@@ -1084,6 +1132,7 @@ class ServingEngine:
                 out.append(req)
         for req in reversed(out):
             self._queue.appendleft(req)
+        self._ahead = None
         # keep fresh ids clear of everything the journal ever issued
         nxt = next(Request._ids)
         Request._ids = itertools.count(max(nxt, max_seen + 1))
@@ -1118,6 +1167,7 @@ class ServingEngine:
         req = slot.req
         now = time.monotonic()
         self._refuse("export_request")
+        self._ahead = None
         payload = export_blocks(self.pool.view, slot.table)
         self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
@@ -1220,6 +1270,7 @@ class ServingEngine:
             req.event("engine_lost", now, replica=self.replica_id)
         self._queue.clear()
         self._poison_pending.clear()
+        self._ahead = None
         if self._journal is not None:
             self._journal.abandon()
 
@@ -1255,10 +1306,13 @@ class ServingEngine:
         hits whichever request occupies slot i at that decode step —
         which can differ from the occupant at arm time if the scheduler
         reseats the slot earlier in the same tick.  A tick that runs no
-        decode step discards the arm rather than letting it linger."""
+        decode step discards the arm rather than letting it linger.  A
+        step already dispatched for the next tick is dropped, so that the
+        step the arm hits is the one that tick commits."""
         if not 0 <= i < self.config.max_active:
             raise ValueError(f"slot {i} out of range")
         self._poison_pending.add(i)
+        self._ahead = None
 
     def arm_prefill_exception(self, exc: BaseException) -> None:
         """Arm ONE exception raised at the next admission's prefill
@@ -1346,8 +1400,11 @@ class ServingEngine:
             # lets a fresh admission strand a grower, whose
             # preempt-youngest victim is then the just-prefilled request
             # (a full prefill thrown away per block boundary while the
-            # pool is tight)
+            # pool is tight).  So do the next write's blocks of the slots
+            # the step in flight carries on
             self._grow()
+            if self._ahead is not None:
+                self._grow_next(self._carried(self._ahead).values())
         produced = self._admit()
         active = [(i, s) for i, s in enumerate(self._slots)
                   if s is not None]
@@ -1358,13 +1415,18 @@ class ServingEngine:
                 produced += self._decode_plain(active)
         else:
             # no decode step ran: a poison armed for this tick must not
-            # linger and hit whatever occupies the slot ticks later
+            # linger and hit whatever occupies the slot ticks later, nor
+            # a step in flight be committed a tick late
             self._poison_pending.clear()
+            self._ahead = None
         return produced
 
-    def _slot_arrays(self, active):
+    def _slot_arrays(self, active, carried=frozenset()):
         """The decode/verify programs' per-slot operand vectors (empty
-        slots carry scratch coordinates — branch-free, shape-stable)."""
+        slots carry scratch coordinates — branch-free, shape-stable).  A
+        slot in `carried` is one position further on than the host has
+        it: the step in flight commits its next token first, and the
+        program takes that token from the step's output."""
         S = self.config.max_active
         tokens = np.zeros((S,), np.int32)
         pos = np.zeros((S,), np.int32)
@@ -1375,10 +1437,11 @@ class ServingEngine:
                          np.int32)
         fill_row = self._layout.fill_row
         for i, s in active:
+            on = int(i in carried)
             tokens[i] = s.last
-            pos[i] = s.pos
+            pos[i] = s.pos + on
             seeds[i] = s.req.seed
-            nprod[i] = len(s.req.tokens)
+            nprod[i] = len(s.req.tokens) + on
             fill_row(tables[i], s.table, s.summary)
         if self._poison_pending:
             for i in self._poison_pending:
@@ -1387,38 +1450,126 @@ class ServingEngine:
         return tokens, pos, seeds, nprod, poison, tables
 
     def _decode_plain(self, active) -> int:
-        """One token for every active slot — the exact pre-speculation
-        decode tick (spec off compiles and runs only this path)."""
-        produced = 0
-        with self._operands_span(active):
+        """One token for every active slot.  The step this tick commits
+        is the one the tick before dispatched ahead, or, with none in
+        flight (the first tick, a drop, a fallback) or none of its slots
+        left, one dispatched now;
+        and where the tick can tell what the step after it needs, that
+        one goes out before this one is fetched (`_next_step`), so the
+        chip runs it while the host commits this one and comes back for
+        the next tick.  Spec off compiles and runs only this path."""
+        step, self._ahead = self._ahead, None
+        if step is None or not any(self._slots[i] is s
+                                   for i, s in step.slots):
+            step = self._dispatch(active)
+        self._ahead = self._next_step(step)
+        self._tick["ahead"] = int(self._ahead is not None)
+        return self._commit(step)
+
+    def _carried(self, step: _Step) -> Dict[int, _Slot]:
+        """The slots of `step` that are still theirs and go on after it:
+        all but those it brings to `max_new_tokens`, a count the host
+        knows before the token.  One that `step` ends on eos is carried
+        too, and the next step's token for it thrown away."""
+        return {i: s for i, s in step.slots if self._slots[i] is s
+                and len(s.req.tokens) + 1 < s.req.max_new_tokens}
+
+    def _lacks(self, slot: _Slot, pos: int):
+        """(the table of `slot` that lacks a block for a write at `pos`,
+        the kind of block it draws from), or None where none does (a
+        window ring that is whole never grows again)."""
+        n_table, n_summary = self._layout.need(pos)
+        if len(slot.table) < n_table:
+            return slot.table, self._layout.tables[0]
+        if len(slot.summary) < n_summary:
+            return slot.summary, self._layout.tables[1]
+        return None
+
+    def _grow_next(self, slots) -> bool:
+        """Each of `slots` owns the blocks its write one position on
+        needs, taken without preemption; False where the pool has none
+        to give (what could be taken stays taken)."""
+        for s in slots:
+            while (lacks := self._lacks(s, s.pos + 1)) is not None:
+                table, kind = lacks
+                ids = self._alloc(1, kind)
+                if ids is None:
+                    return False
+                table.extend(ids)
+        return True
+
+    def _next_step(self, step: _Step) -> Optional[_Step]:
+        """The step after `step`, dispatched before `step` is fetched, or
+        None, and the next tick dispatches its own.  Not with the prefix
+        tree, which commits a prompt's blocks to the tree at admission
+        (speculation, whose yield a slot cannot know before the fetch,
+        never comes here); and only once each slot carried on
+        (`_carried`) owns its next write's blocks, taken without
+        preempting anyone.  A slot seated since `step` went out takes its
+        operands from the host."""
+        if self._prefix is not None:
+            return None
+        carried = self._carried(step)
+        if not self._grow_next(carried.values()):
+            return None
+        went = dict(step.slots)
+        active = [(i, s) for i, s in enumerate(self._slots)
+                  if s is not None and (i in carried or went.get(i) is not s)]
+        if not active:
+            return None
+        return self._dispatch(active, carried.keys(), prev=step.out)
+
+    def _dispatch(self, active, carried=frozenset(),
+                  prev: Optional[jax.Array] = None) -> _Step:
+        """Build a decode step's operands over `active` and dispatch it
+        (async: the fetch is the sync, `_commit`).  `carried` slots take
+        their token from `prev`, the output of the step in flight; with
+        `prev`, the step goes out a tick ahead of the one that commits
+        it, which its `tds.tick.decode.dispatch` span says (`ahead`)."""
+        with self._operands_span(active, carried) as (counts, ids):
             tokens, pos, seeds, nprod, poison, tables = \
-                self._slot_arrays(active)
-        # dispatch returns before the device finishes (async); the
-        # np.asarray token fetch is the sync — the tick record splits
-        # the two (decode.dispatch vs decode.fetch)
-        with self._span("decode.dispatch") as disp:
-            nxt, logits, bad, view = self._decode_fn(
-                self.params, self._stacked, self.pool.view,
-                tokens, pos, tables, seeds, nprod, poison,
+                self._slot_arrays(active, carried)
+            take = np.zeros_like(tokens, bool)
+            take[list(carried)] = True
+        ahead = prev is not None
+        with self._span("decode.dispatch", tick=self._tick["tick"],
+                        ahead=int(ahead)) as disp:
+            out, logits, view = self._decode_fn(
+                self.params, self._stacked, self.pool.view, tokens,
+                self._no_prev if prev is None else prev, take, pos,
+                tables, seeds, nprod, poison,
             )
             self.pool.view = view
-            self.last_logits = logits
-        with self._span("decode.fetch"):
-            nxt = np.asarray(nxt)
-            # same computation, already synchronized by the token fetch
-            bad = np.asarray(bad)
+        return _Step(slots=list(active), out=out, logits=logits,
+                     t0=disp.t0, counts=counts, ids=ids, ahead=ahead)
+
+    def _commit(self, step: _Step) -> int:
+        """Fetch `step` in one transfer (tokens, the layout's counts,
+        health flags) and commit it to each slot it decoded that is still
+        the same request's: tokens, finishes, guard, journal, the counts
+        into the record of this tick, `last_logits`."""
+        S = self.config.max_active
+        lay = self._layout
+        with self._span("decode.fetch", tick=self._tick["tick"],
+                        ahead=int(step.ahead)):
+            out = np.asarray(step.out)
             tnow = time.monotonic()
-        self._gap_hist.append(tnow - disp.t0)
-        if self._layout.fetched:
-            # what the program counted of its own step came behind the
-            # tokens: into the tick's record and, with what the layout
-            # counted of the slots, onto the layout's span
-            fetched = dict(zip(self._layout.fetched, map(
-                int, nxt[self.config.max_active:])))
-            self._tick.update(fetched)
-            with self._span(self._layout.span, **self._span_ids,
+        # one step's wall: from its dispatch, or, where it went out before
+        # the step ahead of it was fetched, from that fetch
+        self._gap_hist.append(tnow - max(step.t0, self._t_fetch))
+        self._t_fetch = tnow
+        self.last_logits = step.logits
+        fetched = dict(zip(lay.fetched, map(int, out[S:])))
+        bad = out[S + len(lay.fetched):].astype(bool)
+        self._tick.update(step.counts, **fetched)
+        if lay.span != "decode.operands":
+            # what the layout counted of the step, and what the program
+            # counted of its own and handed back behind the tokens
+            with self._span(lay.span, tick=self._tick["tick"], **step.ids,
                             **fetched):
                 pass
+        active = [(i, s) for i, s in step.slots if self._slots[i] is s]
+        produced = 0
         with self._span("commit"):
             poisoned = (set(self._guard.observe(bad, [i for i, _ in
                                                       active]))
@@ -1427,7 +1578,7 @@ class ServingEngine:
                 if i in poisoned:
                     self._quarantine(i, s)
                     continue
-                t = int(nxt[i])
+                t = int(out[i])
                 s.pos += 1
                 s.last = t
                 self._append_token(s.req, t, tnow)
@@ -1455,7 +1606,7 @@ class ServingEngine:
         produced = 0
         with self._span("draft") as draft:
             drafts = self._spec.propose(self._slots)  # (S, K+1) int32
-        with self._operands_span(active):
+        with self._operands_span(active) as (counts, _):
             tokens, pos, seeds, nprod, poison, tables = \
                 self._slot_arrays(active)
             S = self.config.max_active
@@ -1471,7 +1622,9 @@ class ServingEngine:
             for i, s in active:
                 limit_kv[i] = (len(s.req.prompt)
                                + s.req.max_new_tokens - 2)
-        with self._span("decode.dispatch"):
+        self._tick.update(counts, ahead=0)
+        with self._span("decode.dispatch", tick=self._tick["tick"],
+                        ahead=0):
             acc, final, bad, view = self._spec.verify(
                 self.params, self._stacked, self.pool.view,
                 span, pos, tables, seeds, nprod, limit_kv, poison,
@@ -1854,22 +2007,12 @@ class ServingEngine:
         """Allocate the next block for any slot whose write horizon
         crossed a block boundary; on exhaustion, preempt the youngest
         active request until the grower fits (or is itself preempted)."""
-        def short(slot):
-            """The table that lacks a block for the next write, if one
-            does (a window ring that is whole never grows again)."""
-            n_table, n_summary = self._layout.need(
-                self._write_horizon(slot.req, slot.pos))
-            if len(slot.table) < n_table:
-                return slot.table, self._layout.tables[0]
-            if len(slot.summary) < n_summary:
-                return slot.summary, self._layout.tables[1]
-            return None
-
         for i, slot in enumerate(self._slots):
             if slot is None or self._slots[i] is not slot:
                 continue
             while self._slots[i] is slot:
-                lacks = short(slot)
+                lacks = self._lacks(slot, self._write_horizon(slot.req,
+                                                              slot.pos))
                 if lacks is None:
                     break
                 table, kind = lacks
@@ -1899,6 +2042,7 @@ class ServingEngine:
         now = time.monotonic()
         self.pool.free_blocks(slot.blocks)
         self._slots[i] = None
+        self._ahead = None
         req.state = "queued"
         self._close_active(req, slot, now)
         req.preemptions += 1
@@ -1946,6 +2090,7 @@ class ServingEngine:
             self._queue.appendleft(s.req)
         self._slots = [None] * self.config.max_active
         self._poison_pending.clear()
+        self._ahead = None
         self.pool = PagedKVPool(**self._pool_args)
         if self._prefix is not None:
             # the tree indexes blocks of the pool that just died with
@@ -2204,27 +2349,21 @@ class ServingEngine:
             self._flight_reason = reason
 
     @contextlib.contextmanager
-    def _operands_span(self, active):
-        """`tds.tick.decode.operands`, around the building of the decode
-        (or verify) step's operands, and what the layout counts of the
-        tick's slots (`tick_counts`): into the tick's record, and as the
-        ids of the span the layout names, which is this one or one of
-        its own, opened and closed right after this one (or, where
-        the decode program hands counts back too, `fetched`, after its
-        fetch: `_decode_plain`)."""
+    def _operands_span(self, active, carried=frozenset()):
+        """`tds.tick.decode.operands`, around the building of a decode
+        (or verify) step's operands; yields what the layout counts of the
+        step's slots (`tick_counts`, a `carried` slot one position on):
+        `counts` for the record of the tick that commits the step, `ids`
+        for the span the layout names.  That is this one, or one of its
+        own, which the commit opens after the fetch (`_commit`)."""
         lay = self._layout
-        counts, ids = lay.tick_counts([s for _, s in active],
-                                      self.config.max_active)
-        self._tick.update(counts)
-        tick = self._tick["tick"]
+        counts, ids = lay.tick_counts(
+            [_At(s.pos + (i in carried), s.table, s.summary)
+             for i, s in active], self.config.max_active)
         own = lay.span != "decode.operands"
-        with self._span("decode.operands", tick=tick,
+        with self._span("decode.operands", tick=self._tick["tick"],
                         **({} if own else ids)):
-            yield
-        self._span_ids = dict(tick=tick, **ids)
-        if own and not lay.fetched:
-            with self._span(lay.span, **self._span_ids):
-                pass
+            yield counts, ids
 
     def _record_tick(self, rec: dict) -> None:
         """End-of-tick bookkeeping, from the tick's own record (`rec`,
@@ -2286,7 +2425,7 @@ class ServingEngine:
         counts.update((k, rec[k]) for k in (
             "kv_steps_live", "kv_steps", "window_blocks",
             "summary_blocks", "windows_rolled", "global_blocks",
-            "pairs", "experts_touched") if k in rec)
+            "pairs", "experts_touched", "ahead") if k in rec)
         if self._spec is not None:
             # the draft-vs-verify wall split: draft_s is the drafter's
             # proposal wall, decode_s+fetch_s the verify program's —

@@ -173,7 +173,10 @@ _NUM = (int, float)
 #      step and handed back behind its tokens: the (token, expert) pairs
 #      computed here and the held experts that got a token, summed over
 #      the expert layers; other engines' records are unchanged
-SCHEMA_VERSION = 19
+#  20: + ahead on the `tick` records of a tick that ran a decode step: 1
+#      where it dispatched the next tick's step before it fetched its own
+#      (serving/engine.py `_next_step`), 0 where it did not
+SCHEMA_VERSION = 20
 
 # step-record fields beyond the required step/ts; values are allowed types
 STEP_FIELDS: Dict[str, tuple] = {
@@ -431,6 +434,9 @@ META_FIELDS: Dict[str, tuple] = {
     "global_blocks": int,
     "pairs": int,
     "experts_touched": int,
+    # whether the tick dispatched the next tick's decode step before it
+    # fetched its own (schema v20)
+    "ahead": int,
     # why this tick record exists: "event" (a count above is nonzero) or
     # "sample" (the tick_record_every cadence)
     "emit": str,

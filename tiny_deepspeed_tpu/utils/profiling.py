@@ -131,6 +131,10 @@ TABLE = {
     "kv_steps": ("counter", "kernels (serve)", None),
     "pairs": ("counter", "kernels (serve)", "moe_tokens_per_expert"),
     "experts_touched": ("counter", "kernels (serve)", "moe_roofline"),
+    # on `tds.tick.decode.dispatch`: 1 where it dispatches the next tick's
+    # step; on `tds.tick.decode.fetch`: 1 where the step it fetches went
+    # out a tick early
+    "ahead": ("counter", _TICK, None),
 }
 
 
